@@ -184,24 +184,23 @@ def aggregate_match_matrix(backend: Backend) -> _Op:
 
 
 def _make_jnp_slide():
-    """Reference batched sliding-window chain (gather windows, dot the
-    alphabet axis, chain the k positions — all under one jit; retraces
-    per distinct (k, shape) group, which the round engine groups by
-    anyway)."""
+    """Reference batched sliding-window chain (dot pattern row j against
+    word positions j..j+M−1 of every tuple, chain the k rows — all under
+    one jit; retraces per distinct (k, shape) group, which the round
+    engine groups by anyway). Static slices, so no (…, M, k, A) window
+    gather is ever materialized."""
     from ..core import field
 
     @jax.jit
     def aa_slide(cols: Array, pats: Array) -> Array:
         # cols (c, B, n, W, A), pats (c, B, k, A) -> (c, B, n, M)
         k = pats.shape[-2]
-        w = cols.shape[-2]
-        m = w - k + 1
-        idx = jnp.arange(m)[:, None] + jnp.arange(k)[None, :]
-        win = cols[..., idx, :]                      # (c, B, n, M, k, A)
-        v = field.dot(win, pats[:, :, None, None], axis=-1)
-        acc = v[..., 0]
-        for j in range(1, k):                        # k static: unrolled
-            acc = field.mul(acc, v[..., j])
+        m = cols.shape[-2] - k + 1
+        acc = None
+        for j in range(k):                           # k static: unrolled
+            v = field.dot(cols[..., j:j + m, :],
+                          pats[:, :, None, None, j, :], axis=-1)
+            acc = v if acc is None else field.mul(acc, v)
         return acc
 
     return aa_slide
@@ -247,22 +246,14 @@ def _make_jnp_ripple_segment():
     """Reference fused k-bit segment: the per-bit chain under ONE jit, so a
     whole degree-reduction-free run of bits is a single device dispatch.
     The loop body is exactly :data:`jnp_ripple_carry`'s math, hence
-    bit-identical to stepping."""
-    import functools
+    bit-identical to stepping (``carry=None`` starts at the LSB step)."""
 
-    @functools.partial(jax.jit, static_argnames=("init",))
-    def _seg(a, b, carry, init):
+    @jax.jit
+    def ripple_segment(a, b, carry=None):
         rb = None
         for i in range(a.shape[-1]):
-            rb, carry = jnp_ripple_carry(a[..., i], b[..., i],
-                                         None if (init and i == 0)
-                                         else carry)
+            rb, carry = jnp_ripple_carry(a[..., i], b[..., i], carry)
         return rb, carry
-
-    def ripple_segment(a, b, carry=None):
-        init = carry is None
-        c0 = jnp.zeros_like(a[..., 0]) if init else carry
-        return _seg(a, b, c0, init)
 
     return ripple_segment
 
@@ -287,9 +278,6 @@ def get_backend(backend: BackendLike) -> Backend:
     if isinstance(backend, Backend):
         return backend
     _ensure_builtins()
-    if backend == "pallas" and not _try_register_pallas():
-        raise ValueError("backend 'pallas' is unavailable: the Pallas "
-                         "kernel import failed on this jax build")
     try:
         return _REGISTRY[backend]
     except KeyError:
@@ -299,16 +287,17 @@ def get_backend(backend: BackendLike) -> Backend:
 
 def available_backends() -> Tuple[str, ...]:
     _ensure_builtins()
-    _try_register_pallas()
     return tuple(sorted(_REGISTRY))
 
 
 def _ensure_builtins() -> None:
-    """Register the pure-jnp backend (import-cycle safe, no kernel deps)."""
+    """Register the pure-jnp and Pallas backends on first use (lazily, so
+    importing the registry never imports the kernels: import-cycle safe)."""
     if "jnp" in _REGISTRY:
         return
     from ..core import automata, field
     from ..core.shamir import Shares
+    from ..kernels import ops as kops
 
     def _raw(op):                       # Shares-level op -> raw-array op
         def run(a: Array, b: Array) -> Array:
@@ -330,16 +319,4 @@ def _ensure_builtins() -> None:
         match_matrix_batch=jax.jit(jax.vmap(match_matrix, in_axes=1,
                                             out_axes=1)),
         aa_slide_batch=jnp_aa_slide))
-
-
-def _try_register_pallas() -> bool:
-    """Register the Pallas kernels on first request; the pure-jnp query
-    suite must keep working on builds where the kernel import fails."""
-    if "pallas" in _REGISTRY:
-        return True
-    try:
-        from ..kernels import ops as kops
-    except ImportError:
-        return False
     register_backend(kops.as_backend())
-    return True

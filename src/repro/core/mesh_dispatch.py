@@ -17,11 +17,12 @@ device-resident:
   arrays, kernel dispatches consume and produce device buffers, and the
   reduce below never touches the host.
 * **SPMD reduce** — a ``"sum"`` step's per-shard mod-p partials are stacked
-  and lowered through ``shard_map``: each device folds its block in uint64
-  and a ``psum`` along the data axes combines them, with a single final
-  ``% p`` fold. F_p addition is exact, so this is **bit-identical** to the
-  host chain of ``field.add`` for every shard count S — the dataplane's
-  standing transcript invariant. The stacked buffer is *donated* into the
+  and lowered through ``shard_map``: each device sums its block as 16-bit
+  halves in uint32, a ``psum`` along the data axes combines them, and a
+  single final Mersenne fold recombines the halves. F_p addition is
+  exact, so this is **bit-identical** to the host chain of ``field.add``
+  for every shard count S below 2¹⁶ — the dataplane's standing
+  transcript invariant. The stacked buffer is *donated* into the
   reduction (round-to-round re-shares reuse the storage; donation is a
   no-op on backends without buffer aliasing, e.g. CPU).
 * **No blocking inside a batch** — ``run_set`` never calls
@@ -47,12 +48,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                    # jax >= 0.6 promotes it out
-    from jax import shard_map           # type: ignore[attr-defined]
-except ImportError:                     # pragma: no cover - version skew
-    from jax.experimental.shard_map import shard_map
 
 from . import field
 from .dataplane import Dispatcher, DispatchSet, ShardedRelation
@@ -181,11 +178,16 @@ class MeshDispatcher(Dispatcher):
         axes = self.data_axes
 
         def psum_fold(block):
-            # uint64 accumulation of < 2^31 partials never wraps for any
-            # realistic S; ONE fold at the end == the field.add chain.
-            acc = jnp.sum(block.astype(jnp.uint64), axis=0)
-            acc = jax.lax.psum(acc, axes)
-            return (acc % jnp.uint64(field.P)).astype(block.dtype)
+            # Split each partial x < p into x = hi·2^16 + lo (hi < 2^15,
+            # lo < 2^16) and sum the halves in uint32 — the widest integer
+            # all-reduce the TPU lowers. Exact for fewer than 2^16 stacked
+            # partials; ONE fold at the end == the field.add chain.
+            u16 = jnp.uint32(0xFFFF)
+            lo = jnp.sum(block & u16, axis=0, dtype=jnp.uint32)
+            hi = jnp.sum(block >> jnp.uint32(16), axis=0, dtype=jnp.uint32)
+            lo, hi = jax.lax.psum((lo, hi), axes)
+            return field.addmod32(field.rotmod32(field.fold32(hi), 16),
+                                  field.fold32(lo))
 
         mapped = shard_map(psum_fold, mesh=self.mesh,
                            in_specs=in_spec, out_specs=out_spec)

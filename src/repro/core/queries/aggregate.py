@@ -49,6 +49,7 @@ checksum chain per round is future work, see ROADMAP).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -59,7 +60,7 @@ from .. import dataplane, encoding, field, shamir
 from ..costs import CostLedger
 from ..dataplane import RelationLike
 from ..shamir import Shares
-from .rounds import (MatchJob, _batched_matcher, _fused_interpolate,
+from .rounds import (MatchJob, _batched_matcher, _open_on_host,
                      _ripple_segmenter, _segment_edges, _share_patterns,
                      _stack_columns, _stack_numeric)
 
@@ -249,7 +250,7 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
     for k, i in enumerate(free):
         per_job[i] = Shares(sums_flat[:, len(cond) + k],
                             db.numeric[jobs[i].value_column].degree)
-    opened = _fused_interpolate(per_job)
+    opened = _open_on_host(per_job)
 
     per_q = codec.word_length * codec.alphabet_size
     for i, j in enumerate(jobs):
@@ -270,6 +271,23 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
 # ---------------------------------------------------------------------------
 # MIN / MAX — sentinel mask + knockout tournament on the SS-SUB comparator
 # ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("pairs",))
+def _pair_level(cand, is_min, pairs: int):
+    """One tournament level's fixed pairing (2i, 2i+1) of (c, B, k, t)
+    candidates. SS-SUB(lhs, rhs) opens [rhs < lhs]: min wants s = [x2 < x1]
+    (lhs=x1), max wants s = [x1 < x2] (lhs=x2); either way the winner is
+    x1 + s·(x2 − x1)."""
+    x1 = cand[:, :, 0:2 * pairs:2]                          # (c,B,pairs,t)
+    x2 = cand[:, :, 1:2 * pairs:2]
+    return (x1, x2, jnp.where(is_min, x1, x2), jnp.where(is_min, x2, x1))
+
+
+@jax.jit
+def _select_winner(x1, x2, s_bits):
+    """The oblivious select x₁ + s·(x₂ − x₁), share-wise."""
+    return field.add(x1, field.mul(s_bits[..., None], field.sub(x2, x1)))
+
 
 def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
                       ) -> List[Tuple[Optional[int], Optional[int]]]:
@@ -371,13 +389,7 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
     k = n
     while k > 1:
         pairs = k // 2
-        x1 = cand[:, :, 0:2 * pairs:2]                      # (c,B,pairs,t)
-        x2 = cand[:, :, 1:2 * pairs:2]
-        # SS-SUB(lhs, rhs) opens [rhs < lhs]: min wants s = [x2 < x1]
-        # (lhs=x1), max wants s = [x1 < x2] (lhs=x2); either way the
-        # winner is x1 + s·(x2 − x1).
-        lhs = jnp.where(is_min, x1, x2)
-        rhs = jnp.where(is_min, x2, x1)
+        x1, x2, lhs, rhs = _pair_level(cand, is_min, pairs)
         carry = None
         carry_deg = 0
         s_bits = None
@@ -394,8 +406,7 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
             s_bits, carry = segment(lhs[..., s0:s1], rhs[..., s0:s1],
                                     carry)
             carry_deg = carry_deg + 2 * cand_deg * (s1 - s0)
-        win = field.add(x1, field.mul(s_bits[..., None],
-                                      field.sub(x2, x1)))
+        win = _select_winner(x1, x2, s_bits)
         win_deg = carry_deg + cand_deg
         for j in jobs:
             j.ledger.cloud(2 * pairs * t_bits)
@@ -420,7 +431,7 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
     val_parts = [Shares(cand[:, i, 0], cand_deg) for i in range(b)]
     cnt_parts = {i: Shares(counts.values[:, kk], counts.degree)
                  for kk, i in enumerate(cond)}
-    opened = _fused_interpolate(val_parts + [cnt_parts[i] for i in cond])
+    opened = _open_on_host(val_parts + [cnt_parts[i] for i in cond])
 
     for i, j in enumerate(jobs):
         j.ledger.recv(c * t_bits)

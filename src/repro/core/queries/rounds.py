@@ -238,25 +238,12 @@ def _share_one_hot(key: jax.Array, db: SecretSharedDB,
                                   degree=db.base_degree)
 
 
-def _fused_interpolate(parts: Sequence[Shares]) -> List[np.ndarray]:
-    """User step: interpolate many share tensors with ONE Lagrange pass per
-    (degree, cloud-count) class — the fused batch equivalent of calling
-    ``shamir.interpolate`` once per tensor. Returns decoded numpy arrays in
-    input order."""
-    out: List[Optional[np.ndarray]] = [None] * len(parts)
-    by_class: Dict[Tuple[int, int], List[int]] = {}
-    for i, s in enumerate(parts):
-        by_class.setdefault((s.degree, s.n_shares), []).append(i)
-    for (deg, c), idxs in by_class.items():
-        flats = [parts[i].values.reshape(c, -1) for i in idxs]
-        vals = np.asarray(shamir.interpolate(
-            Shares(jnp.concatenate(flats, axis=1), deg)))
-        off = 0
-        for i in idxs:
-            size = int(np.prod(parts[i].shape, dtype=np.int64))
-            out[i] = vals[off:off + size].reshape(parts[i].shape)
-            off += size
-    return out
+def _open_on_host(parts: Sequence[Shares]) -> List[np.ndarray]:
+    """User step: open many share tensors. Only the degree+1 shares the
+    user needs leave the clouds, and the Lagrange sum runs on the user's
+    host copy of them (``shamir.interpolate_host``), so opening compiles
+    no device program. Returns decoded numpy arrays in input order."""
+    return [shamir.interpolate_host(s) for s in parts]
 
 
 def _share_patterns(db: SecretSharedDB, jobs: Sequence[MatchJob]) -> Shares:
@@ -620,7 +607,7 @@ def count_phase(be, db: RelationLike, jobs: Sequence[MatchJob]
     parts = mp.bit_shares(be, plane)
     sums = [Shares(field.sum_(sh.values, axis=2), sh.degree)
             for _, sh in parts]
-    vals = _fused_interpolate(sums)
+    vals = _open_on_host(sums)
     out = [0] * len(jobs)
     deg_of: Dict[int, int] = {}
     for (idxs, sh), v in zip(parts, vals):
@@ -716,7 +703,7 @@ def match_all_round(be, db: RelationLike, jobs: Sequence[MatchJob]
     # cross-group fetch_fusion matmul as everything else
     mp = _MatcherPlan(db, jobs)
     parts = mp.bit_shares(be, plane)
-    vals = _fused_interpolate([sh for _, sh in parts])
+    vals = _open_on_host([sh for _, sh in parts])
     out: List[List[int]] = [[] for _ in jobs]
     deg_of: Dict[int, int] = {}
     for (idxs, sh), v in zip(parts, vals):
@@ -892,7 +879,7 @@ def _tree_block_round(be, plane, p_all, columns, exact_slot, cached,
             address_weights=address_weights))
     parts += _block_sums_cached(cached, pat_meta,
                                 address_weights=address_weights)
-    vals = _fused_interpolate(parts)
+    vals = _open_on_host(parts)
     vals_by_entry: Dict[Tuple[int, int, int], int] = {}
     deg_by_job: Dict[int, int] = {}
     vi = 0
@@ -1266,8 +1253,8 @@ def join_emit_round(db: RelationLike, jobs: Sequence[JoinJob],
         j.ledger.recv(c * ny * (mx + my) * w_len * a_len)
         xs_parts.append(fx)
         ys_parts.append(y_part)
-    xs_all = _fused_interpolate(xs_parts)
-    ys_all = _fused_interpolate(ys_parts)
+    xs_all = _open_on_host(xs_parts)
+    ys_all = _open_on_host(ys_parts)
 
     results: List[List[List[str]]] = []
     for j, fx, yp, xs, ys in zip(jobs, xs_parts, ys_parts, xs_all, ys_all):
@@ -1334,7 +1321,7 @@ def equijoin_rounds(be, db: RelationLike, jobs: Sequence[EquiJob]
         j.ledger.recv(c * nx * w_len * a_len
                       + j.right.n_shares * j.right.n_tuples * w_len * a_len)
         col_parts += [bx, by]
-    opened = _fused_interpolate(col_parts)
+    opened = _open_on_host(col_parts)
     val_lists: List[Tuple[List[str], List[str]]] = []
     for i, j in enumerate(jobs):
         bx, by = col_parts[2 * i], col_parts[2 * i + 1]
@@ -1411,8 +1398,8 @@ def equijoin_rounds(be, db: RelationLike, jobs: Sequence[EquiJob]
         xs_parts.append(pairs_x)
         ys_parts.append(pairs_y)
         metas.append((j, lx * ly))
-    xs_all = _fused_interpolate(xs_parts)
-    ys_all = _fused_interpolate(ys_parts)
+    xs_all = _open_on_host(xs_parts)
+    ys_all = _open_on_host(ys_parts)
 
     by_job: Dict[int, List[List[str]]] = {id(j): [] for j in jobs}
     for (j, n_pairs), xs, ys in zip(metas, xs_all, ys_all):

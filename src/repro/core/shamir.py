@@ -178,26 +178,44 @@ def _lagrange_at_zero_np(points: tuple) -> np.ndarray:
     return np.asarray(lams, dtype=np.uint32)
 
 
-def lagrange_coeffs(n_points: int, points: Optional[tuple] = None) -> jax.Array:
-    pts = points if points is not None else tuple(range(1, n_points + 1))
-    return jnp.asarray(_lagrange_at_zero_np(tuple(int(x) for x in pts)))
-
-
 def interpolate(shares: Shares, *, points: Optional[tuple] = None) -> jax.Array:
     """Reconstruct secrets from the first ``degree+1`` shares (or all).
 
     Uses exactly ``degree+1`` shares when available — the user contacts c′
     clouds, not all c (paper §2).
     """
+    need = _shares_needed(shares)
+    pts = points if points is not None else tuple(range(1, need + 1))
+    return _open_at_zero(shares.values, tuple(int(x) for x in pts))
+
+
+def interpolate_host(shares: Shares) -> np.ndarray:
+    """``interpolate`` as the user runs it: the first ``degree+1`` shares
+    are copied to the host and opened in numpy — no device program. Exact:
+    each λ_j·v_j < 2⁶² is reduced before the ≤ c-term sum."""
+    need = _shares_needed(shares)
+    lam = _lagrange_at_zero_np(tuple(range(1, need + 1))).astype(np.uint64)
+    vals = np.asarray(shares.values[:need]).astype(np.uint64)
+    lam = lam.reshape((need,) + (1,) * (vals.ndim - 1))
+    return ((lam * vals % P).sum(axis=0) % P).astype(np.uint32)
+
+
+def _shares_needed(shares: Shares) -> int:
     need = shares.degree + 1
     if shares.n_shares < need:
         raise ValueError(
             f"need {need} shares to open a degree-{shares.degree} sharing, "
             f"have {shares.n_shares}")
-    vals = shares.values[:need]
-    pts = points if points is not None else tuple(range(1, need + 1))
-    lam = lagrange_coeffs(need, pts)                       # (c',)
-    lam = lam.reshape((need,) + (1,) * (vals.ndim - 1))
+    return need
+
+
+@functools.partial(jax.jit, static_argnames=("pts",))
+def _open_at_zero(values: jax.Array, pts: tuple) -> jax.Array:
+    """Σ_j λ_j·values[j] over the first len(pts) shares: one program per
+    shape instead of one per eager op."""
+    vals = values[:len(pts)]
+    lam = jnp.asarray(_lagrange_at_zero_np(pts))           # (c',)
+    lam = lam.reshape((len(pts),) + (1,) * (vals.ndim - 1))
     return field.sum_(field.mul(vals, jnp.broadcast_to(lam, vals.shape)),
                       axis=0)
 
@@ -267,11 +285,18 @@ def reduce_degree(key: jax.Array, shares: Shares, *, target_degree: int = 1
     need = d + 1
     if c < need:
         raise ValueError(f"cannot reduce degree {d} with only {c} shares")
-    lam = lagrange_coeffs(need)                                 # (d+1,)
+    return Shares(_reshare(key, shares.values, need=need,
+                           target_degree=target_degree), target_degree)
+
+
+@functools.partial(jax.jit, static_argnames=("need", "target_degree"))
+def _reshare(key: jax.Array, values: jax.Array, *, need: int,
+             target_degree: int) -> jax.Array:
+    c = values.shape[0]
+    lam = jnp.asarray(_lagrange_at_zero_np(tuple(range(1, need + 1))))
     # sub[k, j, ...] = share_{k -> j}
-    sub = make_shares(key, shares.values[:need], n_shares=c,
+    sub = make_shares(key, values[:need], n_shares=c,
                       degree=target_degree)                     # (c, d+1, ...)
-    lam_b = lam.reshape((1, need) + (1,) * (shares.values.ndim - 1))
-    new_vals = field.sum_(
+    lam_b = lam.reshape((1, need) + (1,) * (values.ndim - 1))
+    return field.sum_(
         field.mul(sub, jnp.broadcast_to(lam_b, sub.shape)), axis=1)
-    return Shares(new_vals, target_degree)

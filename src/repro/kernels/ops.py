@@ -1,26 +1,19 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Handle cloud-axis batching (vmap), interpret-mode selection (interpret=True
-everywhere except a real TPU backend), and the join-oriented composite
-``match_matrix``.
+Handle cloud-axis batching, and the join-oriented composite
+``match_matrix``. Every kernel picks compiled Mosaic or the Pallas
+interpreter from the platform (``ss_matmul.interpret_default``); a kernel
+that fails to lower raises.
 """
 from __future__ import annotations
-
-import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 
-from .aa_match import (aa_match_batch_pallas, aa_match_pallas,
-                       aa_slide_batch_pallas)
+from .aa_match import aa_match_batch_pallas, aa_slide_batch_pallas
 from .ripple import ripple_carry_pallas, ripple_segment_pallas
 from .ss_matmul import (is_tall_skinny, share_onehot_pallas, ss_matmul_pallas,
                         ss_matmul_tall_pallas)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @jax.jit
@@ -32,12 +25,10 @@ def ss_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     square 128³ tiles. Both are the same kernel body, so results are
     bit-identical either way.
     """
-    interp = _interpret()
-
     def fn(x, y):
         if is_tall_skinny(x.shape[0], x.shape[1], y.shape[1]):
-            return ss_matmul_tall_pallas(x, y, interpret=interp)
-        return ss_matmul_pallas(x, y, interpret=interp)
+            return ss_matmul_tall_pallas(x, y)
+        return ss_matmul_pallas(x, y)
 
     if a.ndim == 2 and b.ndim == 2:
         return fn(a, b)
@@ -53,44 +44,26 @@ def share_onehot(tokens: jax.Array, a1: jax.Array, *,
     """Fused degree-1 one-hot share generation (embedding fast path):
     tokens (M,) int32 + per-token coefficients a1 (M, V) uint32 ->
     share tensor (n_shares, M, V), never materializing the one-hot."""
-    return share_onehot_pallas(tokens, a1, n_shares=n_shares,
-                               interpret=_interpret())
+    return share_onehot_pallas(tokens, a1, n_shares=n_shares)
 
 
 @jax.jit
 def aa_match(col: jax.Array, pat: jax.Array) -> jax.Array:
-    """Batched AA match. col: ([c,] n, W, A), pat: ([c,] W, A) -> ([c,] n)."""
-    interp = _interpret()
-    fn = functools.partial(aa_match_pallas, interpret=interp)
+    """Batched AA match. col: ([c,] n, W, A), pat: ([c,] W, A) -> ([c,] n).
+    The cloud axis rides the kernel's batch grid axis."""
     if col.ndim == 3:
-        return fn(col, pat)
+        return aa_match_batch_pallas(col[None], pat[None])[0]
     if col.ndim == 4:
-        return jax.vmap(fn)(col, pat)
+        return aa_match_batch_pallas(col, pat)
     raise ValueError(f"unsupported rank: {col.shape}")
-
-
-@jax.jit
-def aa_match_batch_vmap(col: jax.Array, pat: jax.Array) -> jax.Array:
-    """Nested-vmap fallback for the stacked-predicate AA match: one kernel
-    launch per (c, B) cell. Kept as the safety net (and the parity oracle)
-    for the 2-D grid kernel below."""
-    interp = _interpret()
-    fn = functools.partial(aa_match_pallas, interpret=interp)
-    if col.ndim != 5:
-        raise ValueError(f"unsupported rank: {col.shape}")
-    return jax.vmap(jax.vmap(fn))(col, pat)
 
 
 @jax.jit
 def _aa_match_batch_grid(col: jax.Array, pat: jax.Array) -> jax.Array:
     c, b, n, w, a = col.shape
     out = aa_match_batch_pallas(col.reshape(c * b, n, w, a),
-                                pat.reshape(c * b, w, a),
-                                interpret=_interpret())
+                                pat.reshape(c * b, w, a))
     return out.reshape(c, b, n)
-
-
-_GRID_KERNEL_BROKEN = False
 
 
 def aa_match_batch(col: jax.Array, pat: jax.Array) -> jax.Array:
@@ -98,26 +71,14 @@ def aa_match_batch(col: jax.Array, pat: jax.Array) -> jax.Array:
     -> (c, B, n). The cloud and batch axes fold into ONE 2-D grid
     ``pallas_call`` — a (c·B, n-tile) grid whose pattern tile stays
     resident in VMEM across a row's n-tiles — so the batched query engine
-    really issues a single device dispatch per protocol round. If the grid
-    kernel fails to lower on this backend, the failure is logged once and
-    all later calls take the nested-vmap path directly (a failed jit trace
-    is not cached, so retrying every round would re-pay the trace)."""
-    global _GRID_KERNEL_BROKEN
+    really issues a single device dispatch per protocol round."""
     if col.ndim != 5:
         raise ValueError(f"unsupported rank: {col.shape}")
     c, b, _, w, a = col.shape
-    if pat.shape != (c, b, w, a):   # caller bugs must propagate, not latch
+    if pat.shape != (c, b, w, a):
         raise ValueError(f"pattern shape {pat.shape} does not match "
                          f"column stack {col.shape}")
-    if not _GRID_KERNEL_BROKEN:
-        try:
-            return _aa_match_batch_grid(col, pat)
-        except Exception as e:   # pragma: no cover — exotic backends only
-            _GRID_KERNEL_BROKEN = True
-            warnings.warn(f"aa_match_batch 2-D grid kernel failed to build "
-                          f"({e!r}); using the nested-vmap fallback for "
-                          f"the rest of this process", RuntimeWarning)
-    return aa_match_batch_vmap(col, pat)
+    return _aa_match_batch_grid(col, pat)
 
 
 @jax.jit
@@ -125,40 +86,24 @@ def _aa_slide_batch_grid(cols: jax.Array, pats: jax.Array) -> jax.Array:
     c, b, n, w, a = cols.shape
     k = pats.shape[-2]
     out = aa_slide_batch_pallas(cols.reshape(c * b, n, w, a),
-                                pats.reshape(c * b, k, a),
-                                interpret=_interpret())
+                                pats.reshape(c * b, k, a))
     return out.reshape(c, b, n, w - k + 1)
-
-
-_SLIDE_KERNEL_BROKEN = False
 
 
 def aa_slide_batch(cols: jax.Array, pats: jax.Array) -> jax.Array:
     """Stacked sliding-window AA match: cols (c, B, n, W, A), pats
     (c, B, k, A) -> (c, B, n, M) raw window-chain products, M = W−k+1.
     Cloud and batch axes fold into one (c·B, n-tile) 2-D grid
-    ``pallas_call`` reusing the ``aa_match_batch`` VMEM pattern-tile
-    layout. On lowering failure the jnp reference program takes over for
-    the rest of the process (same latch protocol as ``aa_match_batch``)."""
-    global _SLIDE_KERNEL_BROKEN
+    ``pallas_call``, the same kernel as ``aa_match_batch``."""
     if cols.ndim != 5 or pats.ndim != 4:
         raise ValueError(f"unsupported ranks: {cols.shape}, {pats.shape}")
     c, b, _, w, a = cols.shape
     k = pats.shape[-2]
     if (pats.shape[0], pats.shape[1], pats.shape[3]) != (c, b, a) \
-            or not 1 <= k <= w:  # caller bugs must propagate, not latch
+            or not 1 <= k <= w:
         raise ValueError(f"pattern tile shape {pats.shape} does not match "
                          f"column stack {cols.shape}")
-    if not _SLIDE_KERNEL_BROKEN:
-        try:
-            return _aa_slide_batch_grid(cols, pats)
-        except Exception as e:   # pragma: no cover — exotic backends only
-            _SLIDE_KERNEL_BROKEN = True
-            warnings.warn(f"aa_slide_batch 2-D grid kernel failed to build "
-                          f"({e!r}); using the jnp reference for the rest "
-                          f"of this process", RuntimeWarning)
-    from ..api.backends import jnp_aa_slide   # reference fallback
-    return jnp_aa_slide(cols, pats)
+    return _aa_slide_batch_grid(cols, pats)
 
 
 def ripple_carry(a: jax.Array, b: jax.Array, carry=None):
@@ -167,15 +112,13 @@ def ripple_carry(a: jax.Array, b: jax.Array, carry=None):
     a, b: (...,) uint32 bit planes; carry: same shape or ``None`` for the
     LSB step. Returns ``(rb, carry')``. Flattens to one 1-D elementwise
     pallas dispatch regardless of how many queries are stacked."""
-    interp = _interpret()
     shape = a.shape
     flat_a = a.reshape(-1)
     flat_b = b.reshape(-1)
     init = carry is None
     flat_c = (jnp.zeros_like(flat_a) if init
               else carry.reshape(-1))
-    rb, co = ripple_carry_pallas(flat_a, flat_b, flat_c, init=init,
-                                 interpret=interp)
+    rb, co = ripple_carry_pallas(flat_a, flat_b, flat_c, init=init)
     return rb.reshape(shape), co.reshape(shape)
 
 
@@ -187,7 +130,6 @@ def ripple_segment(a: jax.Array, b: jax.Array, carry=None):
     Returns the final ``(rb, carry')`` after k steps, each shaped (...).
     The carry chains in registers inside the kernel, so a degree-reduction
     interval of k bits costs one launch instead of k."""
-    interp = _interpret()
     shape = a.shape[:-1]
     k = a.shape[-1]
     flat_a = jnp.moveaxis(a.reshape(-1, k), -1, 0)     # (k, N)
@@ -195,8 +137,7 @@ def ripple_segment(a: jax.Array, b: jax.Array, carry=None):
     init = carry is None
     flat_c = (jnp.zeros(flat_a.shape[1:], flat_a.dtype) if init
               else carry.reshape(-1))
-    rb, co = ripple_segment_pallas(flat_a, flat_b, flat_c, init=init,
-                                   interpret=interp)
+    rb, co = ripple_segment_pallas(flat_a, flat_b, flat_c, init=init)
     return rb.reshape(shape), co.reshape(shape)
 
 

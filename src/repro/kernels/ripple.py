@@ -30,34 +30,36 @@ axis, flattened lanes on the 128-wide lane axis.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ss_matmul import P32, _addmod, _mulmod, _round_up
+from ..core.field import P, addmod32, mulmod32
+from .ss_matmul import ZERO, _round_up, interpret_default
 
 
 def _submod(x: jax.Array, y: jax.Array) -> jax.Array:
     """(x − y) mod p for x, y < p, in 32-bit lanes."""
-    return _addmod(x, jnp.where(y == 0, y, P32 - y))
+    return addmod32(x, jnp.where(y == 0, y, P - y))
 
 
 def _ripple_kernel(a_ref, b_ref, c_ref, rb_ref, co_ref, *, init: bool):
     a = a_ref[...]
     b = b_ref[...]
     ai = _submod(jnp.ones_like(a), a)
-    ab = _mulmod(ai, b)
-    s = _addmod(ai, b)
+    ab = mulmod32(ai, b)
+    s = addmod32(ai, b)
     if init:
         carry = _submod(s, ab)
-        rb = _submod(s, _addmod(carry, carry))
+        rb = _submod(s, addmod32(carry, carry))
     else:
         carry_in = c_ref[...]
-        x = _submod(s, _addmod(ab, ab))
-        cx = _mulmod(carry_in, x)
-        carry = _addmod(ab, cx)
-        rb = _submod(_addmod(x, carry_in), _addmod(cx, cx))
+        x = _submod(s, addmod32(ab, ab))
+        cx = mulmod32(carry_in, x)
+        carry = addmod32(ab, cx)
+        rb = _submod(addmod32(x, carry_in), addmod32(cx, cx))
     rb_ref[...] = rb
     co_ref[...] = carry
 
@@ -65,7 +67,7 @@ def _ripple_kernel(a_ref, b_ref, c_ref, rb_ref, co_ref, *, init: bool):
 @functools.partial(jax.jit, static_argnames=("bn", "init", "interpret"))
 def ripple_carry_pallas(a: jax.Array, b: jax.Array, carry: jax.Array, *,
                         bn: int = 4096, init: bool = False,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """a, b, carry: flat (N,) uint32 share planes -> (rb, carry') each (N,).
 
     ``init=True`` runs the LSB step (``carry`` is ignored but must be
@@ -81,7 +83,7 @@ def ripple_carry_pallas(a: jax.Array, b: jax.Array, carry: jax.Array, *,
         in_specs=[pl.BlockSpec((bn,), lambda i: (i,))] * 3,
         out_specs=[pl.BlockSpec((bn,), lambda i: (i,))] * 2,
         out_shape=[jax.ShapeDtypeStruct((n_pad,), jnp.uint32)] * 2,
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(jnp.pad(a, pad), jnp.pad(b, pad), jnp.pad(carry, pad))
     return out[0][:n], out[1][:n]
 
@@ -95,16 +97,16 @@ def _ripple_segment_kernel(a_ref, b_ref, c_ref, rb_ref, co_ref, *,
         a = a_ref[i, :]
         b = b_ref[i, :]
         ai = _submod(jnp.ones_like(a), a)
-        ab = _mulmod(ai, b)
-        s = _addmod(ai, b)
+        ab = mulmod32(ai, b)
+        s = addmod32(ai, b)
         if init and i == 0:
             carry = _submod(s, ab)
-            rb = _submod(s, _addmod(carry, carry))
+            rb = _submod(s, addmod32(carry, carry))
         else:
-            x = _submod(s, _addmod(ab, ab))
-            cx = _mulmod(carry, x)
-            rb = _submod(_addmod(x, carry), _addmod(cx, cx))
-            carry = _addmod(ab, cx)
+            x = _submod(s, addmod32(ab, ab))
+            cx = mulmod32(carry, x)
+            rb = _submod(addmod32(x, carry), addmod32(cx, cx))
+            carry = addmod32(ab, cx)
     rb_ref[0, :] = rb
     co_ref[0, :] = carry
 
@@ -112,7 +114,7 @@ def _ripple_segment_kernel(a_ref, b_ref, c_ref, rb_ref, co_ref, *,
 @functools.partial(jax.jit, static_argnames=("bn", "init", "interpret"))
 def ripple_segment_pallas(a: jax.Array, b: jax.Array, carry: jax.Array, *,
                           bn: int = 4096, init: bool = False,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """a, b: (k, N) bit planes (k = consecutive bit positions, N flattened
     lanes); carry: (N,) -> final ``(rb, carry')`` each (N,) after k chained
     steps in ONE kernel launch.
@@ -127,12 +129,12 @@ def ripple_segment_pallas(a: jax.Array, b: jax.Array, carry: jax.Array, *,
     out = pl.pallas_call(
         functools.partial(_ripple_segment_kernel, k=k, init=init),
         grid=(n_pad // bn,),
-        in_specs=[pl.BlockSpec((k, bn), lambda i: (0, i)),
-                  pl.BlockSpec((k, bn), lambda i: (0, i)),
-                  pl.BlockSpec((1, bn), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1, bn), lambda i: (0, i))] * 2,
+        in_specs=[pl.BlockSpec((k, bn), lambda i: (ZERO, i)),
+                  pl.BlockSpec((k, bn), lambda i: (ZERO, i)),
+                  pl.BlockSpec((1, bn), lambda i: (ZERO, i))],
+        out_specs=[pl.BlockSpec((1, bn), lambda i: (ZERO, i))] * 2,
         out_shape=[jax.ShapeDtypeStruct((1, n_pad), jnp.uint32)] * 2,
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(jnp.pad(a, pad2), jnp.pad(b, pad2),
       jnp.pad(carry, pad1).reshape(1, n_pad))
     return out[0][0, :n], out[1][0, :n]

@@ -1,20 +1,16 @@
 """Pallas TPU kernel: mod-p matmul over F_p, p = 2³¹−1 (Mersenne-31).
 
-TPU adaptation (see DESIGN.md §2): the MXU multiplies bf16/int8 — it cannot
-form 62-bit integer products — so modular matmul on TPU is a **VPU**
-(vector-unit) workload in 32-bit lanes. We therefore:
+The MXU cannot form 62-bit integer products, but it multiplies int8 exactly
+into int32. So each 31-bit operand splits into four signed 8-bit limbs
+(``field.digits8``) and a tile product becomes 16 int8 MXU dots, folded back
+into [0, p) with Mersenne rotates (2³¹ ≡ 1) — ``field.limb_contract``, the
+same algorithm ``field.matmul`` runs over whole arrays. (bm × bk) · (bk × bn)
+blocks tile into VMEM with an explicit BlockSpec grid, accumulating mod p in
+a VMEM scratch across the K grid axis (K is the innermost grid dimension, so
+the scratch carries). A tile's int32 sums stay exact while bk ≤ 2¹⁶.
 
-  * decompose each 31-bit operand into 16-bit limbs
-    ``x = x1·2¹⁶ + x0`` (x1 < 2¹⁵, x0 < 2¹⁶), so every partial product fits
-    a 32-bit lane:  ``x·y = x1y1·2³² + (x1y0 + x0y1)·2¹⁶ + x0y0``;
-  * exploit the Mersenne congruences ``2³¹ ≡ 1, 2³² ≡ 2 (mod p)`` to fold
-    the limb products back into [0, p) with shifts/adds only — no division;
-  * tile (bm × bk) · (bk × bn) blocks into VMEM with an explicit BlockSpec
-    grid, accumulating mod-p in a VMEM scratch across the K grid axis
-    (K is the innermost/fastest grid dimension, so the scratch carries).
-
-VMEM budget per grid cell (defaults bm = bn = bk = 128, uint32):
-  a-tile 64 KiB + b-tile 64 KiB + scratch 64 KiB + out 64 KiB = 256 KiB ≪ 16 MiB,
+VMEM per grid cell at the defaults bm = bn = bk = 128: a/b tiles 64 KiB each,
+their limbs 64 KiB, scratch + out 128 KiB — far below the scoped limit,
 leaving room for double-buffered pipelining of the next a/b tiles.
 """
 from __future__ import annotations
@@ -28,55 +24,33 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-P32 = np.uint32(2**31 - 1)
-MASK16 = np.uint32(0xFFFF)
-MASK15 = np.uint32(0x7FFF)
+from ..core.field import LIMB_K_MAX, addmod32, limb_contract, mulmod32
+
+#: int32 block index for ``BlockSpec`` index maps: a bare ``0`` becomes an
+#: int64 under the process-wide x64 flag, which Mosaic cannot return.
+ZERO = np.int32(0)
 
 
-def _fold32(x: jax.Array) -> jax.Array:
-    """uint32 -> [0, p): one Mersenne fold + conditional subtract."""
-    x = (x & P32) + (x >> np.uint32(31))                  # < p + 2
-    return x - jnp.where(x >= P32, P32, np.uint32(0))
+def interpret_default(interpret: Optional[bool]) -> bool:
+    """``None`` -> the platform decides: compiled Mosaic on a TPU, the Pallas
+    interpreter everywhere else (CPU/GPU have no Mosaic lowering)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
-def _addmod(a: jax.Array, b: jax.Array) -> jax.Array:
-    """(a + b) mod p for a, b < p. a+b < 2p < 2³², no wrap."""
-    s = a + b
-    return s - jnp.where(s >= P32, P32, np.uint32(0))
+def _dot(x, y):
+    return jnp.dot(x, y, preferred_element_type=jnp.int32)
 
 
-def _mulmod(x: jax.Array, y: jax.Array) -> jax.Array:
-    """(x · y) mod p for x, y < p, entirely in 32-bit lanes."""
-    x0 = x & MASK16
-    x1 = x >> np.uint32(16)          # < 2^15
-    y0 = y & MASK16
-    y1 = y >> np.uint32(16)
-    lo = x0 * y0                     # < 2^32, exact in uint32
-    mid = x1 * y0 + x0 * y1          # each < 2^31, sum < 2^32
-    hi = x1 * y1                     # < 2^30
-    # mid·2¹⁶ mod p: mid = mh·2¹⁵ + ml  ⇒  mh·2³¹ + ml·2¹⁶ ≡ mh + ml·2¹⁶
-    t_mid = (mid >> np.uint32(15)) + ((mid & MASK15) << np.uint32(16))
-    # lo mod p: lo = lh·2³¹ + ll ⇒ lh + ll
-    t_lo = (lo >> np.uint32(31)) + (lo & P32)
-    # hi·2³² ≡ 2·hi
-    t_hi = hi << np.uint32(1)
-    return _addmod(_addmod(_fold32(t_mid), _fold32(t_lo)), _fold32(t_hi))
-
-
-def _ss_matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, bk: int, nk: int):
+def _ss_matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     """One (i, j, k) grid cell: acc += A[i,k] ·ₚ B[k,j]; emit at last k."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]                                  # (bm, bk) uint32
-    b = b_ref[...]                                  # (bk, bn)
-
-    def body(k, acc):
-        prod = _mulmod(a[:, k][:, None], b[k, :][None, :])   # (bm, bn)
-        return _addmod(acc, prod)
-
-    acc_ref[...] = jax.lax.fori_loop(0, bk, body, acc_ref[...])
+    acc_ref[...] = addmod32(acc_ref[...],
+                            limb_contract(a_ref[...], b_ref[...], _dot))
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _emit():
@@ -88,19 +62,16 @@ def ss_matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
                      bn: int = 128, bk: int = 128,
                      interpret: Optional[bool] = None) -> jax.Array:
     """(M,K) @ (K,N) mod p. Pads to block multiples (zeros are absorbing).
-
-    ``interpret=None`` auto-detects: compiled lowering on a real TPU
-    backend, the Pallas interpreter everywhere else (CPU/GPU have no
-    Mosaic lowering for these kernels).
+    ``interpret=None`` lets the platform decide (:func:`interpret_default`).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
+    assert bk <= LIMB_K_MAX, bk
     if m == 0 or n == 0:        # empty fetch stack / empty relation slice
         return jnp.zeros((m, n), jnp.uint32)
-    bm = min(bm, _round_up(max(m, 1), 8))
+    # int8 limb tiles are (32, 128)-native on the MXU path
+    bm = min(bm, _round_up(max(m, 1), 32))
     bn = min(bn, _round_up(max(n, 1), 128))
     bk = min(bk, _round_up(max(k, 1), 128))
     mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
@@ -108,7 +79,7 @@ def ss_matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
     b_p = jnp.pad(b, ((0, kp - k), (0, np_ - n)))
     nk = kp // bk
     out = pl.pallas_call(
-        functools.partial(_ss_matmul_kernel, bk=bk, nk=nk),
+        functools.partial(_ss_matmul_kernel, nk=nk),
         grid=(mp // bm, np_ // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -117,7 +88,7 @@ def ss_matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.uint32)],
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(a_p, b_p)
     return out[:m, :n]
 
@@ -156,7 +127,7 @@ def ss_matmul_tall_pallas(a: jax.Array, b: jax.Array, *,
     """
     m, k = a.shape
     n = b.shape[1]
-    bm = min(_round_up(max(m, 1), 8), TALL_MAX_M)
+    bm = min(_round_up(max(m, 1), 32), TALL_MAX_M)
     bn = min(_round_up(max(n, 1), 128), 128)
     bk = min(_round_up(max(k, 1), 128), 512)
     return ss_matmul_pallas(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret)
@@ -178,7 +149,7 @@ def _share_onehot_kernel(tok_ref, a1_ref, o_ref, *, bm: int, bv: int):
              + j * np.int32(bv))
     onehot = jnp.where(v_ids == tok, np.uint32(1), np.uint32(0))
     xk = (kc + 1).astype(jnp.uint32)                # eval point, < c+1 ≪ p
-    o_ref[...] = _addmod(onehot, _mulmod(a1, xk))[None]
+    o_ref[...] = addmod32(onehot, mulmod32(a1, xk))[None]
 
 
 @functools.partial(jax.jit,
@@ -196,8 +167,6 @@ def share_onehot_pallas(tokens: jax.Array, a1: jax.Array, *, n_shares: int,
     Padding: token rows pad with -1 (matches no vocab id ⇒ zero one-hot),
     coefficients pad with 0 ⇒ padded share cells are 0 and slice away.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     (m,) = tokens.shape
     m2, v = a1.shape
     assert m == m2, (tokens.shape, a1.shape)
@@ -211,11 +180,11 @@ def share_onehot_pallas(tokens: jax.Array, a1: jax.Array, *, n_shares: int,
         functools.partial(_share_onehot_kernel, bm=bm, bv=bv),
         grid=(n_shares, mp // bm, vp // bv),
         in_specs=[
-            pl.BlockSpec((bm, 1), lambda kc, i, j: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda kc, i, j: (i, ZERO)),
             pl.BlockSpec((bm, bv), lambda kc, i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bv), lambda kc, i, j: (kc, i, j)),
         out_shape=jax.ShapeDtypeStruct((n_shares, mp, vp), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(tok_p, a1_p)
     return out[:, :m, :v]

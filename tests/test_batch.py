@@ -21,6 +21,7 @@ from repro.api.backends import Backend, batched_matcher, ripple_stepper
 from repro.core import outsource, Codec
 from repro.core.queries import CardinalityError, select_tree
 from repro.core import shamir
+from repro.core.queries import rounds
 from repro.runtime import MapReduceRunner, WorkerPool
 
 CODEC = Codec(word_length=8)
@@ -70,14 +71,19 @@ def _counting_backend(name="jnp"):
 
 
 def _count_interpolations(monkeypatch):
+    """Count user-side opens: ``shamir.interpolate`` on the device and
+    ``rounds._open_on_host``, one host open per phase."""
     counter = {"n": 0}
-    real = shamir.interpolate
 
-    def counting(shares, **kw):
-        counter["n"] += 1
-        return real(shares, **kw)
+    def counting(real):
+        def wrapped(*args, **kw):
+            counter["n"] += 1
+            return real(*args, **kw)
+        return wrapped
 
-    monkeypatch.setattr(shamir, "interpolate", counting)
+    monkeypatch.setattr(shamir, "interpolate", counting(shamir.interpolate))
+    monkeypatch.setattr(rounds, "_open_on_host",
+                        counting(rounds._open_on_host))
     return counter
 
 

@@ -81,3 +81,57 @@ def test_uniform_in_range():
     assert x.max() < P
     # crude uniformity: mean within 2% of p/2
     assert abs(float(x.mean()) / (P / 2) - 1.0) < 0.02
+
+
+def test_uniform_is_62_bit_draw_mod_p():
+    # the uint32-lane reduction must equal the 62-bit draw reduced mod p
+    key = jax.random.PRNGKey(7)
+    bits = np.asarray(jax.random.bits(key, (3, 1000), dtype=np.uint64))
+    want = (bits >> np.uint64(2)) % np.uint64(P)
+    assert np.array_equal(np.asarray(field.uniform(key, (3, 1000))), want)
+
+
+def test_to_field_folds_like_modulo():
+    x = np.array([0, 1, P - 1, P, P + 1, 2**32 - 1, 2**62 - 1, 2**64 - 1,
+                  P * 5], dtype=np.uint64)
+    assert np.array_equal(np.asarray(field.to_field(x)), x % np.uint64(P))
+    s = np.array([-1, -P, -P - 1, 0, 5], dtype=np.int64)
+    assert np.array_equal(np.asarray(field.to_field(s)),
+                          [(int(v) % P) for v in s])
+
+
+@pytest.mark.parametrize("k,fill", [
+    (field.LIMB_K_MAX, P - 1),            # one chunk at the exactness bound
+    (field.LIMB_K_MAX + 3, P - 1),        # two chunks summed mod p
+    (field.LIMB_K_MAX + 3, None),         # random operands across chunks
+])
+def test_matmul_limb_chunks_exact(k, fill):
+    rng = np.random.default_rng(1)
+    if fill is None:
+        a = rng.integers(0, P, size=(2, k), dtype=np.uint64)
+        b = rng.integers(0, P, size=(k, 3), dtype=np.uint64)
+    else:
+        a = np.full((2, k), fill, np.uint64)
+        b = np.full((k, 3), fill, np.uint64)
+    want = (a.astype(object) @ b.astype(object)) % P
+    got = np.asarray(field.matmul(a.astype(np.uint32), b.astype(np.uint32)))
+    assert np.array_equal(got.astype(object), want)
+
+
+def test_slide_chain_at_xla_cpu_fold_shape():
+    # At this shape (27 clouds, 256 tuples, W=12, A=69, k=4) XLA:CPU
+    # (jax 0.9.0) returned one wrong window product, at tuple 0 of one
+    # cloud, when sum_ reduced its uint64 total by the Mersenne fold
+    # instead of `%`. sum_ keeps `%` until that is understood; this pins the
+    # program that showed it.
+    from repro.api.backends import jnp_aa_slide
+    rng = np.random.default_rng(0)
+    cols = rng.integers(0, P, size=(27, 1, 256, 12, 69), dtype=np.uint32)
+    pats = rng.integers(0, P, size=(27, 1, 4, 69), dtype=np.uint32)
+    p64 = np.uint64(P)
+    want = None
+    for j in range(4):
+        v = (cols[..., j:j + 9, :].astype(np.uint64)
+             * pats[:, :, None, None, j, :] % p64).sum(-1) % p64
+        want = v if want is None else want * v % p64
+    assert np.array_equal(np.asarray(jnp_aa_slide(cols, pats)), want)
