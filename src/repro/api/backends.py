@@ -197,10 +197,11 @@ def _make_jnp_slide():
         k = pats.shape[-2]
         m = cols.shape[-2] - k + 1
         acc = None
-        for j in range(k):                           # k static: unrolled
-            v = field.dot(cols[..., j:j + m, :],
-                          pats[:, :, None, None, j, :], axis=-1)
-            acc = v if acc is None else field.mul(acc, v)
+        with jax.named_scope("match"):
+            for j in range(k):                       # k static: unrolled
+                v = field.dot(cols[..., j:j + m, :],
+                              pats[:, :, None, None, j, :], axis=-1)
+                acc = v if acc is None else field.mul(acc, v)
         return acc
 
     return aa_slide

@@ -41,7 +41,8 @@ import jax
 
 from ..core import encoding
 from ..core.costs import CostLedger
-from ..core.dataplane import Dispatcher, RelationLike, ShardedRelation
+from ..core.dataplane import (Dispatcher, RelationLike, ShardedRelation,
+                              span)
 from ..core.engine import SecretSharedDB
 from ..core.queries import CardinalityError, aggregate, rounds
 from ..core.queries import embed as embed_q
@@ -680,123 +681,132 @@ class QueryClient:
                        ent: AttachedRelation) -> _BatchWork:
         """Group, plan and run every pre-fetch round of one batch."""
         db, rel = ent.db, ent.rel
-        stats = self.stats(ent.name)
-        results: Dict[int, QueryResult] = {}
-        count_grp: List[_Slot] = []
-        sel_grp: Dict[str, List[_Slot]] = {"one_tuple": [], "one_round": [],
-                                           "tree": []}
-        range_grps: Dict[Tuple[int, int], List[_Slot]] = {}
-        agg_sum_grps: Dict[int, List[_Slot]] = {}
-        agg_mm_grps: Dict[Tuple[int, int], List[_Slot]] = {}
-        embed_grp: List[_Slot] = []
-        pkfk_grp: List[_Slot] = []
-        equi_grp: List[_Slot] = []
-        auto_slots: List[_Slot] = []
-        group_sizes: Dict[str, int] = {s: 0 for s in sel_grp}
-        group_rounds: Dict[str, int] = {}
+        # planning: stats, strategy choice and grouping, up to the first
+        # round (the span ``client.plan``)
+        plane = ent.dataplane
+        with span(plane.stats if plane is not None else None,
+                  "client.plan"):
+            stats = self.stats(ent.name)
+            results: Dict[int, QueryResult] = {}
+            count_grp: List[_Slot] = []
+            sel_grp: Dict[str, List[_Slot]] = {
+                "one_tuple": [], "one_round": [], "tree": []}
+            range_grps: Dict[Tuple[int, int], List[_Slot]] = {}
+            agg_sum_grps: Dict[int, List[_Slot]] = {}
+            agg_mm_grps: Dict[Tuple[int, int], List[_Slot]] = {}
+            embed_grp: List[_Slot] = []
+            pkfk_grp: List[_Slot] = []
+            equi_grp: List[_Slot] = []
+            auto_slots: List[_Slot] = []
+            group_sizes: Dict[str, int] = {s: 0 for s in sel_grp}
+            group_rounds: Dict[str, int] = {}
 
-        def join_group(slot: _Slot, strategy: str,
-                       ell: Optional[int]) -> None:
-            """Track a group's size and deepest member's estimated rounds
-            so later AUTO riders are priced at their true marginal depth."""
-            slot.strategy = strategy
-            group_sizes[strategy] += 1
-            ell_eff = (1 if strategy == "one_tuple" else
-                       _planner.DEFAULT_ELL if ell is None else max(ell, 1))
-            if slot.spec is not None:
-                est = _planner.estimate_pattern_cost(
-                    stats, slot.spec, select=strategy, ell=ell_eff,
-                    padded_rows=slot.plan.padding.rows)
-            else:
-                est = _planner.estimate_select_cost(
-                    strategy, stats, ell=ell_eff,
-                    padded_rows=slot.plan.padding.rows)
-            group_rounds[strategy] = max(group_rounds.get(strategy, 0),
-                                         est.rounds)
-            sel_grp[strategy].append(slot)
-
-        for idx, plan in enumerate(plans):
-            slot = _Slot(idx, plan, self._next_key(ent))
-            if isinstance(plan, Count):
-                slot.column, slot.pattern, slot.spec = _lower_match(
-                    db, plan.where, "Count predicate")
-                count_grp.append(slot)
-            elif isinstance(plan, Select):
-                slot.column, slot.pattern, slot.spec = _lower_match(
-                    db, plan.where, "Select predicate")
-                if slot.spec is not None and plan.strategy == "one_tuple":
-                    raise _planner.PlanNotSupported(
-                        plan.where, "one_tuple select (the §3.2.1 single-"
-                        "tuple map is the exact-equality special case — "
-                        "pattern predicates run one_round or tree)")
-                if plan.strategy == AUTO:
-                    auto_slots.append(slot)   # assigned once groups known
-                    continue
-                if plan.strategy == "one_tuple" and plan.padding.rows:
-                    raise ValueError(
-                        "one_tuple returns the single tuple directly and "
-                        "cannot pad its output size — use one_round/tree "
-                        "(or auto, which excludes one_tuple when padding is "
-                        "requested)")
-                join_group(slot, plan.strategy, plan.expected_matches)
-            elif isinstance(plan, (RangeCount, RangeSelect)):
-                slot.column = resolve_column(db, plan.where.column)
-                gk = (db.numeric_bits.get(slot.column, -1),
-                      plan.reduce_every)
-                range_grps.setdefault(gk, []).append(slot)
-            elif isinstance(plan, Aggregate):
-                slot.column = resolve_column(db, plan.column)
-                if plan.where is not None:
-                    slot.pred_column = resolve_column(db, plan.where.column)
-                t_bits = db.numeric_bits.get(slot.column, -1)
-                if plan.op in ("sum", "avg"):
-                    agg_sum_grps.setdefault(t_bits, []).append(slot)
+            def join_group(slot: _Slot, strategy: str,
+                           ell: Optional[int]) -> None:
+                """Track a group's size and deepest member's estimated
+                rounds so later AUTO riders are priced at their true
+                marginal depth."""
+                slot.strategy = strategy
+                group_sizes[strategy] += 1
+                ell_eff = (1 if strategy == "one_tuple" else
+                           _planner.DEFAULT_ELL if ell is None
+                           else max(ell, 1))
+                if slot.spec is not None:
+                    est = _planner.estimate_pattern_cost(
+                        stats, slot.spec, select=strategy, ell=ell_eff,
+                        padded_rows=slot.plan.padding.rows)
                 else:
-                    agg_mm_grps.setdefault((t_bits, plan.reduce_every),
-                                           []).append(slot)
-            elif isinstance(plan, EmbedLookup):
-                embed_grp.append(slot)
-            elif isinstance(plan, Join):
-                self._validate_join(plan)
-                (pkfk_grp if plan.kind == "pkfk" else equi_grp).append(slot)
-            else:
-                raise _planner.PlanNotSupported(plan)
+                    est = _planner.estimate_select_cost(
+                        strategy, stats, ell=ell_eff,
+                        padded_rows=slot.plan.padding.rows)
+                group_rounds[strategy] = max(group_rounds.get(strategy, 0),
+                                             est.rounds)
+                sel_grp[strategy].append(slot)
 
-        # AUTO selections plan against the batch's live group sizes and
-        # depths (riding a non-empty group costs only the rounds the rider
-        # adds beyond its deepest member — marginal round pricing; with
-        # round_cost_bits=0 this reduces to sequential planning). Pattern
-        # predicates choose among their eligible strategies only.
-        for slot in auto_slots:
-            if slot.spec is not None:
-                chosen = _planner.choose_pattern_strategy(
-                    stats, slot.spec, ell=slot.plan.expected_matches,
-                    padded_rows=slot.plan.padding.rows,
-                    round_cost_bits=self.round_cost_bits,
-                    group_sizes=group_sizes,
-                    group_rounds=group_rounds).strategy
-            else:
-                chosen = _planner.choose_select_strategy(
-                    stats, ell=slot.plan.expected_matches,
-                    padded_rows=slot.plan.padding.rows,
-                    round_cost_bits=self.round_cost_bits,
-                    group_sizes=group_sizes,
-                    group_rounds=group_rounds).strategy
-            join_group(slot, chosen, slot.plan.expected_matches)
+            for idx, plan in enumerate(plans):
+                slot = _Slot(idx, plan, self._next_key(ent))
+                if isinstance(plan, Count):
+                    slot.column, slot.pattern, slot.spec = _lower_match(
+                        db, plan.where, "Count predicate")
+                    count_grp.append(slot)
+                elif isinstance(plan, Select):
+                    slot.column, slot.pattern, slot.spec = _lower_match(
+                        db, plan.where, "Select predicate")
+                    if slot.spec is not None and plan.strategy == "one_tuple":
+                        raise _planner.PlanNotSupported(
+                            plan.where, "one_tuple select (the §3.2.1 single-"
+                            "tuple map is the exact-equality special case — "
+                            "pattern predicates run one_round or tree)")
+                    if plan.strategy == AUTO:
+                        auto_slots.append(slot)   # assigned once groups known
+                        continue
+                    if plan.strategy == "one_tuple" and plan.padding.rows:
+                        raise ValueError(
+                            "one_tuple returns the single tuple directly and "
+                            "cannot pad its output size — use "
+                            "one_round/tree (or auto, which excludes "
+                            "one_tuple when padding is requested)")
+                    join_group(slot, plan.strategy, plan.expected_matches)
+                elif isinstance(plan, (RangeCount, RangeSelect)):
+                    slot.column = resolve_column(db, plan.where.column)
+                    gk = (db.numeric_bits.get(slot.column, -1),
+                          plan.reduce_every)
+                    range_grps.setdefault(gk, []).append(slot)
+                elif isinstance(plan, Aggregate):
+                    slot.column = resolve_column(db, plan.column)
+                    if plan.where is not None:
+                        slot.pred_column = resolve_column(
+                            db, plan.where.column)
+                    t_bits = db.numeric_bits.get(slot.column, -1)
+                    if plan.op in ("sum", "avg"):
+                        agg_sum_grps.setdefault(t_bits, []).append(slot)
+                    else:
+                        agg_mm_grps.setdefault((t_bits, plan.reduce_every),
+                                               []).append(slot)
+                elif isinstance(plan, EmbedLookup):
+                    embed_grp.append(slot)
+                elif isinstance(plan, Join):
+                    self._validate_join(plan)
+                    (pkfk_grp if plan.kind == "pkfk"
+                     else equi_grp).append(slot)
+                else:
+                    raise _planner.PlanNotSupported(plan)
 
-        be = self.backend
-        # deferred cross-group fetch: (slot, strategy, addresses) per job
-        fetch_jobs: List[rounds.FetchJob] = []
-        fetch_meta: List[Tuple[_Slot, str, List[int]]] = []
+            # AUTO selections plan against the batch's live group sizes and
+            # depths (riding a non-empty group costs only the rounds the rider
+            # adds beyond its deepest member — marginal round pricing; with
+            # round_cost_bits=0 this reduces to sequential planning). Pattern
+            # predicates choose among their eligible strategies only.
+            for slot in auto_slots:
+                if slot.spec is not None:
+                    chosen = _planner.choose_pattern_strategy(
+                        stats, slot.spec, ell=slot.plan.expected_matches,
+                        padded_rows=slot.plan.padding.rows,
+                        round_cost_bits=self.round_cost_bits,
+                        group_sizes=group_sizes,
+                        group_rounds=group_rounds).strategy
+                else:
+                    chosen = _planner.choose_select_strategy(
+                        stats, ell=slot.plan.expected_matches,
+                        padded_rows=slot.plan.padding.rows,
+                        round_cost_bits=self.round_cost_bits,
+                        group_sizes=group_sizes,
+                        group_rounds=group_rounds).strategy
+                join_group(slot, chosen, slot.plan.expected_matches)
 
-        # conditional AVG denominators ride the batch's §3.1 count phase:
-        # their MatchJobs fuse into the same dispatch as explicit Counts.
-        avg_cnt_slots: List[_Slot] = []
-        for group in agg_sum_grps.values():
-            for s in group:
-                if s.plan.op == "avg" and s.plan.where is not None:
-                    s.key, s.fetch_key = jax.random.split(s.key)
-                    avg_cnt_slots.append(s)
+            be = self.backend
+            # deferred cross-group fetch: (slot, strategy, addresses) per job
+            fetch_jobs: List[rounds.FetchJob] = []
+            fetch_meta: List[Tuple[_Slot, str, List[int]]] = []
+
+            # conditional AVG denominators ride the batch's §3.1 count phase:
+            # their MatchJobs fuse into the same dispatch as explicit Counts.
+            avg_cnt_slots: List[_Slot] = []
+            for group in agg_sum_grps.values():
+                for s in group:
+                    if s.plan.op == "avg" and s.plan.where is not None:
+                        s.key, s.fetch_key = jax.random.split(s.key)
+                        avg_cnt_slots.append(s)
 
         if count_grp or avg_cnt_slots:
             counts = rounds.count_phase(be, rel, [

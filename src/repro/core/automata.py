@@ -50,9 +50,11 @@ def match_words(column: Shares, pattern: Shares) -> Shares:
     """
     col = column.values                            # (c, n, W, A)
     pat = pattern.values[:, None]                  # (c, 1, W, A)
-    v = _inner_over_alphabet(col, jnp.broadcast_to(pat, col.shape))
+    with jax.named_scope("match"):
+        v = _inner_over_alphabet(col, jnp.broadcast_to(pat, col.shape))
+        bits = _chain(v)
     out_degree = (column.degree + pattern.degree) * col.shape[-2]
-    return Shares(_chain(v), out_degree)
+    return Shares(bits, out_degree)
 
 
 # alias used by query code: a "column" is (c, n, W, A)
@@ -90,17 +92,19 @@ def match_matrix(col_x: Shares, col_y: Shares, *,
     yv = col_y.values            # (c, ny, W, A)
     w = xv.shape[-2]
     out_degree = (col_x.degree + col_y.degree) * w
-    if method == "aggregate":
-        c, nx = xv.shape[0], xv.shape[1]
-        ny = yv.shape[1]
-        xf = xv.reshape(c, nx, -1)
-        yf = yv.reshape(c, ny, -1)
-        p_cnt = field.matmul(xf, jnp.swapaxes(yf, -1, -2))   # (c,nx,ny)
-        return Shares(_equality_indicator(p_cnt, w), out_degree)
-    acc = None
-    for j in range(w):
-        pj = field.matmul(xv[:, :, j, :], jnp.swapaxes(yv[:, :, j, :], -1, -2))
-        acc = pj if acc is None else field.mul(acc, pj)
+    with jax.named_scope("join"):
+        if method == "aggregate":
+            c, nx = xv.shape[0], xv.shape[1]
+            ny = yv.shape[1]
+            xf = xv.reshape(c, nx, -1)
+            yf = yv.reshape(c, ny, -1)
+            p_cnt = field.matmul(xf, jnp.swapaxes(yf, -1, -2))  # (c,nx,ny)
+            return Shares(_equality_indicator(p_cnt, w), out_degree)
+        acc = None
+        for j in range(w):
+            pj = field.matmul(xv[:, :, j, :],
+                              jnp.swapaxes(yv[:, :, j, :], -1, -2))
+            acc = pj if acc is None else field.mul(acc, pj)
     return Shares(acc, out_degree)
 
 

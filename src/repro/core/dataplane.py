@@ -50,6 +50,7 @@ Two multi-tenant refinements ride on the thread pool:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -105,11 +106,9 @@ class Dispatcher:
 
     def run_set(self, plane: "ShardedRelation", ds: "DispatchSet"):
         """Execute + reduce one cloud step, recording telemetry."""
-        t0 = time.perf_counter()
         parts = self.run_all([d.run for d in ds.dispatches])
         out = ds.combine(parts)
         plane.stats.record(len(ds.dispatches),
-                           wall_s=time.perf_counter() - t0,
                            transfer_bytes=sum(_tree_nbytes(p)
                                               for p in parts))
         return out
@@ -323,10 +322,16 @@ class ShardDispatch:
 
 @dataclasses.dataclass(frozen=True)
 class DispatchSet:
-    """All shards' dispatches for one cloud step + the reduction rule."""
+    """All shards' dispatches for one cloud step + the reduction rule.
+
+    ``phase`` names the protocol step for telemetry only: the step runs
+    under the span ``cloud.<phase>`` (``match``, ``fetch``, ``embed``,
+    ``ripple``, ``join``; ``step`` where its caller named none).
+    """
     dispatches: Tuple[ShardDispatch, ...]
     reduce: str = "concat"          # "concat" | "sum" | "list"
     axis: int = -1                  # concat axis
+    phase: str = "step"
 
     def combine(self, parts: List[Any]):
         if self.reduce == "list":
@@ -343,34 +348,63 @@ class DispatchSet:
         raise ValueError(f"unknown reduce mode {self.reduce!r}")
 
 
+@contextlib.contextmanager
+def span(sink, name: str):
+    """One program span: ``name`` on the profiler's host plane and its
+    host seconds added to ``sink.span_s[name]``.
+
+    The span opens a ``jax.profiler.TraceAnnotation``, so a traced run
+    sees it on the same clock as the device's operations; with no
+    profiler attached that costs well under a microsecond. ``sink`` is a
+    plane's :class:`DispatchStats` or the server's ``ServeStats`` (both
+    expose ``add_span``), or None to trace only. A span never waits on
+    the device: the seconds are host wall time, so a span that ends in a
+    host copy includes the wait for the bytes.
+    """
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        if sink is not None:
+            sink.add_span(name, time.perf_counter() - t0)
+
+
 @dataclasses.dataclass
 class DispatchStats:
     """Execution-side telemetry (never part of the protocol transcript).
 
-    ``dispatch_s`` accumulates the wall-time of every cloud step (dispatch
-    fan-out + reduce, as seen by the dispatcher — jax async dispatch means
-    this is *submission* time unless the policy blocks). ``transfer_bytes``
-    accumulates staged bytes: for host dispatchers, every shard partial
-    that round-trips through the combine; for a device-resident dispatcher,
-    only the initial host→device placement (zero afterwards — the
-    device-residency invariant, asserted in tests/test_mesh_dispatch.py).
+    ``transfer_bytes`` accumulates staged bytes: for host dispatchers,
+    every shard partial that round-trips through the combine; for a
+    device-resident dispatcher, only the initial host→device placement
+    (zero afterwards — the device-residency invariant, asserted in
+    tests/test_mesh_dispatch.py). ``span_s`` accumulates the host seconds
+    of every :func:`span` charged to this plane, by name: ``cloud.<phase>``
+    for cloud steps (submission plus the combine; jax dispatch is
+    asynchronous, so this is not device time), ``client.plan``,
+    ``user.share`` and ``user.open`` for the user's side of a batch.
     """
     dispatches: int = 0             # shard dispatches executed
     steps: int = 0                  # cloud steps (DispatchSets) executed
     fused_steps: int = 0            # steps executed inside a fused wave
-    dispatch_s: float = 0.0         # cumulative cloud-step wall-time
     transfer_bytes: int = 0         # staged bytes (see above)
+    span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
-    def record(self, n_dispatches: int, wall_s: float = 0.0,
-               transfer_bytes: int = 0, fused: bool = False) -> None:
+    def record(self, n_dispatches: int, transfer_bytes: int = 0,
+               fused: bool = False) -> None:
         self.dispatches += n_dispatches
         self.steps += 1
         if fused:
-            # the step ran inside a cross-plane fused_execute wave;
-            # wall_s then covers the whole wave, not this step alone.
+            # the step ran inside a cross-plane fused_execute wave
             self.fused_steps += 1
-        self.dispatch_s += wall_s
         self.transfer_bytes += transfer_bytes
+
+    def add_span(self, name: str, seconds: float) -> None:
+        self.span_s[name] = self.span_s.get(name, 0.0) + seconds
+
+    def copy(self) -> "DispatchStats":
+        """A snapshot whose ``span_s`` later spans leave unchanged."""
+        return dataclasses.replace(self, span_s=dict(self.span_s))
 
 
 # ---------------------------------------------------------------------------
@@ -481,27 +515,30 @@ class ShardedRelation:
 
     # -- dispatch -----------------------------------------------------------
     def dispatch_set(self, build: Callable[[SecretSharedDB, Shard], Any],
-                     *, reduce: str = "concat", axis: int = -1
-                     ) -> DispatchSet:
+                     *, reduce: str = "concat", axis: int = -1,
+                     phase: str = "step") -> DispatchSet:
         """One cloud step: a per-shard dispatch descriptor per shard."""
         return DispatchSet(tuple(
             ShardDispatch(sh, functools.partial(build, self.view(sh.index),
                                                 sh))
-            for sh in self.shards), reduce=reduce, axis=axis)
+            for sh in self.shards), reduce=reduce, axis=axis, phase=phase)
 
     def execute(self, ds: DispatchSet):
         """Run one step through the placement policy and reduce it."""
-        return self.dispatcher.run_set(self, ds)
+        with span(self.stats, f"cloud.{ds.phase}"):
+            return self.dispatcher.run_set(self, ds)
 
-    def run_concat(self, build, *, axis: int = -1):
+    def run_concat(self, build, *, axis: int = -1, phase: str = "step"):
         return self.execute(self.dispatch_set(build, reduce="concat",
-                                              axis=axis))
+                                              axis=axis, phase=phase))
 
-    def run_sum(self, build):
-        return self.execute(self.dispatch_set(build, reduce="sum"))
+    def run_sum(self, build, *, phase: str = "step"):
+        return self.execute(self.dispatch_set(build, reduce="sum",
+                                              phase=phase))
 
-    def run_list(self, build) -> List[Any]:
-        return self.execute(self.dispatch_set(build, reduce="list"))
+    def run_list(self, build, *, phase: str = "step") -> List[Any]:
+        return self.execute(self.dispatch_set(build, reduce="list",
+                                              phase=phase))
 
 
 RelationLike = Union[SecretSharedDB, ShardedRelation]
@@ -552,25 +589,31 @@ def fused_execute(pairs: Sequence[Tuple["ShardedRelation", DispatchSet]]
             plane, ds = pairs[idxs[0]]
             results[idxs[0]] = plane.execute(ds)
             continue
+        # one span for the whole wave; each plane is charged an equal
+        # share of its seconds, so the planes' spans sum to the wall spent
+        name = f"cloud.{pairs[idxs[0]][1].phase}"
         t0 = time.perf_counter()
-        waves: List[Tuple[int, List[Future]]] = []
+        with span(None, name):
+            waves: List[Tuple[int, List[Future]]] = []
+            for i in idxs:
+                plane, ds = pairs[i]
+                disp = plane.dispatcher
+                handle = (disp if isinstance(disp, PoolHandle)
+                          else pool.handle())       # transient, weight 1
+                waves.append((i, pool.enqueue(
+                    handle, [d.run for d in ds.dispatches])))
+            for i, futs in waves:
+                plane, ds = pairs[i]
+                parts = [f.result() for f in futs]
+                out = ds.combine(parts)
+                plane.stats.record(len(ds.dispatches),
+                                   transfer_bytes=sum(_tree_nbytes(p)
+                                                      for p in parts),
+                                   fused=True)
+                results[i] = out
+        share = (time.perf_counter() - t0) / len(idxs)
         for i in idxs:
-            plane, ds = pairs[i]
-            disp = plane.dispatcher
-            handle = (disp if isinstance(disp, PoolHandle)
-                      else pool.handle())       # transient, weight 1
-            waves.append((i, pool.enqueue(handle,
-                                          [d.run for d in ds.dispatches])))
-        for i, futs in waves:
-            plane, ds = pairs[i]
-            parts = [f.result() for f in futs]
-            out = ds.combine(parts)
-            plane.stats.record(len(ds.dispatches),
-                               wall_s=time.perf_counter() - t0,
-                               transfer_bytes=sum(_tree_nbytes(p)
-                                                  for p in parts),
-                               fused=True)
-            results[i] = out
+            pairs[i][0].stats.add_span(name, share)
     return results
 
 

@@ -179,16 +179,19 @@ def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     — the integer mode XLA:TPU runs on the MXU — recombined with Mersenne
     rotates. K streams in ``LIMB_K_MAX`` chunks summed mod p. The Pallas
     kernel (kernels/ss_matmul.py) is the same algorithm tiled for VMEM.
+    Its operations sit under the name scope ``fetch``: the oblivious fetch
+    and the embedding lookup, a fetch of one-hot rows, are its main users.
     """
     def dot(x, y):
         return jnp.matmul(x, y, preferred_element_type=jnp.int32)
 
     k_dim = a.shape[-1]
     acc = None
-    for k0 in range(0, max(k_dim, 1), LIMB_K_MAX):
-        part = limb_contract(a[..., k0:k0 + LIMB_K_MAX],
-                             b[..., k0:k0 + LIMB_K_MAX, :], dot)
-        acc = part if acc is None else addmod32(acc, part)
+    with jax.named_scope("fetch"):
+        for k0 in range(0, max(k_dim, 1), LIMB_K_MAX):
+            part = limb_contract(a[..., k0:k0 + LIMB_K_MAX],
+                                 b[..., k0:k0 + LIMB_K_MAX, :], dot)
+            acc = part if acc is None else addmod32(acc, part)
     return acc
 
 

@@ -43,7 +43,6 @@ device-residency invariant instead of asserting it by inspection.
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -141,7 +140,6 @@ class MeshDispatcher(Dispatcher):
         # the placement-only ``transfer_bytes`` accounting.
         d2h = (jax.transfer_guard_device_to_host("disallow")
                if self.strict_transfers else contextlib.nullcontext())
-        t0 = time.perf_counter()
         with d2h:
             parts = [d.run() for d in ds.dispatches]
             both = (jax.transfer_guard("disallow") if self.strict_transfers
@@ -152,9 +150,7 @@ class MeshDispatcher(Dispatcher):
                 else:
                     out = ds.combine(parts)  # concat/list: already on device
         moved, self._pending_transfer_bytes = self._pending_transfer_bytes, 0
-        plane.stats.record(len(ds.dispatches),
-                           wall_s=time.perf_counter() - t0,
-                           transfer_bytes=moved)
+        plane.stats.record(len(ds.dispatches), transfer_bytes=moved)
         return out
 
     # -- SPMD mod-p reduction ----------------------------------------------

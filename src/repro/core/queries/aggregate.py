@@ -58,7 +58,7 @@ import numpy as np
 
 from .. import dataplane, encoding, field, shamir
 from ..costs import CostLedger
-from ..dataplane import RelationLike
+from ..dataplane import RelationLike, span
 from ..shamir import Shares
 from .rounds import (MatchJob, _batched_matcher, _open_on_host,
                      _ripple_segmenter, _segment_edges, _share_patterns,
@@ -205,7 +205,9 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
             f"be exact")
     cond = [i for i, j in enumerate(jobs) if j.conditional]
     free = [i for i, j in enumerate(jobs) if not j.conditional]
-    p_all = (_share_patterns(db, [jobs[i] for i in cond]) if cond else None)
+    with span(plane.stats, "user.share"):
+        p_all = (_share_patterns(db, [jobs[i] for i in cond]) if cond
+                 else None)
     w = db.relation.values.shape[-2]
     match_deg = ((db.relation.degree + p_all.degree) * w if cond else 0)
     weights = _value_weights(t_bits)
@@ -241,7 +243,7 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts,
                                                                 axis=1)
 
-    sums_flat = plane.run_sum(one)                         # (c, Bc+Bf)
+    sums_flat = plane.run_sum(one, phase="match")          # (c, Bc+Bf)
     per_job: List[Optional[Shares]] = [None] * len(jobs)
     for k, i in enumerate(cond):
         per_job[i] = Shares(sums_flat[:, k],
@@ -250,7 +252,7 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
     for k, i in enumerate(free):
         per_job[i] = Shares(sums_flat[:, len(cond) + k],
                             db.numeric[jobs[i].value_column].degree)
-    opened = _open_on_host(per_job)
+    opened = _open_on_host(plane.stats, per_job)
 
     per_q = codec.word_length * codec.alphabet_size
     for i, j in enumerate(jobs):
@@ -337,15 +339,17 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
     masked_by_pos: dict = {}
     if cond:
         cond_jobs = [jobs[i] for i in cond]
-        p_all = _share_patterns(db, [
-            MatchJob(j.pred_column, j.pattern, split_keys[i][0], j.ledger)
-            for i, j in zip(cond, cond_jobs)])
+        with span(plane.stats, "user.share"):
+            p_all = _share_patterns(db, [
+                MatchJob(j.pred_column, j.pattern, split_keys[i][0],
+                         j.ledger) for i, j in zip(cond, cond_jobs)])
         match_deg = (db.relation.degree + p_all.degree) * w
         bits = Shares(plane.run_concat(
             lambda v, sh: _batched_matcher(be)(
                 _stack_columns(v, [j.pred_column
                                    for j in cond_jobs]).values,
-                p_all.values), axis=2), match_deg)          # (c, Bc, n)
+                p_all.values), axis=2, phase="match"),
+            match_deg)                                      # (c, Bc, n)
         counts = Shares(field.sum_(bits.values, axis=2), match_deg)
         # sentinel mask: non-matching rows become the op's losing extreme
         # (a public constant, so masking is cloud-local share arithmetic):
@@ -361,8 +365,9 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
         masked = field.add(field.mul(bits.values[..., None], delta),
                            jnp.broadcast_to(sent_b, x.values.shape))
         red_key, sub = jax.random.split(red_key)
-        masked = shamir.reduce_degree(
-            sub, Shares(masked, match_deg + x.degree), target_degree=d)
+        with span(plane.stats, "cloud.reshare"):
+            masked = shamir.reduce_degree(
+                sub, Shares(masked, match_deg + x.degree), target_degree=d)
         for i, j in enumerate(cond_jobs):
             j.ledger.round()                 # the mask re-share round
             j.ledger.send(c * c)
@@ -397,14 +402,17 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
                                                         reduce_every)):
             if seg_i > 0 and carry_deg > 1:
                 red_key, sub = jax.random.split(red_key)
-                carry = shamir.reduce_degree(
-                    sub, Shares(carry, carry_deg), target_degree=1).values
+                with span(plane.stats, "cloud.reshare"):
+                    carry = shamir.reduce_degree(
+                        sub, Shares(carry, carry_deg),
+                        target_degree=1).values
                 carry_deg = 1
                 for j in jobs:
                     j.ledger.round()
                     j.ledger.send(c * c)
-            s_bits, carry = segment(lhs[..., s0:s1], rhs[..., s0:s1],
-                                    carry)
+            with span(plane.stats, "cloud.ripple"):
+                s_bits, carry = segment(lhs[..., s0:s1], rhs[..., s0:s1],
+                                        carry)
             carry_deg = carry_deg + 2 * cand_deg * (s1 - s0)
         win = _select_winner(x1, x2, s_bits)
         win_deg = carry_deg + cand_deg
@@ -418,8 +426,9 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
             # the FINAL level opens at its native degree instead, so a
             # post-reduction tamper is visible to verification.
             red_key, sub = jax.random.split(red_key)
-            cand = shamir.reduce_degree(sub, Shares(win, win_deg),
-                                        target_degree=d).values
+            with span(plane.stats, "cloud.reshare"):
+                cand = shamir.reduce_degree(sub, Shares(win, win_deg),
+                                            target_degree=d).values
             cand_deg = d
             for j in jobs:
                 j.ledger.round()
@@ -431,7 +440,8 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
     val_parts = [Shares(cand[:, i, 0], cand_deg) for i in range(b)]
     cnt_parts = {i: Shares(counts.values[:, kk], counts.degree)
                  for kk, i in enumerate(cond)}
-    opened = _open_on_host(val_parts + [cnt_parts[i] for i in cond])
+    opened = _open_on_host(plane.stats,
+                           val_parts + [cnt_parts[i] for i in cond])
 
     for i, j in enumerate(jobs):
         j.ledger.recv(c * t_bits)

@@ -35,9 +35,10 @@ import numpy as np
 
 from .. import dataplane, field, shamir
 from ..costs import CostLedger
-from ..dataplane import RelationLike
+from ..dataplane import RelationLike, span
 from ..shamir import Shares
 from .aggregate import VerificationError, _verify_openings
+from .rounds import stack_onehots
 
 __all__ = [
     "QUANT_SCALE", "QUANT_RANGE", "quantize_to_field",
@@ -181,21 +182,22 @@ def embed_phase(be, rel: RelationLike, jobs: Sequence[EmbedJob]
             f"table has {c}")
 
     mats, spans, pos = [], [], 0
-    for job in jobs:
-        flat = np.asarray(job.tokens).reshape(-1)
-        if flat.size and (flat.min() < 0 or flat.max() >= v):
-            raise ValueError(
-                f"token id out of range [0, {v}): "
-                f"[{int(flat.min())}, {int(flat.max())}]")
-        mats.append(share_tokens(job.key, flat, vocab=v, n_shares=c,
-                                 be=be).values)
-        spans.append((pos, pos + int(flat.size)))
-        pos += int(flat.size)
-
-    stacked = mats[0] if len(mats) == 1 else jnp.concatenate(mats, axis=1)
+    with span(plane.stats, "user.share"):
+        for job in jobs:
+            flat = np.asarray(job.tokens).reshape(-1)
+            if flat.size and (flat.min() < 0 or flat.max() >= v):
+                raise ValueError(
+                    f"token id out of range [0, {v}): "
+                    f"[{int(flat.min())}, {int(flat.max())}]")
+            mats.append(share_tokens(job.key, flat, vocab=v, n_shares=c,
+                                     be=be).values)
+            spans.append((pos, pos + int(flat.size)))
+            pos += int(flat.size)
+        stacked = mats[0] if len(mats) == 1 else stack_onehots(tuple(mats))
     fetched = plane.run_sum(
         lambda view, sh: be.ss_matmul(stacked[:, :, sh.lo:sh.hi],
-                                      view.relation.values))      # (c, N, D)
+                                      view.relation.values),
+        phase="embed")                                            # (c, N, D)
     out_sh = Shares(fetched, out_deg)
 
     # Table-1 billing, per job: one round; the shared one-hots go up, the
@@ -212,5 +214,6 @@ def embed_phase(be, rel: RelationLike, jobs: Sequence[EmbedJob]
         if job.verify:
             _verify_openings(job, [out_sh[lo:hi]], "embedding lookup")
 
-    opened = np.asarray(dequantize_from_field(shamir.interpolate(out_sh)))
+    with span(plane.stats, "user.open"):
+        opened = np.asarray(dequantize_from_field(shamir.interpolate(out_sh)))
     return [opened[lo:hi] for lo, hi in spans]

@@ -63,6 +63,7 @@ bit-identical for every shard count; S is purely an execution knob.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -71,7 +72,7 @@ import numpy as np
 
 from .. import automata, dataplane, encoding, field, shamir
 from ..costs import CostLedger
-from ..dataplane import RelationLike
+from ..dataplane import RelationLike, span
 from ..engine import SecretSharedDB
 from ..partition import split_bounds
 from ..shamir import Shares
@@ -238,12 +239,17 @@ def _share_one_hot(key: jax.Array, db: SecretSharedDB,
                                   degree=db.base_degree)
 
 
-def _open_on_host(parts: Sequence[Shares]) -> List[np.ndarray]:
+def _open_on_host(sink, parts: Sequence[Shares]) -> List[np.ndarray]:
     """User step: open many share tensors. Only the degree+1 shares the
     user needs leave the clouds, and the Lagrange sum runs on the user's
     host copy of them (``shamir.interpolate_host``), so opening compiles
-    no device program. Returns decoded numpy arrays in input order."""
-    return [shamir.interpolate_host(s) for s in parts]
+    no device program. Returns decoded numpy arrays in input order.
+
+    Runs under the span ``user.open`` charged to ``sink`` (a plane's
+    ``DispatchStats``); its seconds include the host's wait for the
+    device to produce the bytes it copies."""
+    with span(sink, "user.open"):
+        return [shamir.interpolate_host(s) for s in parts]
 
 
 def _share_patterns(db: SecretSharedDB, jobs: Sequence[MatchJob]) -> Shares:
@@ -349,8 +355,9 @@ class _MatcherPlan:
         communication round — before the share-local zero test.
     """
 
-    def __init__(self, db: SecretSharedDB, jobs: Sequence[MatchJob]):
-        self.db = db
+    def __init__(self, plane: "dataplane.ShardedRelation",
+                 jobs: Sequence[MatchJob]):
+        db = self.db = plane.db
         self.jobs = list(jobs)
         self.w = db.codec.word_length
         full: List[int] = []
@@ -371,8 +378,9 @@ class _MatcherPlan:
             self.groups.append(("prefix", k, prefix[k]))
         for k in sorted(slide):
             self.groups.append(("slide", k, slide[k]))
-        self.pats = [_share_patterns(db, [self.jobs[i] for i in idxs])
-                     for _, _, idxs in self.groups]
+        with span(plane.stats, "user.share"):
+            self.pats = [_share_patterns(db, [self.jobs[i] for i in idxs])
+                         for _, _, idxs in self.groups]
 
     def _shard_values(self, be, v: SecretSharedDB, sh):
         """Cloud step on one shard: per group ``(local job idxs, local
@@ -388,17 +396,19 @@ class _MatcherPlan:
                 continue
             if kind == "prefix":
                 out.append((idxs, _batched_matcher(be)(
-                    cols.values[..., :k, :], pats.values), [], None))
+                    prefix_tile(cols.values, k=k), pats.values), [], None))
                 continue
             win = _slide_matcher(be)(cols.values, pats.values)  # (c,Bg,ns,M)
             if self.w - k + 1 == 1:
                 # one window: the chain product IS the bit, either kind
-                out.append((idxs, win[..., 0], [], None))
+                out.append((idxs, window_bits(win, cols.values), [], None))
                 continue
-            suf = [b for b, i in enumerate(idxs)
-                   if self.jobs[i].spec.kind == "suffix"]
-            con = [b for b, i in enumerate(idxs)
-                   if self.jobs[i].spec.kind == "contains"]
+            suf = tuple(b for b, i in enumerate(idxs)
+                        if self.jobs[i].spec.kind == "suffix")
+            con = tuple(b for b, i in enumerate(idxs)
+                        if self.jobs[i].spec.kind == "contains")
+            win_suf, term, win_con = window_bits(win, cols.values, suf=suf,
+                                                 con=con, k=k)
             bits = None
             if suf:
                 # suffix ⟺ some window matches AND everything after it is
@@ -406,13 +416,12 @@ class _MatcherPlan:
                 # real pattern char never matches the terminator), so the
                 # linear sum of window·terminator products is the exact
                 # 0/1 bit.
-                term = cols.values[:, suf][..., k:, 0]   # (c,Bs,ns,M-1)
                 ones = jnp.ones(term.shape[:-1] + (1,), field.DTYPE)
                 bits = field.sum_(
-                    field.mul(win[:, suf],
+                    field.mul(win_suf,
                               jnp.concatenate([term, ones], axis=-1)),
                     axis=-1)
-            p_cnt = field.sum_(win[:, con], axis=-1) if con else None
+            p_cnt = field.sum_(win_con, axis=-1) if con else None
             out.append(([idxs[b] for b in suf], bits,
                         [idxs[b] for b in con], p_cnt))
         return out
@@ -433,7 +442,7 @@ class _MatcherPlan:
         mirroring the range engine's carry reduction) and finish with the
         share-local zero test."""
         shard_outs = plane.run_list(
-            lambda v, sh: self._shard_values(be, v, sh))
+            lambda v, sh: self._shard_values(be, v, sh), phase="match")
 
         def cat(gi, slot):
             parts = [so[gi][slot] for so in shard_outs]
@@ -451,42 +460,96 @@ class _MatcherPlan:
             if con_idx:
                 m = self.w - k + 1
                 red_key = jax.random.fold_in(self.jobs[con_idx[0]].key, 1)
-                p_red = shamir.reduce_degree(
-                    red_key, Shares(cat(gi, 3), t2 * k), target_degree=1)
+                with span(plane.stats, "cloud.reshare"):
+                    p_red = shamir.reduce_degree(
+                        red_key, Shares(cat(gi, 3), t2 * k),
+                        target_degree=1)
                 z = automata.zero_indicator(p_red.values, m)
                 result.append((con_idx, Shares(
                     field.sub(jnp.ones_like(z), z), m)))
         return result
 
 
-def _stack_columns(db: SecretSharedDB, columns: Sequence[int]) -> Shares:
-    """Cloud-local view: each job's attribute column -> (c, B, n, W, A).
+# ---------------------------------------------------------------------------
+# cloud-local copies, each one named program (``jit_<name>`` in a trace)
+# ---------------------------------------------------------------------------
 
-    When every job targets the same column the stack is a broadcast view,
-    not a copy.
+@functools.partial(jax.jit, static_argnames=("picks", "axis"))
+def stack_columns(src, picks: Tuple[int, ...], axis: int) -> jax.Array:
+    """Each job's column, stacked on a new axis 1 -> (c, B, ...).
+
+    ``src`` holds the columns along ``axis`` (the relation's attribute
+    axis), or is a tuple of separate column arrays, stacked along ``axis``
+    first (binary-form columns). ``picks`` names each job's column. One
+    distinct column broadcasts to B copies, several gather.
     """
-    rel = db.relation.values                       # (c, n, m, W, A)
-    if len(set(columns)) == 1:
-        one = rel[:, :, columns[0]]                # (c, n, W, A)
-        stacked = jnp.broadcast_to(one[:, None],
-                                   (one.shape[0], len(columns))
-                                   + one.shape[1:])
-    else:
-        stacked = jnp.moveaxis(rel[:, :, np.asarray(columns)], 2, 1)
-    return Shares(stacked, db.relation.degree)
+    if isinstance(src, tuple):
+        src = jnp.stack(src, axis=axis)
+    b = len(picks)
+    if len(set(picks)) == 1:
+        one = jax.lax.index_in_dim(src, picks[0], axis, keepdims=False)
+        return jnp.broadcast_to(one[:, None],
+                                (one.shape[0], b) + one.shape[1:])
+    return jnp.moveaxis(jnp.take(src, jnp.asarray(picks), axis=axis),
+                        axis, 1)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def prefix_tile(cols: jax.Array, k: int) -> jax.Array:
+    """The first ``k`` word positions of stacked columns (c, B, n, W, A),
+    which a prefix pattern's truncated chain reads."""
+    return cols[..., :k, :]
+
+
+@functools.partial(jax.jit, static_argnames=("suf", "con", "k"))
+def window_bits(win: jax.Array, cols: jax.Array, *, suf=(), con=(), k=0):
+    """The slices of a stacked window match ``win`` (c, B, n, M) that the
+    pattern finishes read.
+
+    With one window (M = 1) it is every job's bit, ``win[..., 0]``.
+    Otherwise ``(suffix windows, suffix terminators, substring windows)``:
+    the windows of the suffix jobs ``suf`` with the terminator row of
+    their columns ``cols`` past position ``k``, and the windows of the
+    substring jobs ``con``; None where that kind is absent.
+    """
+    if win.shape[-1] == 1:
+        return win[..., 0]
+    win_suf = term = win_con = None
+    if suf:
+        s = jnp.asarray(suf, jnp.int32)
+        win_suf, term = win[:, s], cols[:, s][..., k:, 0]
+    if con:
+        win_con = win[:, jnp.asarray(con, jnp.int32)]
+    return win_suf, term, win_con
+
+
+@jax.jit
+def fetch_relayout(rel: jax.Array) -> jax.Array:
+    """A relation's shares (c, n, m, W, A) as the fetch's right operand
+    (c, n, m·W·A); on a TPU a copy, since the tiled layouts differ."""
+    return rel.reshape(rel.shape[0], rel.shape[1], -1)
+
+
+@jax.jit
+def stack_onehots(mats: Tuple[jax.Array, ...]) -> jax.Array:
+    """Shared one-hot (or match-matrix) row blocks (c, r_i, n) stacked
+    into one fetch operand (c, Σr_i, n)."""
+    return jnp.concatenate(mats, axis=1)
+
+
+def _stack_columns(db: SecretSharedDB, columns: Sequence[int]) -> Shares:
+    """Cloud-local view: each job's attribute column -> (c, B, n, W, A)."""
+    return Shares(stack_columns(db.relation.values, tuple(columns), axis=2),
+                  db.relation.degree)
 
 
 def _stack_numeric(db: SecretSharedDB, columns: Sequence[int]) -> Shares:
     """Cloud-local view of binary-form columns -> (c, B, n, t_bits)."""
-    first = db.numeric[columns[0]]
-    if len(set(columns)) == 1:
-        one = first.values                          # (c, n, t)
-        stacked = jnp.broadcast_to(one[:, None],
-                                   (one.shape[0], len(columns))
-                                   + one.shape[1:])
-    else:
-        stacked = jnp.stack([db.numeric[c].values for c in columns], axis=1)
-    return Shares(stacked, first.degree)
+    distinct = sorted(set(columns))
+    return Shares(stack_columns(
+        tuple(db.numeric[c].values for c in distinct),
+        tuple(distinct.index(c) for c in columns), axis=1),
+        db.numeric[columns[0]].degree)
 
 
 def _match_stack(be, cols: Shares, pats: Shares) -> Shares:
@@ -540,7 +603,8 @@ def _block_sums(be, plane: "dataplane.ShardedRelation", p_all: Shares,
         return field.sum_(masked, axis=2)                      # (c, K)
 
     w = plane.db.relation.values.shape[-2]
-    return Shares(plane.run_sum(one), (rel_degree + p_all.degree) * w)
+    return Shares(plane.run_sum(one, phase="match"),
+                  (rel_degree + p_all.degree) * w)
 
 
 def _block_sums_cached(cached: Dict[int, Shares],
@@ -584,14 +648,16 @@ def count_phase(be, db: RelationLike, jobs: Sequence[MatchJob]
         # exact + masked only: the classic single-group fast path (one
         # additive-reduce dispatch set, partial sums combine in F_p)
         columns = [j.column for j in jobs]
-        p_all = _share_patterns(db, jobs)
+        with span(plane.stats, "user.share"):
+            p_all = _share_patterns(db, jobs)
         w = db.relation.values.shape[-2]
         deg = (db.relation.degree + p_all.degree) * w
         counts = Shares(plane.run_sum(
             lambda v, sh: field.sum_(_batched_matcher(be)(
-                _stack_columns(v, columns).values, p_all.values), axis=2)),
-            deg)                                               # (c, B)
-        out = np.asarray(shamir.interpolate(counts))
+                _stack_columns(v, columns).values, p_all.values), axis=2),
+            phase="match"), deg)                               # (c, B)
+        with span(plane.stats, "user.open"):
+            out = np.asarray(shamir.interpolate(counts))
         per_q = codec.word_length * codec.alphabet_size
         for j in jobs:
             j.ledger.round()
@@ -603,11 +669,11 @@ def count_phase(be, db: RelationLike, jobs: Sequence[MatchJob]
 
     # mixed / pattern batch: per-group fused match bits, summed and
     # interpolated in one fused user pass per degree class
-    mp = _MatcherPlan(db, jobs)
+    mp = _MatcherPlan(plane, jobs)
     parts = mp.bit_shares(be, plane)
     sums = [Shares(field.sum_(sh.values, axis=2), sh.degree)
             for _, sh in parts]
-    vals = _open_on_host(sums)
+    vals = _open_on_host(plane.stats, sums)
     out = [0] * len(jobs)
     deg_of: Dict[int, int] = {}
     for (idxs, sh), v in zip(parts, vals):
@@ -640,7 +706,8 @@ def one_tuple_round(be, db: RelationLike, jobs: Sequence[MatchJob]
     codec = db.codec
     b = len(jobs)
     columns = [j.column for j in jobs]
-    p_all = _share_patterns(db, jobs)
+    with span(plane.stats, "user.share"):
+        p_all = _share_patterns(db, jobs)
     c, _, m, w, a = db.relation.values.shape
     match_deg = (db.relation.degree + p_all.degree) * w
 
@@ -651,12 +718,12 @@ def one_tuple_round(be, db: RelationLike, jobs: Sequence[MatchJob]
     def one(v: SecretSharedDB, sh):
         bits = _batched_matcher(be)(_stack_columns(v, columns).values,
                                     p_all.values)              # (c,B,n_s)
-        return be.ss_matmul(bits, v.relation.values.reshape(
-            c, sh.n_tuples, m * w * a))
+        return be.ss_matmul(bits, fetch_relayout(v.relation.values))
 
-    sums = Shares(plane.run_sum(one).reshape(c, b, m, w, a),
+    sums = Shares(plane.run_sum(one, phase="fetch").reshape(c, b, m, w, a),
                   match_deg + db.relation.degree)              # (c,B,m,W,A)
-    tup = np.asarray(shamir.interpolate(sums))                 # (B, m, W, A)
+    with span(plane.stats, "user.open"):
+        tup = np.asarray(shamir.interpolate(sums))             # (B, m, W, A)
     per_q = codec.word_length * codec.alphabet_size
     for j in jobs:
         j.ledger.round()
@@ -681,13 +748,16 @@ def match_all_round(be, db: RelationLike, jobs: Sequence[MatchJob]
     codec = db.codec
     if not _needs_pattern_engine(jobs):
         columns = [j.column for j in jobs]
-        p_all = _share_patterns(db, jobs)
+        with span(plane.stats, "user.share"):
+            p_all = _share_patterns(db, jobs)
         w = db.relation.values.shape[-2]
         bits = Shares(plane.run_concat(
             lambda v, sh: _batched_matcher(be)(
-                _stack_columns(v, columns).values, p_all.values), axis=2),
+                _stack_columns(v, columns).values, p_all.values), axis=2,
+            phase="match"),
             (db.relation.degree + p_all.degree) * w)           # (c, B, n)
-        v = np.asarray(shamir.interpolate(bits))               # (B, n)
+        with span(plane.stats, "user.open"):
+            v = np.asarray(shamir.interpolate(bits))           # (B, n)
         per_q = codec.word_length * codec.alphabet_size
         for j in jobs:
             j.ledger.round()
@@ -701,9 +771,9 @@ def match_all_round(be, db: RelationLike, jobs: Sequence[MatchJob]
     # mixed / pattern batch: grouped dispatches, one fused interpolation
     # pass per degree class — pattern selects then ride the same
     # cross-group fetch_fusion matmul as everything else
-    mp = _MatcherPlan(db, jobs)
+    mp = _MatcherPlan(plane, jobs)
     parts = mp.bit_shares(be, plane)
-    vals = _open_on_host([sh for _, sh in parts])
+    vals = _open_on_host(plane.stats, [sh for _, sh in parts])
     out: List[List[int]] = [[] for _ in jobs]
     deg_of: Dict[int, int] = {}
     for (idxs, sh), v in zip(parts, vals):
@@ -759,11 +829,12 @@ def tree_rounds(be, db: RelationLike, jobs: Sequence[TreeJob]
     exact_pos = [i for i in range(len(jobs)) if i not in set(pat_pos)]
     exact_slot = {i: s for s, i in enumerate(exact_pos)}
     columns = [jobs[i].column for i in exact_pos]
-    p_all = (_share_patterns(db, [jobs[i] for i in exact_pos])
-             if exact_pos else None)
+    with span(plane.stats, "user.share"):
+        p_all = (_share_patterns(db, [jobs[i] for i in exact_pos])
+                 if exact_pos else None)
     cached: Dict[int, Shares] = {}
     if pat_pos:
-        mp = _MatcherPlan(db, [jobs[i] for i in pat_pos])
+        mp = _MatcherPlan(plane, [jobs[i] for i in pat_pos])
         for idxs, sh in mp.bit_shares(be, plane):
             for b, local in enumerate(idxs):
                 cached[pat_pos[local]] = Shares(sh.values[:, b], sh.degree)
@@ -879,7 +950,7 @@ def _tree_block_round(be, plane, p_all, columns, exact_slot, cached,
             address_weights=address_weights))
     parts += _block_sums_cached(cached, pat_meta,
                                 address_weights=address_weights)
-    vals = _open_on_host(parts)
+    vals = _open_on_host(plane.stats, parts)
     vals_by_entry: Dict[Tuple[int, int, int], int] = {}
     deg_by_job: Dict[int, int] = {}
     vi = 0
@@ -943,18 +1014,19 @@ def range_phase(be, db: RelationLike, jobs: Sequence[RangeJob]) -> Shares:
     # -- user round: share both endpoints of every job --------------------
     a_vals, b_vals = [], []
     red_key = None
-    for j in jobs:
-        k_a, k_b, k_s1, _ = jax.random.split(j.key, 4)
-        if red_key is None:
-            red_key = k_s1              # seeds the fused reduction chain
-        a_vals.append(encoding.share_encoded(
-            k_a, encoding.encode_number_bits(j.lo, t_bits),
-            n_shares=c, degree=db.base_degree).values)
-        b_vals.append(encoding.share_encoded(
-            k_b, encoding.encode_number_bits(j.hi, t_bits),
-            n_shares=c, degree=db.base_degree).values)
-        j.ledger.round()
-        j.ledger.send(c * 2 * t_bits)
+    with span(plane.stats, "user.share"):
+        for j in jobs:
+            k_a, k_b, k_s1, _ = jax.random.split(j.key, 4)
+            if red_key is None:
+                red_key = k_s1          # seeds the fused reduction chain
+            a_vals.append(encoding.share_encoded(
+                k_a, encoding.encode_number_bits(j.lo, t_bits),
+                n_shares=c, degree=db.base_degree).values)
+            b_vals.append(encoding.share_encoded(
+                k_b, encoding.encode_number_bits(j.hi, t_bits),
+                n_shares=c, degree=db.base_degree).values)
+            j.ledger.round()
+            j.ledger.send(c * 2 * t_bits)
 
     x = _stack_numeric(db, [j.column for j in jobs])       # (c, B, n, t)
     d = db.base_degree
@@ -981,8 +1053,10 @@ def range_phase(be, db: RelationLike, jobs: Sequence[RangeJob]) -> Shares:
             carry_full = (carries[0] if len(shards) == 1
                           else jnp.concatenate(carries, axis=2))
             red_key, sub = jax.random.split(red_key)
-            carry_full = shamir.reduce_degree(
-                sub, Shares(carry_full, carry_deg), target_degree=1).values
+            with span(plane.stats, "cloud.reshare"):
+                carry_full = shamir.reduce_degree(
+                    sub, Shares(carry_full, carry_deg),
+                    target_degree=1).values
             carry_deg = 1
             carries = [carry_full[:, :, sh.lo:sh.hi] for sh in shards]
             for j in jobs:
@@ -993,7 +1067,8 @@ def range_phase(be, db: RelationLike, jobs: Sequence[RangeJob]) -> Shares:
         outs = plane.run_list(
             lambda v, sh, s0=s0, s1=s1: segment(
                 lhs_parts[sh.index][..., s0:s1],
-                rhs_parts[sh.index][..., s0:s1], carries[sh.index]))
+                rhs_parts[sh.index][..., s0:s1], carries[sh.index]),
+            phase="ripple")
         rb_parts = [o[0] for o in outs]
         carries = [o[1] for o in outs]
         carry_deg = carry_deg + 2 * d * (s1 - s0)
@@ -1019,7 +1094,8 @@ def range_rounds(be, db: RelationLike, jobs: Sequence[RangeJob]
     """
     if not jobs:
         return []
-    ind = range_phase(be, db, jobs)
+    plane = dataplane.as_dataplane(db)
+    ind = range_phase(be, plane, jobs)
     c, n = db.n_shares, db.n_tuples
     out: List[Union[int, List[int], None]] = [None] * len(jobs)
     cnt_idx = [i for i, j in enumerate(jobs) if not j.want_addresses]
@@ -1027,14 +1103,16 @@ def range_rounds(be, db: RelationLike, jobs: Sequence[RangeJob]
     if cnt_idx:
         totals = Shares(field.sum_(ind.values[:, cnt_idx], axis=2),
                         ind.degree)                         # (c, Bc)
-        vals = np.asarray(shamir.interpolate(totals))
+        with span(plane.stats, "user.open"):
+            vals = np.asarray(shamir.interpolate(totals))
         for i, v in zip(cnt_idx, vals):
             jobs[i].ledger.recv(c)
             jobs[i].ledger.user(ind.degree + 1)
             out[i] = int(v)
     if sel_idx:
         bits = Shares(ind.values[:, sel_idx], ind.degree)   # (c, Bs, n)
-        vals = np.asarray(shamir.interpolate(bits))
+        with span(plane.stats, "user.open"):
+            vals = np.asarray(shamir.interpolate(bits))
         for k, i in enumerate(sel_idx):
             jobs[i].ledger.recv(c * n)
             jobs[i].ledger.user((ind.degree + 1) * n)
@@ -1057,26 +1135,26 @@ def _fetch_stack(be, plane, jobs: Sequence[FetchJob],
     db = plane.db
     ellps = []
     mats = []
-    for j in jobs:
-        ell = len(j.addresses)
-        ellp = max(j.padded_rows or ell, ell)
-        ellps.append(ellp)
-        m_sh = _share_one_hot(j.key, db, j.addresses, ellp)     # (c, ℓ', n)
-        mats.append(m_sh.values)
-    stacked = jnp.concatenate(mats + [e.values for e in extras], axis=1)
-    c, _, m, w, a = db.relation.values.shape
+    with span(plane.stats, "user.share"):
+        for j in jobs:
+            ell = len(j.addresses)
+            ellp = max(j.padded_rows or ell, ell)
+            ellps.append(ellp)
+            m_sh = _share_one_hot(j.key, db, j.addresses, ellp)  # (c,ℓ',n)
+            mats.append(m_sh.values)
+        stacked = stack_onehots(tuple(mats + [e.values for e in extras]))
     ds = plane.dispatch_set(                        # ONE dispatch per shard
-        lambda v, sh: be.ss_matmul(
-            stacked[:, :, sh.lo:sh.hi],
-            v.relation.values.reshape(c, sh.n_tuples, m * w * a)),
-        reduce="sum")
+        lambda v, sh: be.ss_matmul(stacked[:, :, sh.lo:sh.hi],
+                                   fetch_relayout(v.relation.values)),
+        reduce="sum", phase="fetch")
     return ds, ellps
 
 
-def _fetch_split(db, fetched_flat, ellps: List[int],
+def _fetch_split(plane, fetched_flat, ellps: List[int],
                  jobs: Sequence[FetchJob], extras: Sequence[FetchEntry]
                  ) -> Tuple[List[List[List[str]]], List[Shares]]:
     """User step after the fused matmul: interpolate, decode, charge."""
+    db = plane.db
     codec = db.codec
     n = db.n_tuples
     c, _, m, w, a = db.relation.values.shape
@@ -1086,7 +1164,8 @@ def _fetch_split(db, fetched_flat, ellps: List[int],
         fetched = Shares(
             fetched_flat[:, :job_rows].reshape(c, job_rows, m, w, a),
             db.base_degree + db.relation.degree)
-        out = np.asarray(shamir.interpolate(fetched))          # (R, m, W, A)
+        with span(plane.stats, "user.open"):
+            out = np.asarray(shamir.interpolate(fetched))      # (R,m,W,A)
         off = 0
         for j, ellp in zip(jobs, ellps):
             ell = len(j.addresses)
@@ -1136,7 +1215,7 @@ def fetch_fusion_multi(be, parts: Sequence[FetchPart]
                                        for _, plane, ds, _ in live])
     for (i, plane, _, ellps), flat in zip(live, fetched):
         _, jobs, extras = parts[i]
-        out[i] = _fetch_split(plane.db, flat, ellps, jobs, extras)
+        out[i] = _fetch_split(plane, flat, ellps, jobs, extras)
     return out
 
 
@@ -1220,7 +1299,7 @@ def join_match_round(be, db: RelationLike, jobs: Sequence[JoinJob]
         m_vals = plane.run_concat(
             lambda v, sh: matcher(
                 jnp.stack([v.column(cx).values for cx in cols_x], axis=1),
-                by_stack), axis=2)                      # (c, B, nx, ny)
+                by_stack), axis=2, phase="join")        # (c, B, nx, ny)
         deg = (db.relation.degree + by_deg) * w_len
         for k, i in enumerate(idxs):
             j = jobs[i]
@@ -1234,7 +1313,8 @@ def join_emit_round(db: RelationLike, jobs: Sequence[JoinJob],
     """User/cloud step 2 of B PK/FK joins: re-randomize the fetched parent
     halves, ship both halves, interpolate ALL jobs' tuples in one fused user
     step per degree class, decode and drop dangling children."""
-    db = dataplane.as_dataplane(db).db
+    plane = dataplane.as_dataplane(db)
+    db = plane.db
     codec = db.codec
     w_len, a_len = codec.word_length, codec.alphabet_size
     c, nx, mx = db.n_shares, db.n_tuples, db.n_attrs
@@ -1253,8 +1333,8 @@ def join_emit_round(db: RelationLike, jobs: Sequence[JoinJob],
         j.ledger.recv(c * ny * (mx + my) * w_len * a_len)
         xs_parts.append(fx)
         ys_parts.append(y_part)
-    xs_all = _open_on_host(xs_parts)
-    ys_all = _open_on_host(ys_parts)
+    xs_all = _open_on_host(plane.stats, xs_parts)
+    ys_all = _open_on_host(plane.stats, ys_parts)
 
     results: List[List[List[str]]] = []
     for j, fx, yp, xs, ys in zip(jobs, xs_parts, ys_parts, xs_all, ys_all):
@@ -1321,7 +1401,7 @@ def equijoin_rounds(be, db: RelationLike, jobs: Sequence[EquiJob]
         j.ledger.recv(c * nx * w_len * a_len
                       + j.right.n_shares * j.right.n_tuples * w_len * a_len)
         col_parts += [bx, by]
-    opened = _open_on_host(col_parts)
+    opened = _open_on_host(plane.stats, col_parts)
     val_lists: List[Tuple[List[str], List[str]]] = []
     for i, j in enumerate(jobs):
         bx, by = col_parts[2 * i], col_parts[2 * i + 1]
@@ -1333,40 +1413,40 @@ def equijoin_rounds(be, db: RelationLike, jobs: Sequence[EquiJob]
 
     # -- phase 2: all layer-1 fetch matrices, X side in ONE matmul -------
     specs = []          # (job, addr_x, addr_y, real, x_mat, y_mat)
-    for j, (x_vals, y_vals) in zip(jobs, val_lists):
-        common = sorted(set(x_vals) & set(y_vals))
-        key = j.key
-        for idx in range(len(common) + j.padded_values):
-            key, kx, ky = jax.random.split(key, 3)
-            real = idx < len(common)
-            if real:
-                v = common[idx]
-                addr_x = [i for i, t in enumerate(x_vals) if t == v]
-                addr_y = [i for i, t in enumerate(y_vals) if t == v]
-            else:   # fake job: all-zero matrices, same traffic (hides k)
-                addr_x, addr_y = [0], [0]
-            j.ledger.round(2)       # Thm 6: two rounds per (fake) value
-            xm = _one_hot_fetch_shares(kx, db, addr_x, j.ledger)
-            ym = _one_hot_fetch_shares(ky, j.right, addr_y, j.ledger)
-            specs.append((j, addr_x, addr_y, real, xm, ym))
+    with span(plane.stats, "user.share"):
+        for j, (x_vals, y_vals) in zip(jobs, val_lists):
+            common = sorted(set(x_vals) & set(y_vals))
+            key = j.key
+            for idx in range(len(common) + j.padded_values):
+                key, kx, ky = jax.random.split(key, 3)
+                real = idx < len(common)
+                if real:
+                    v = common[idx]
+                    addr_x = [i for i, t in enumerate(x_vals) if t == v]
+                    addr_y = [i for i, t in enumerate(y_vals) if t == v]
+                else:   # fake job: all-zero matrices, same traffic (hides k)
+                    addr_x, addr_y = [0], [0]
+                j.ledger.round(2)   # Thm 6: two rounds per (fake) value
+                xm = _one_hot_fetch_shares(kx, db, addr_x, j.ledger)
+                ym = _one_hot_fetch_shares(ky, j.right, addr_y, j.ledger)
+                specs.append((j, addr_x, addr_y, real, xm, ym))
 
     if not specs:       # every job had zero common values and no padding
         return [[] for _ in jobs]
-    x_stack = jnp.concatenate([s[4].values for s in specs], axis=1)
+    x_stack = stack_onehots(tuple(s[4].values for s in specs))
     x_fetched = plane.run_sum(          # ONE X-side dispatch per shard
-        lambda v, sh: be.ss_matmul(
-            x_stack[:, :, sh.lo:sh.hi],
-            v.relation.values.reshape(c, sh.n_tuples, -1)))
+        lambda v, sh: be.ss_matmul(x_stack[:, :, sh.lo:sh.hi],
+                                   fetch_relayout(v.relation.values)),
+        phase="join")
     y_by_right: Dict[int, List[int]] = {}
     for i, s in enumerate(specs):
         y_by_right.setdefault(id(s[0].right), []).append(i)
     y_fetched: Dict[int, jax.Array] = {}
     for _, idxs in y_by_right.items():
         right = specs[idxs[0]][0].right
-        ny = right.n_tuples
-        y_stack = jnp.concatenate([specs[i][5].values for i in idxs], axis=1)
-        out = be.ss_matmul(y_stack, right.relation.values.reshape(
-            right.n_shares, ny, -1))                 # one per right relation
+        y_stack = stack_onehots(tuple(specs[i][5].values for i in idxs))
+        with span(plane.stats, "cloud.join"):  # one per right relation
+            out = be.ss_matmul(y_stack, fetch_relayout(right.relation.values))
         off = 0
         for i in idxs:
             rows_i = specs[i][5].values.shape[1]
@@ -1398,8 +1478,8 @@ def equijoin_rounds(be, db: RelationLike, jobs: Sequence[EquiJob]
         xs_parts.append(pairs_x)
         ys_parts.append(pairs_y)
         metas.append((j, lx * ly))
-    xs_all = _open_on_host(xs_parts)
-    ys_all = _open_on_host(ys_parts)
+    xs_all = _open_on_host(plane.stats, xs_parts)
+    ys_all = _open_on_host(plane.stats, ys_parts)
 
     by_job: Dict[int, List[List[str]]] = {id(j): [] for j in jobs}
     for (j, n_pairs), xs, ys in zip(metas, xs_all, ys_all):

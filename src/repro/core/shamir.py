@@ -294,9 +294,10 @@ def _reshare(key: jax.Array, values: jax.Array, *, need: int,
              target_degree: int) -> jax.Array:
     c = values.shape[0]
     lam = jnp.asarray(_lagrange_at_zero_np(tuple(range(1, need + 1))))
-    # sub[k, j, ...] = share_{k -> j}
-    sub = make_shares(key, values[:need], n_shares=c,
-                      degree=target_degree)                     # (c, d+1, ...)
-    lam_b = lam.reshape((1, need) + (1,) * (values.ndim - 1))
-    return field.sum_(
-        field.mul(sub, jnp.broadcast_to(lam_b, sub.shape)), axis=1)
+    with jax.named_scope("reshare"):
+        # sub[k, j, ...] = share_{k -> j}
+        sub = make_shares(key, values[:need], n_shares=c,
+                          degree=target_degree)                 # (c, d+1, ...)
+        lam_b = lam.reshape((1, need) + (1,) * (values.ndim - 1))
+        return field.sum_(
+            field.mul(sub, jnp.broadcast_to(lam_b, sub.shape)), axis=1)

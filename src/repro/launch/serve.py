@@ -54,11 +54,13 @@ Three overload behaviours are self-tuning:
     ledger, so rows and ledgers stay bit-identical to solo serving).
 
 Per-request latency (enqueue → result), queue-wait and batch-fill
-histograms, close-reason counters, batch/throughput counters, a
-per-family served breakdown, and per-relation ``queue_depth`` /
-``steered_wait_ms`` gauges are kept in ``ServeStats``, both in aggregate
-and per relation; ``snapshot()`` reads it all consistently under the stats
-lock. Per-request keys derive from the target relation's root key in pop
+histograms, close-reason counters, batch counters, a per-family served
+breakdown, per-relation ``queue_depth`` / ``steered_wait_ms`` gauges and
+the host seconds of every program span (``span_s``) are kept in
+``ServeStats``, both in aggregate and per relation; ``snapshot()`` reads
+it all consistently under the stats lock. The scheduler's clock is
+``time.perf_counter``, so waits and latencies never jump with the wall
+clock. Per-request keys derive from the target relation's root key in pop
 order (streams are per relation, so tenants never perturb each other's
 transcripts); an optional ``MapReduceExecutor`` fans each cloud-side map
 phase (including the fused batch dispatch) out over fault-tolerant worker
@@ -82,7 +84,7 @@ from ..api import (DEFAULT_RELATION, MapReduceExecutor, Plan, QueryClient,
                    QueryResult)
 from ..api.plans import PATTERN_PREDICATES
 from ..core.dataplane import (Dispatcher, ShardedRelation,
-                              ThreadedDispatcher)
+                              ThreadedDispatcher, span)
 from ..core.engine import SecretSharedDB
 from ..models import decode_step, init_cache, prefill
 from ..models.config import ModelConfig
@@ -233,11 +235,13 @@ def _window() -> "Deque[float]":
 class RelationStats:
     """One relation's slice of the serving telemetry.
 
-    ``dispatches`` / ``dispatch_s`` / ``transfer_bytes`` mirror the
-    relation dataplane's :class:`~repro.core.dataplane.DispatchStats`
-    deltas, accumulated per served batch — so the measured cloud-step
-    wall-time and staged bytes (zero after placement for a device-resident
-    dispatcher) are visible to monitoring code, not only dispatch counts.
+    ``dispatches`` / ``transfer_bytes`` / ``span_s`` mirror the relation
+    dataplane's :class:`~repro.core.dataplane.DispatchStats` deltas,
+    accumulated per served batch — so the staged bytes (zero after
+    placement for a device-resident dispatcher) and the host seconds of
+    each program span (``cloud.<phase>`` steps, ``client.plan``,
+    ``user.share``, ``user.open``) are visible to monitoring code, not
+    only dispatch counts.
 
     ``queue_depth`` and ``steered_wait_ms`` are *gauges* (last observed
     value, refreshed each served batch, not accumulated):
@@ -251,8 +255,8 @@ class RelationStats:
     batches: int = 0
     busy_s: float = 0.0
     dispatches: int = 0
-    dispatch_s: float = 0.0
     transfer_bytes: int = 0
+    span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     queue_depth: int = 0
     steered_wait_ms: float = 0.0
     wait_trajectory_ms: "Deque[float]" = dataclasses.field(
@@ -269,8 +273,8 @@ class RelationStats:
         return dict(served=self.served, failed=self.failed,
                     batches=self.batches, busy_s=self.busy_s,
                     dispatches=self.dispatches,
-                    dispatch_s=self.dispatch_s,
                     transfer_bytes=self.transfer_bytes,
+                    span_s=dict(self.span_s),
                     queue_depth=self.queue_depth,
                     steered_wait_ms=self.steered_wait_ms,
                     wait_trajectory_ms=list(self.wait_trajectory_ms),
@@ -292,7 +296,10 @@ class ServeStats:
     Top-level counters/histograms aggregate over every relation (the
     pre-multi-tenant surface, unchanged); :attr:`relations` carries the
     per-relation breakdown — served_by_family, queue-wait and batch-fill
-    histograms keyed by registry name.
+    histograms keyed by registry name. ``span_s`` holds the host seconds
+    of every program span by name: the scheduler's own ``serve.park``
+    (waiting with no batch due) and ``serve.batch`` (one closed batch or
+    fused wave), plus each relation's dataplane spans.
 
     Writers and readers run on different threads (scheduler vs monitoring
     code), so every mutation goes through the ``note_*``/``record_batch``
@@ -306,8 +313,9 @@ class ServeStats:
     batches: int = 0
     busy_s: float = 0.0              # wall time spent inside run_batch
     dispatches: int = 0              # shard dispatches (dataplane deltas)
-    dispatch_s: float = 0.0          # cloud-step wall-time (dataplane)
     transfer_bytes: int = 0          # staged bytes (dataplane)
+    span_s: Dict[str, float] = dataclasses.field(
+        default_factory=dict)       # program span name -> host seconds
     latencies_s: "Deque[float]" = dataclasses.field(default_factory=_window)
     queue_waits_s: "Deque[float]" = dataclasses.field(
         default_factory=_window)
@@ -326,10 +334,6 @@ class ServeStats:
     def mean_batch_size(self) -> float:
         return self.served / self.batches if self.batches else 0.0
 
-    @property
-    def throughput_qps(self) -> float:
-        return self.served / self.busy_s if self.busy_s > 0 else 0.0
-
     def _rel_locked(self, relation: Optional[str]) -> RelationStats:
         rs = self.relations.get(relation or "")
         if rs is None:
@@ -337,6 +341,11 @@ class ServeStats:
         return rs
 
     # -- locked writers (called from the pump, any thread) ------------------
+    def add_span(self, name: str, seconds: float) -> None:
+        """A program span of the server itself (see ``dataplane.span``)."""
+        with self._lock:
+            self.span_s[name] = self.span_s.get(name, 0.0) + seconds
+
     def note_queue_wait(self, wait_s: float,
                         relation: Optional[str] = None) -> None:
         with self._lock:
@@ -377,12 +386,13 @@ class ServeStats:
     def record_batch(self, fill: int, reason: str,
                      relation: Optional[str] = None,
                      busy_s: float = 0.0, dispatches: int = 0,
-                     dispatch_s: float = 0.0,
                      transfer_bytes: int = 0,
+                     span_s: Optional[Dict[str, float]] = None,
                      queue_depth: Optional[int] = None,
                      steered_wait_ms: Optional[float] = None) -> None:
-        """One closed batch. ``queue_depth``/``steered_wait_ms`` refresh
-        the relation's gauges (and the steering trajectory) when given."""
+        """One closed batch. ``span_s`` is the dataplane's span seconds
+        during it; ``queue_depth``/``steered_wait_ms`` refresh the
+        relation's gauges (and the steering trajectory) when given."""
         with self._lock:
             for st in ([self] if relation is None
                        else [self, self._rel_locked(relation)]):
@@ -391,8 +401,9 @@ class ServeStats:
                 st.batch_fill[fill] = st.batch_fill.get(fill, 0) + 1
                 st.closes[reason] = st.closes.get(reason, 0) + 1
                 st.dispatches += dispatches
-                st.dispatch_s += dispatch_s
                 st.transfer_bytes += transfer_bytes
+                for name, seconds in (span_s or {}).items():
+                    st.span_s[name] = st.span_s.get(name, 0.0) + seconds
             if relation is not None:
                 rs = self._rel_locked(relation)
                 if queue_depth is not None:
@@ -432,9 +443,8 @@ class ServeStats:
                         mean_batch_size=self.mean_batch_size,
                         busy_s=self.busy_s,
                         dispatches=self.dispatches,
-                        dispatch_s=self.dispatch_s,
                         transfer_bytes=self.transfer_bytes,
-                        throughput_qps=self.throughput_qps,
+                        span_s=dict(self.span_s),
                         p50_latency_s=_quantile(list(self.latencies_s),
                                                 0.50),
                         p95_latency_s=_quantile(list(self.latencies_s),
@@ -690,7 +700,7 @@ class QueryServer:
         tenant = self._tenant(relation if relation is not None
                               else request.relation)
         request.relation = tenant.name
-        request.enqueued_at = time.time()
+        request.enqueued_at = time.perf_counter()
         with self._cond:
             if self._rejecting:
                 request.error = ServerStopped(
@@ -782,71 +792,75 @@ class QueryServer:
 
         After each batch the tenant's deadline is steered
         (:meth:`_Tenant.steer`) and its ``queue_depth`` /
-        ``steered_wait_ms`` gauges are refreshed.
+        ``steered_wait_ms`` gauges are refreshed. The whole call is the
+        span ``serve.batch``.
         """
-        t0 = time.time()
-        for tenant, _reason, batch in closed:
-            for r in batch:
-                r.queue_wait_s = t0 - (r.enqueued_at or t0)
-                self.stats.note_queue_wait(r.queue_wait_s, tenant.name)
-        planes = {t.name: self.client.dataplane_of(t.name)
-                  for t, _, _ in closed}
-        d0s = {name: dataclasses.replace(p.stats) if p else None
-               for name, p in planes.items()}
-        fused: Optional[List[List[QueryResult]]] = None
-        if len(closed) > 1:
-            try:
-                fused = self.client.run_batch_multi(
-                    [(t.name, [r.plan for r in batch])
-                     for t, _, batch in closed])
-            except Exception:  # noqa: BLE001 — isolate failing relation(s)
-                fused = None
-        t_prev = t0
-        for i, (tenant, reason, batch) in enumerate(closed):
-            if fused is not None:
-                outcomes: List[Union[QueryResult, Exception]] = \
-                    list(fused[i])
-            else:
+        with span(self.stats, "serve.batch"):
+            t0 = time.perf_counter()
+            for tenant, _reason, batch in closed:
+                for r in batch:
+                    r.queue_wait_s = t0 - (r.enqueued_at or t0)
+                    self.stats.note_queue_wait(r.queue_wait_s, tenant.name)
+            planes = {t.name: self.client.dataplane_of(t.name)
+                      for t, _, _ in closed}
+            d0s = {name: p.stats.copy() if p else None
+                   for name, p in planes.items()}
+            fused: Optional[List[List[QueryResult]]] = None
+            if len(closed) > 1:
                 try:
-                    outcomes = list(self.client.run_batch(
-                        [r.plan for r in batch], relation=tenant.name))
-                except Exception:  # noqa: BLE001 — isolate request(s)
-                    outcomes = []
-                    for r in batch:
-                        try:
-                            outcomes.append(self.client.run_batch(
-                                [r.plan], relation=tenant.name)[0])
-                        except Exception as e:  # noqa: BLE001
-                            outcomes.append(e)
-            t1 = time.time()
-            # busy accounting: a fused wave's wall is split across its
-            # relations (the aggregate stays the wall actually spent);
-            # sequential fallbacks charge their own span.
-            busy = ((t1 - t0) / len(closed) if fused is not None
-                    else t1 - t_prev)
-            t_prev = t1
-            for r, res in zip(batch, outcomes):
-                r.latency_s = t1 - (r.enqueued_at or t0)
-                if isinstance(res, Exception):
-                    r.error = res
-                    self.stats.note_result(r.latency_s, None, tenant.name)
+                    fused = self.client.run_batch_multi(
+                        [(t.name, [r.plan for r in batch])
+                         for t, _, batch in closed])
+                except Exception:  # noqa: BLE001 — isolate failing relations
+                    fused = None
+            t_prev = t0
+            for i, (tenant, reason, batch) in enumerate(closed):
+                if fused is not None:
+                    outcomes: List[Union[QueryResult, Exception]] = \
+                        list(fused[i])
                 else:
-                    r.result = res
-                    self.stats.note_result(r.latency_s,
-                                           plan_family(r.plan), tenant.name)
-                r._done.set()
-            plane, d0 = planes[tenant.name], d0s[tenant.name]
-            d = plane.stats if plane else None
-            with self._cond:
-                depth = len(tenant.queue)
-                steered = tenant.steer(reason, len(batch))
-            self.stats.record_batch(
-                len(batch), reason, tenant.name, busy_s=busy,
-                dispatches=(d.dispatches - d0.dispatches) if d else 0,
-                dispatch_s=(d.dispatch_s - d0.dispatch_s) if d else 0.0,
-                transfer_bytes=(d.transfer_bytes - d0.transfer_bytes)
-                if d else 0,
-                queue_depth=depth, steered_wait_ms=steered)
+                    try:
+                        outcomes = list(self.client.run_batch(
+                            [r.plan for r in batch], relation=tenant.name))
+                    except Exception:  # noqa: BLE001 — isolate request(s)
+                        outcomes = []
+                        for r in batch:
+                            try:
+                                outcomes.append(self.client.run_batch(
+                                    [r.plan], relation=tenant.name)[0])
+                            except Exception as e:  # noqa: BLE001
+                                outcomes.append(e)
+                t1 = time.perf_counter()
+                # busy accounting: a fused wave's wall is split across its
+                # relations (the aggregate stays the wall actually spent);
+                # sequential fallbacks charge their own span.
+                busy = ((t1 - t0) / len(closed) if fused is not None
+                        else t1 - t_prev)
+                t_prev = t1
+                for r, res in zip(batch, outcomes):
+                    r.latency_s = t1 - (r.enqueued_at or t0)
+                    if isinstance(res, Exception):
+                        r.error = res
+                        self.stats.note_result(r.latency_s, None, tenant.name)
+                    else:
+                        r.result = res
+                        self.stats.note_result(r.latency_s,
+                                               plan_family(r.plan),
+                                               tenant.name)
+                    r._done.set()
+                plane, d0 = planes[tenant.name], d0s[tenant.name]
+                d = plane.stats if plane else None
+                with self._cond:
+                    depth = len(tenant.queue)
+                    steered = tenant.steer(reason, len(batch))
+                self.stats.record_batch(
+                    len(batch), reason, tenant.name, busy_s=busy,
+                    dispatches=(d.dispatches - d0.dispatches) if d else 0,
+                    transfer_bytes=(d.transfer_bytes - d0.transfer_bytes)
+                    if d else 0,
+                    span_s=({k: v - d0.span_s.get(k, 0.0)
+                             for k, v in d.span_s.items()} if d else None),
+                    queue_depth=depth, steered_wait_ms=steered)
 
     # -- async driver -------------------------------------------------------
     def start(self) -> "QueryServer":
@@ -952,7 +966,8 @@ class QueryServer:
             with self._cond:
                 while not self._stopping and not any(
                         t.queue for t in self._tenants.values()):
-                    self._cond.wait()       # submit()/stop()/attach notify
+                    with span(self.stats, "serve.park"):
+                        self._cond.wait()   # submit()/stop()/attach notify
                 if self._stopping:
                     break
                 # per-relation close decisions: a batch group closes by
@@ -966,7 +981,7 @@ class QueryServer:
                 # neighbour's expired deadline. EVERY relation due in the
                 # same scan closes together — the batches then run as one
                 # fused dispatch wave (see _run_closed).
-                now = time.time()
+                now = time.perf_counter()
                 earliest: Optional[float] = None
                 for name in self._rotation():
                     t = self._tenants[name]
@@ -984,7 +999,8 @@ class QueryServer:
                 if not todos:
                     # floored park: a sub-ms (or steered-to-tiny) deadline
                     # must not degrade the loop into a busy-spin.
-                    self._cond.wait(max(MIN_PARK_S, earliest - now))
+                    with span(self.stats, "serve.park"):
+                        self._cond.wait(max(MIN_PARK_S, earliest - now))
                     continue
             self._pump_due(todos)
         # drain-before-exit: close a final batch per relation so stop()
