@@ -383,11 +383,16 @@ class DispatchStats:
     for cloud steps (submission plus the combine; jax dispatch is
     asynchronous, so this is not device time), ``client.plan``,
     ``user.share`` and ``user.open`` for the user's side of a batch.
+    ``table_splits`` counts the shard tables split into resident int8
+    digits (:meth:`ShardedRelation.table_digits`), ``presplit_contractions``
+    the shard contractions that read such digits.
     """
     dispatches: int = 0             # shard dispatches executed
     steps: int = 0                  # cloud steps (DispatchSets) executed
     fused_steps: int = 0            # steps executed inside a fused wave
     transfer_bytes: int = 0         # staged bytes (see above)
+    table_splits: int = 0           # resident digit sets made
+    presplit_contractions: int = 0  # contractions over resident digits
     span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def record(self, n_dispatches: int, transfer_bytes: int = 0,
@@ -441,6 +446,7 @@ class ShardedRelation:
         self.dispatcher = dispatcher or SERIAL
         self.stats = DispatchStats()
         self._views: dict = {}
+        self._digits: dict = {}
 
     # -- SecretSharedDB delegation (user-side code reads relation metadata
     # off the plane without caring about the shard count) -------------------
@@ -512,6 +518,24 @@ class ShardedRelation:
                 numeric_bits=dict(db.numeric_bits),
                 base_degree=db.base_degree)
         return self._views[index]
+
+    def table_digits(self, index: int) -> Tuple[jax.Array, ...]:
+        """Shard ``index``'s share tensor as its four int8 digits
+        (``field.table_digits``), made on the device once per view and
+        kept beside it: what ``field.matmul`` reads in place of a table
+        it would split on every call."""
+        digits = self._digits.get(index)
+        if digits is None:
+            digits = field.table_digits(self.view(index).relation.values)
+            self._digits[index] = digits
+            self.stats.table_splits += 1
+        return digits
+
+    def clear_views(self) -> None:
+        """Drop the cached shard views and their digits, after ``db`` has
+        been replaced (a placement)."""
+        self._views.clear()
+        self._digits.clear()
 
     # -- dispatch -----------------------------------------------------------
     def dispatch_set(self, build: Callable[[SecretSharedDB, Shard], Any],

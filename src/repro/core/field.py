@@ -27,7 +27,7 @@ DTYPE = jnp.uint32
 
 __all__ = [
     "P", "DTYPE", "to_field", "add", "sub", "neg", "mul", "pow_", "inv",
-    "sum_", "dot", "matmul", "uniform", "from_signed",
+    "sum_", "dot", "matmul", "table_digits", "uniform", "from_signed",
 ]
 
 
@@ -155,11 +155,18 @@ def digits8(x: jax.Array):
     return out
 
 
-def limb_contract(a: jax.Array, b: jax.Array, dot) -> jax.Array:
+def _is_digits(b) -> bool:
+    """Whether ``b`` is already split: the digits of :func:`digits8`."""
+    return isinstance(b, (tuple, list))
+
+
+def limb_contract(a: jax.Array, b, dot) -> jax.Array:
     """Σ_k a·b mod p as 16 exact int8 dots: ``dot(x, y)`` contracts two int8
     digit arrays into int32 (|Σ| ≤ K·2¹⁴, exact while K ≤ ``LIMB_K_MAX``).
-    Digit pair (i, j) carries weight 2^{8(i+j)}, a 31-bit rotate mod p."""
-    ad, bd = digits8(a), digits8(b)
+    Digit pair (i, j) carries weight 2^{8(i+j)}, a 31-bit rotate mod p.
+    ``b`` is a uint32 array or its four digits from :func:`digits8`."""
+    ad = digits8(a)
+    bd = b if _is_digits(b) else digits8(b)
     acc = None
     for i in range(4):
         for j in range(4):
@@ -171,7 +178,15 @@ def limb_contract(a: jax.Array, b: jax.Array, dot) -> jax.Array:
 
 
 @jax.jit
-def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+def table_digits(x: jax.Array):
+    """:func:`digits8` of ``x`` as one program of its own: the digits of an
+    operand that :func:`matmul` contracts many times, made once and passed
+    in its place."""
+    return tuple(digits8(x))
+
+
+@jax.jit
+def matmul(a: jax.Array, b) -> jax.Array:
     """Modular matmul ``a @ b`` for 2-D (or batched) uint32 operands.
 
     Each operand splits into four signed 8-bit limbs (:func:`digits8`), so
@@ -181,6 +196,11 @@ def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     kernel (kernels/ss_matmul.py) is the same algorithm tiled for VMEM.
     Its operations sit under the name scope ``fetch``: the oblivious fetch
     and the embedding lookup, a fetch of one-hot rows, are its main users.
+
+    ``b`` may come already split, as the four int8 digits that
+    :func:`digits8` (or :func:`table_digits`) returns: then only ``a`` is
+    split here, and the result is bit-identical. The pytree's structure
+    picks the branch at trace time.
     """
     def dot(x, y):
         return jnp.matmul(x, y, preferred_element_type=jnp.int32)
@@ -189,8 +209,11 @@ def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     acc = None
     with jax.named_scope("fetch"):
         for k0 in range(0, max(k_dim, 1), LIMB_K_MAX):
-            part = limb_contract(a[..., k0:k0 + LIMB_K_MAX],
-                                 b[..., k0:k0 + LIMB_K_MAX, :], dot)
+            if _is_digits(b):
+                bk = [d[..., k0:k0 + LIMB_K_MAX, :] for d in b]
+            else:
+                bk = b[..., k0:k0 + LIMB_K_MAX, :]
+            part = limb_contract(a[..., k0:k0 + LIMB_K_MAX], bk, dot)
             acc = part if acc is None else addmod32(acc, part)
     return acc
 

@@ -125,7 +125,7 @@ class MeshDispatcher(Dispatcher):
             numeric={c: put(s) for c, s in db.numeric.items()},
             numeric_bits=dict(db.numeric_bits),
             base_degree=db.base_degree)
-        plane._views.clear()
+        plane.clear_views()
         plane._mesh_placed_by = self
 
     # -- the dispatch seam --------------------------------------------------

@@ -194,10 +194,19 @@ def embed_phase(be, rel: RelationLike, jobs: Sequence[EmbedJob]
             spans.append((pos, pos + int(flat.size)))
             pos += int(flat.size)
         stacked = mats[0] if len(mats) == 1 else stack_onehots(tuple(mats))
+    # The jnp contraction takes the fixed table as resident int8 digits,
+    # split once per shard view rather than on every step; any other
+    # ss_matmul (Pallas, the MapReduce split) reads the uint32 shares.
+    presplit = be.ss_matmul is field.matmul
+    tables = ([plane.table_digits(sh.index) for sh in plane.shards]
+              if presplit else None)
     fetched = plane.run_sum(
-        lambda view, sh: be.ss_matmul(stacked[:, :, sh.lo:sh.hi],
-                                      view.relation.values),
+        lambda view, sh: be.ss_matmul(
+            stacked[:, :, sh.lo:sh.hi],
+            tables[sh.index] if presplit else view.relation.values),
         phase="embed")                                            # (c, N, D)
+    if presplit:
+        plane.stats.presplit_contractions += plane.n_shards
     out_sh = Shares(fetched, out_deg)
 
     # Table-1 billing, per job: one round; the shared one-hots go up, the

@@ -235,13 +235,14 @@ def _window() -> "Deque[float]":
 class RelationStats:
     """One relation's slice of the serving telemetry.
 
-    ``dispatches`` / ``transfer_bytes`` / ``span_s`` mirror the relation
-    dataplane's :class:`~repro.core.dataplane.DispatchStats` deltas,
-    accumulated per served batch — so the staged bytes (zero after
-    placement for a device-resident dispatcher) and the host seconds of
-    each program span (``cloud.<phase>`` steps, ``client.plan``,
-    ``user.share``, ``user.open``) are visible to monitoring code, not
-    only dispatch counts.
+    ``dispatches`` / ``transfer_bytes`` / ``table_splits`` /
+    ``presplit_contractions`` / ``span_s`` mirror the relation dataplane's
+    :class:`~repro.core.dataplane.DispatchStats` deltas, accumulated per
+    served batch — so the staged bytes (zero after placement for a
+    device-resident dispatcher), the resident table digits made and read,
+    and the host seconds of each program span (``cloud.<phase>`` steps,
+    ``client.plan``, ``user.share``, ``user.open``) are visible to
+    monitoring code, not only dispatch counts.
 
     ``queue_depth`` and ``steered_wait_ms`` are *gauges* (last observed
     value, refreshed each served batch, not accumulated):
@@ -256,6 +257,8 @@ class RelationStats:
     busy_s: float = 0.0
     dispatches: int = 0
     transfer_bytes: int = 0
+    table_splits: int = 0
+    presplit_contractions: int = 0
     span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     queue_depth: int = 0
     steered_wait_ms: float = 0.0
@@ -274,6 +277,8 @@ class RelationStats:
                     batches=self.batches, busy_s=self.busy_s,
                     dispatches=self.dispatches,
                     transfer_bytes=self.transfer_bytes,
+                    table_splits=self.table_splits,
+                    presplit_contractions=self.presplit_contractions,
                     span_s=dict(self.span_s),
                     queue_depth=self.queue_depth,
                     steered_wait_ms=self.steered_wait_ms,
@@ -314,6 +319,8 @@ class ServeStats:
     busy_s: float = 0.0              # wall time spent inside run_batch
     dispatches: int = 0              # shard dispatches (dataplane deltas)
     transfer_bytes: int = 0          # staged bytes (dataplane)
+    table_splits: int = 0            # resident digit sets made (dataplane)
+    presplit_contractions: int = 0   # contractions over them (dataplane)
     span_s: Dict[str, float] = dataclasses.field(
         default_factory=dict)       # program span name -> host seconds
     latencies_s: "Deque[float]" = dataclasses.field(default_factory=_window)
@@ -386,7 +393,8 @@ class ServeStats:
     def record_batch(self, fill: int, reason: str,
                      relation: Optional[str] = None,
                      busy_s: float = 0.0, dispatches: int = 0,
-                     transfer_bytes: int = 0,
+                     transfer_bytes: int = 0, table_splits: int = 0,
+                     presplit_contractions: int = 0,
                      span_s: Optional[Dict[str, float]] = None,
                      queue_depth: Optional[int] = None,
                      steered_wait_ms: Optional[float] = None) -> None:
@@ -402,6 +410,8 @@ class ServeStats:
                 st.closes[reason] = st.closes.get(reason, 0) + 1
                 st.dispatches += dispatches
                 st.transfer_bytes += transfer_bytes
+                st.table_splits += table_splits
+                st.presplit_contractions += presplit_contractions
                 for name, seconds in (span_s or {}).items():
                     st.span_s[name] = st.span_s.get(name, 0.0) + seconds
             if relation is not None:
@@ -444,6 +454,8 @@ class ServeStats:
                         busy_s=self.busy_s,
                         dispatches=self.dispatches,
                         transfer_bytes=self.transfer_bytes,
+                        table_splits=self.table_splits,
+                        presplit_contractions=self.presplit_contractions,
                         span_s=dict(self.span_s),
                         p50_latency_s=_quantile(list(self.latencies_s),
                                                 0.50),
@@ -857,6 +869,11 @@ class QueryServer:
                     len(batch), reason, tenant.name, busy_s=busy,
                     dispatches=(d.dispatches - d0.dispatches) if d else 0,
                     transfer_bytes=(d.transfer_bytes - d0.transfer_bytes)
+                    if d else 0,
+                    table_splits=(d.table_splits - d0.table_splits)
+                    if d else 0,
+                    presplit_contractions=(d.presplit_contractions
+                                           - d0.presplit_contractions)
                     if d else 0,
                     span_s=({k: v - d0.span_s.get(k, 0.0)
                              for k, v in d.span_s.items()} if d else None),

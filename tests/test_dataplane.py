@@ -361,6 +361,25 @@ def test_sharded_dispatch_counts_scale_with_shards(range_db):
     assert calls4["aa_match_batch"] == 4 and calls4["ss_matmul"] == 4
 
 
+@pytest.mark.parametrize("shards", [1, 3])
+def test_match_relation_fetch_makes_no_table_digits(range_db, shards):
+    """Only an embedding table is kept as resident digits: a one-round
+    select's fetch contracts the relation's uint32 shares as before."""
+    rows, db = range_db
+    client = QueryClient(db, key=9)
+    plane = client.attach(shards=shards)
+    plans = [Select(Eq("Name", "nm2"), strategy="one_round"),
+             Select(Eq("Name", "zzz"), strategy="one_round")]
+    got = client.run_batch(plans)
+    for res, name in zip(got, ("nm2", "zzz")):
+        assert res.strategy == "one_round"
+        assert res.addresses == [i for i, r in enumerate(rows)
+                                 if r[1] == name]
+        assert res.rows == [r for r in rows if r[1] == name]
+    assert plane.stats.table_splits == 0
+    assert plane.stats.presplit_contractions == 0
+
+
 # ---------------------------------------------------------------------------
 # fused-op parity oracles
 # ---------------------------------------------------------------------------

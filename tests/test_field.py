@@ -118,6 +118,44 @@ def test_matmul_limb_chunks_exact(k, fill):
     assert np.array_equal(got.astype(object), want)
 
 
+EDGES = np.array([0, 1, 2**30 - 1, 2**30, P - 2**30, P - 1], np.uint64)
+
+
+def _with_edges(x):
+    """``x`` with the digit split's edge residues spread through it."""
+    flat = x.reshape(-1)
+    flat[::7] = np.resize(EDGES, flat[::7].shape)
+    return x
+
+
+@pytest.mark.parametrize("split", ["digits8", "table_digits"])
+@pytest.mark.parametrize("lead", [(), (3,)], ids=["2d", "batched"])
+@pytest.mark.parametrize("k", [1, 300, field.LIMB_K_MAX + 37])
+def test_matmul_presplit_operand_bit_identical(k, lead, split):
+    # the right operand handed over as its int8 digits gives the same
+    # residues as the uint32 operand, across a LIMB_K_MAX chunk boundary
+    rng = np.random.default_rng(k + len(lead))
+    a = _with_edges(rng.integers(0, P, size=lead + (5, k), dtype=np.uint64))
+    b = _with_edges(rng.integers(0, P, size=lead + (k, 4), dtype=np.uint64))
+    a, b = a.astype(np.uint32), b.astype(np.uint32)
+    digits = getattr(field, split)(jax.numpy.asarray(b))
+    got = np.asarray(field.matmul(a, digits))
+    assert np.array_equal(got, np.asarray(field.matmul(a, b)))
+    if k <= 300:
+        want = (a.astype(object) @ b.astype(object)) % P
+        assert np.array_equal(got.astype(object), want)
+
+
+def test_digits8_recombine_to_residue():
+    rng = np.random.default_rng(2)
+    x = np.concatenate([EDGES, rng.integers(0, P, 4096, dtype=np.uint64)])
+    digits = [np.asarray(d) for d in
+              field.digits8(jax.numpy.asarray(x.astype(np.uint32)))]
+    assert all(d.dtype == np.int8 for d in digits)
+    total = sum(d.astype(object) * 2 ** (8 * i) for i, d in enumerate(digits))
+    assert np.array_equal(total % P, x.astype(object))
+
+
 def test_slide_chain_at_xla_cpu_fold_shape():
     # At this shape (27 clouds, 256 tuples, W=12, A=69, k=4) XLA:CPU
     # (jax 0.9.0) returned one wrong window product, at tuple 0 of one

@@ -24,8 +24,10 @@ import numpy as np
 import pytest
 
 from repro.api import (EmbedLookup, MeshDispatcher, QueryClient,
-                       ThreadedDispatcher, estimate_embed_cost)
+                       ShardedRelation, ThreadedDispatcher,
+                       estimate_embed_cost, get_backend)
 from repro.core import shamir
+from repro.core.costs import CostLedger
 from repro.core.queries import embed as embed_q
 from repro.models import private_embed as pe
 
@@ -114,6 +116,86 @@ def test_one_fused_dispatch_per_step_per_shard(table_sh):
         client.run(EmbedLookup(tokens=(4, 5, 6, 7)), relation="emb")
         assert plane.stats.dispatches - d0 == shards
         assert plane.stats.transfer_bytes == placed   # device residency
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+@pytest.mark.parametrize("disp", ["serial", "threaded", "mesh"])
+def test_resident_digits_split_once_and_match_oracle(table_sh, shards, disp):
+    # the jnp contraction reads the table's int8 digits, made once per
+    # shard view; every lookup still opens to the per-token oracle
+    dispatcher = {"serial": None,
+                  "threaded": ThreadedDispatcher(max_workers=2),
+                  "mesh": MeshDispatcher(strict_transfers=True)}[disp]
+    client = _client(table_sh, shards=shards, dispatcher=dispatcher)
+    plane = client._entry("emb").dataplane
+    rng = np.random.default_rng(11)
+    lookups, placed = 5, None
+    for i in range(lookups):
+        tokens = tuple(int(t) for t in rng.integers(0, V, 6))
+        res = client.run(EmbedLookup(tokens=tokens), relation="emb")
+        assert np.array_equal(np.asarray(res.embeddings),
+                              _oracle(table_sh, tokens))
+        if placed is None:
+            placed = plane.stats.transfer_bytes
+    assert plane.stats.table_splits == plane.n_shards == shards
+    assert plane.stats.presplit_contractions == lookups * shards
+    if disp == "mesh":
+        assert plane.stats.transfer_bytes == placed   # device residency
+
+
+def test_reattached_table_gets_fresh_digits(table, table_sh):
+    other = pe.setup_private_embed(jax.random.PRNGKey(6), -table[::-1],
+                                   n_shares=4)
+    client = _client(table_sh, shards=2)
+    tokens = (0, 5, V - 1)
+    first = client.run(EmbedLookup(tokens=tokens), relation="emb")
+    client.attach(pe.as_embed_relation(other), name="emb", shards=2)
+    again = client.run(EmbedLookup(tokens=tokens), relation="emb")
+    assert np.array_equal(np.asarray(first.embeddings),
+                          _oracle(table_sh, tokens))
+    assert np.array_equal(np.asarray(again.embeddings),
+                          _oracle(other, tokens))
+    assert not np.array_equal(np.asarray(first.embeddings),
+                              np.asarray(again.embeddings))
+    assert client._entry("emb").dataplane.stats.table_splits == 2
+
+
+def test_placement_drops_digits_of_the_old_views(table, table_sh):
+    # a plane that split its views and is then placed by a mesh
+    # dispatcher (or has its db replaced) makes its digits anew
+    other = pe.setup_private_embed(jax.random.PRNGKey(6), -table[::-1],
+                                   n_shares=4)
+    plane = ShardedRelation(pe.as_embed_relation(table_sh), shards=2)
+    be = get_backend("jnp")
+
+    def lookup(tokens):
+        job = embed_q.EmbedJob(tokens=np.asarray(tokens),
+                               key=jax.random.PRNGKey(4),
+                               ledger=CostLedger())
+        return embed_q.embed_phase(be, plane, [job])[0]
+
+    tokens = (1, 2, 40)
+    assert np.array_equal(lookup(tokens), _oracle(table_sh, tokens))
+    plane.dispatcher = MeshDispatcher()
+    lookup(tokens)                                # placed during this step
+    assert np.array_equal(lookup(tokens), _oracle(table_sh, tokens))
+    assert plane.stats.table_splits == 4          # before and after placing
+    plane.db = pe.as_embed_relation(other)
+    plane.clear_views()
+    assert np.array_equal(lookup(tokens), _oracle(other, tokens))
+    assert plane.stats.table_splits == 6
+    assert plane.stats.presplit_contractions == 4 * 2
+
+
+def test_pallas_backend_contracts_the_uint32_table(table_sh):
+    pytest.importorskip("jax.experimental.pallas")
+    client = QueryClient(key=3, backend="pallas")
+    plane = client.attach(pe.as_embed_relation(table_sh), name="emb")
+    tokens = (3, 9, V - 2)
+    res = client.run(EmbedLookup(tokens=tokens), relation="emb")
+    assert np.array_equal(np.asarray(res.embeddings),
+                          _oracle(table_sh, tokens))
+    assert plane.stats.table_splits == plane.stats.presplit_contractions == 0
 
 
 def test_batch_of_jobs_fuses_and_matches_sequential(table_sh):
@@ -399,3 +481,28 @@ def test_query_server_routes_embed_family(table_sh):
     assert emb.ledger == solo.ledger          # tenant == solo, bit for bit
     assert cnt.count >= 1
     assert srv.stats.batches >= 2
+
+
+def test_query_server_counts_splits_at_warmup_only(table_sh):
+    # after a warm-up batch has split the table, a served window makes no
+    # digits and every embed step contracts the resident ones
+    from repro.launch.serve import QueryServer
+    shards = 2
+    with QueryServer(key=1) as srv:
+        srv.attach("emb", pe.as_embed_relation(table_sh), shards=shards)
+        srv.submit(EmbedLookup(tokens=(1,)), relation="emb")
+        srv.pump(relation="emb")
+        assert srv.stats.snapshot()["table_splits"] == shards
+        srv.reset()
+        for i in range(3):
+            handles = [srv.submit(EmbedLookup(tokens=(i, 2 * i + 1)),
+                                  relation="emb") for _ in range(2)]
+            srv.pump(relation="emb")
+            for h in handles:
+                assert h.wait(timeout=30).result is not None
+        snap = srv.stats.snapshot()
+    rel = snap["relations"]["emb"]
+    assert rel["batches"] == 3
+    assert snap["table_splits"] == rel["table_splits"] == 0
+    assert snap["presplit_contractions"] == rel["presplit_contractions"] \
+        == 3 * shards

@@ -133,6 +133,22 @@ def test_field_matmul_compiles(one_chip, shape):
                         for ln in dots)           # exact int8 -> int32 dots
 
 
+def test_field_matmul_resident_digits_compiles(one_chip):
+    # the embedding table as the four int8 digits kept beside it: the
+    # same exact int8 -> int32 dots, and a split program with no temps
+    a = jax.ShapeDtypeStruct((C_EMBED, TOKENS, VOCAB), U32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((C_EMBED, VOCAB, D_MODEL), U32,
+                                 sharding=one_chip)
+    digits = tuple(jax.ShapeDtypeStruct(table.shape, jnp.int8,
+                                        sharding=one_chip) for _ in range(4))
+    text = _compile(field.matmul, a, digits)
+    dots = [ln for ln in text.splitlines() if "convolution(" in ln]
+    assert dots and all(ln.split("=")[1].lstrip().startswith("s32")
+                        for ln in dots)
+    split = jax.jit(field.table_digits).lower(table).compile()
+    assert split.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_mesh_reduce_compiles(topo, n_dev):
     mesh = make_mesh((n_dev, 1), ("data", "model"),
