@@ -131,6 +131,23 @@ def _lower_match(db: SecretSharedDB, where, context: str
     raise _planner.PlanNotSupported(where, context)
 
 
+def _ripple_every(db: SecretSharedDB, plan: Plan, t_bits: int) -> int:
+    """The ``reduce_every`` a range or MIN/MAX plan runs with: the
+    planner's choice from (c, base degree, t) where the plan leaves it at
+    0, else the plan's own value once the planner has checked it
+    (:func:`~.planner.choose_reduce_every`). A column not outsourced in
+    binary form keeps the plan's value; the round engine refuses it."""
+    if t_bits <= 0:
+        return plan.reduce_every
+    minmax = isinstance(plan, Aggregate)
+    match_degree = ((db.relation.degree + db.base_degree)
+                    * db.codec.word_length
+                    if minmax and plan.where is not None else None)
+    return _planner.choose_reduce_every(
+        db.n_shares, db.base_degree, t_bits, plan.reduce_every,
+        tournament=minmax, match_degree=match_degree)
+
+
 def _plan_signature(plan: Plan) -> tuple:
     """Structural cache key for one plan (Join rights key by identity —
     two different share sets are different plans even if equal-valued)."""
@@ -506,7 +523,8 @@ class QueryClient:
                 if col not in db.numeric_bits:   # as range_phase would
                     raise ValueError(f"column {col} was not outsourced in "
                                      f"binary form")
-                gk = (db.numeric_bits[col], plan.reduce_every)
+                t_bits = db.numeric_bits[col]
+                gk = (t_bits, _ripple_every(db, plan, t_bits))
                 want = isinstance(plan, RangeSelect)
                 range_grps.setdefault(gk, []).append(
                     (want, None, plan.padding.rows if want else None))
@@ -516,14 +534,16 @@ class QueryClient:
                     raise ValueError(f"column {col} was not outsourced in "
                                      f"binary form")
                 t_bits = db.numeric_bits[col]
+                every = (_ripple_every(db, plan, t_bits)
+                         if plan.op in ("min", "max") else 0)
                 est = _planner.estimate_aggregate_cost(
                     stats, plan.op, t_bits=t_bits,
                     conditional=plan.where is not None,
-                    verify=plan.verify, reduce_every=plan.reduce_every)
+                    verify=plan.verify, reduce_every=every)
                 # mirror run_batch grouping: SUM/AVG fuse per bit-width,
                 # MIN/MAX per (bit-width, reduce_every)
                 gk = (("agg_sum", t_bits) if plan.op in ("sum", "avg")
-                      else ("agg_minmax", t_bits, plan.reduce_every))
+                      else ("agg_minmax", t_bits, every))
                 agg_grps.setdefault(gk, []).append(est)
             elif isinstance(plan, EmbedLookup):
                 embed_ests.append(_planner.estimate_embed_cost(
@@ -626,7 +646,8 @@ class QueryClient:
 
         * Count/Select groups stack their shared predicates — each match,
           Q&A and address round is one fused dispatch + one interpolation.
-        * Range plans group by (bit-width, ``reduce_every``); the whole
+        * Range plans group by (bit-width, ``reduce_every`` as the planner
+          resolves it: :func:`~.planner.choose_reduce_every`); the whole
           group's SS-SUB bit-vectors ripple in ONE ``(c, 2B, n, t)`` carry
           chain — one ``ripple_carry`` dispatch per bit-round, one
           degree-reduction re-share per boundary for the batch.
@@ -749,8 +770,8 @@ class QueryClient:
                     join_group(slot, plan.strategy, plan.expected_matches)
                 elif isinstance(plan, (RangeCount, RangeSelect)):
                     slot.column = resolve_column(db, plan.where.column)
-                    gk = (db.numeric_bits.get(slot.column, -1),
-                          plan.reduce_every)
+                    t_bits = db.numeric_bits.get(slot.column, -1)
+                    gk = (t_bits, _ripple_every(db, plan, t_bits))
                     range_grps.setdefault(gk, []).append(slot)
                 elif isinstance(plan, Aggregate):
                     slot.column = resolve_column(db, plan.column)
@@ -761,8 +782,9 @@ class QueryClient:
                     if plan.op in ("sum", "avg"):
                         agg_sum_grps.setdefault(t_bits, []).append(slot)
                     else:
-                        agg_mm_grps.setdefault((t_bits, plan.reduce_every),
-                                               []).append(slot)
+                        agg_mm_grps.setdefault(
+                            (t_bits, _ripple_every(db, plan, t_bits)),
+                            []).append(slot)
                 elif isinstance(plan, EmbedLookup):
                     embed_grp.append(slot)
                 elif isinstance(plan, Join):

@@ -43,7 +43,8 @@ from ..core.costs import WORD_BITS
 from ..core.dataplane import ShardedRelation
 from ..core.encoding import PatternSpec
 from ..core.engine import SecretSharedDB
-from ..core.queries.rounds import match_phase_cost
+from ..core.queries.aggregate import sum_limbs
+from ..core.queries.rounds import match_phase_cost, ripple_schedule
 
 #: ℓ assumed when the plan carries no ``expected_matches`` hint. Two is the
 #: smallest multi-match cardinality: it keeps ``one_tuple`` out of the
@@ -273,6 +274,50 @@ def choose_match_method(stats: DBStats, method: str = "auto") -> str:
                key=lambda m: estimate_match_method_launches(stats, m))
 
 
+def choose_reduce_every(c: int, degree: int, t_bits: int,
+                        requested: int = 0, *, tournament: bool = False,
+                        match_degree: Optional[int] = None) -> int:
+    """``reduce_every`` for a range (or, with ``tournament``, a MIN/MAX)
+    ripple over a t-bit column shared at ``degree`` to ``c`` clouds.
+
+    Every sharing re-shared or opened must stay at degree ≤ c − 1 (no
+    fewer than degree + 1 points determine it): each segment's carry of
+    the round engine's :func:`~repro.core.queries.rounds.ripple_schedule`,
+    and the result bit, which the tournament's select x₁ + s·(x₂ − x₁)
+    lifts by ``degree`` before it is re-shared or opened.
+    ``requested = 0`` gets the widest segment that keeps them there: 0, no
+    reduction, wherever the whole width fits, so such a plan runs, and
+    costs, exactly as without the choice. An explicit ``requested`` is
+    kept if it fits and refused otherwise. ``match_degree`` is a
+    conditional tournament's match: its count opens at that degree and
+    the masked candidates re-share at that plus ``degree``, whatever the
+    segment. Raises ``ValueError`` naming the degree and c.
+    """
+    top = c - 1
+    mux = degree if tournament else 0
+    if match_degree is not None and match_degree + degree > top:
+        raise ValueError(
+            f"the MIN/MAX sentinel mask re-shares at degree "
+            f"{match_degree + degree}; {c} clouds hold at most degree {top}")
+
+    def worst(k: int) -> int:
+        degrees = [g for _, _, g in ripple_schedule(t_bits, k, degree)]
+        return max(degrees[:-1] + [degrees[-1] + mux])
+    if requested:
+        if worst(requested) > top:
+            raise ValueError(
+                f"reduce_every={requested} lets a {t_bits}-bit ripple reach "
+                f"degree {worst(requested)}; {c} clouds re-share or open at "
+                f"most degree {top}")
+        return requested
+    for k in range(t_bits, 0, -1):
+        if worst(k) <= top:
+            return 0 if k == t_bits else k
+    raise ValueError(
+        f"no reduce_every keeps a {t_bits}-bit ripple within {c} clouds: "
+        f"one bit a segment reaches degree {worst(1)}, above {top}")
+
+
 def estimate_range_cost(stats: DBStats, *, t_bits: int,
                         reduce_every: int = 0, want_addresses: bool = False,
                         ell: int = DEFAULT_ELL,
@@ -309,8 +354,9 @@ def estimate_aggregate_cost(stats: DBStats, op: str, *, t_bits: int,
                             reduce_every: int = 0) -> CostEstimate:
     """OBSCURE-style aggregation over a t-bit numeric column.
 
-    sum:     one contraction round — pattern up (conditional only), the
-             scalar sum share back from each cloud.
+    sum:     one contraction round — pattern up (conditional only), a
+             share of each limb's sum back from each cloud (one limb where
+             the field sum cannot wrap: ``aggregate.sum_limbs``).
     avg:     the sum plus (conditional only) the §3.1 count round for the
              denominator; an unconditional AVG divides by the public n.
     min/max: knockout tournament of ⌈log₂ n⌉ SS-SUB comparator levels —
@@ -326,7 +372,8 @@ def estimate_aggregate_cost(stats: DBStats, op: str, *, t_bits: int,
     s = stats
     S = max(1, min(s.shards, max(s.n, 1)))
     if op in ("sum", "avg"):
-        elems = s.c + (s.c * s.w * s.a if conditional else 0)
+        elems = (s.c * len(sum_limbs(s.n, t_bits))
+                 + (s.c * s.w * s.a if conditional else 0))
         rounds, dispatches = 1, S
         if op == "avg" and conditional:
             elems += _count_elems(s)

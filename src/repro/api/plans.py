@@ -203,6 +203,10 @@ class RangeCount(Plan):
 
     reduce_every > 0 inserts the paper's degree-reduction (re-sharing) round
     every that many SS-SUB bit positions, trading rounds for cloud count.
+    Left at 0, the planner picks it from (c, base degree, t): no reduction
+    where the whole width opens within the c clouds, else the widest
+    segment that does (``planner.choose_reduce_every``). An explicit value
+    that would re-share or open above degree c − 1 is refused at plan time.
     """
     where: Between
     reduce_every: int = 0
@@ -210,7 +214,8 @@ class RangeCount(Plan):
 
 @dataclasses.dataclass(frozen=True)
 class RangeSelect(Plan):
-    """Fetch all tuples with col in [lo, hi] (§3.4 + §3.2 fetch)."""
+    """Fetch all tuples with col in [lo, hi] (§3.4 + §3.2 fetch);
+    ``reduce_every`` as for :class:`RangeCount`."""
     where: Between
     reduce_every: int = 0
     padding: Padding = Padding.NONE
@@ -269,7 +274,8 @@ class Aggregate(Plan):
             response share is inconsistent. Needs c >= degree + 2 clouds;
             the extra round/bits are priced in ``explain()``.
     reduce_every: MIN/MAX only — insert a degree-reduction round every
-            this many comparator bit positions (same knob as range plans).
+            this many comparator bit positions (same knob as range plans,
+            chosen by the planner when left at 0).
     """
     op: str
     column: ColumnRef

@@ -385,7 +385,12 @@ class DispatchStats:
     ``user.share`` and ``user.open`` for the user's side of a batch.
     ``table_splits`` counts the shard tables split into resident int8
     digits (:meth:`ShardedRelation.table_digits`), ``presplit_contractions``
-    the shard contractions that read such digits.
+    the shard contractions that read such digits. ``reshares`` counts the
+    protocol's re-sharing rounds (``shamir.reduce_degree`` of a range or
+    tournament carry, a tournament's mask or winners, a window match), and
+    ``ripple_segments`` the SS-SUB ripple segments dispatched (one per
+    segment of a range group or of a tournament level, whatever the shard
+    count).
     """
     dispatches: int = 0             # shard dispatches executed
     steps: int = 0                  # cloud steps (DispatchSets) executed
@@ -393,6 +398,8 @@ class DispatchStats:
     transfer_bytes: int = 0         # staged bytes (see above)
     table_splits: int = 0           # resident digit sets made
     presplit_contractions: int = 0  # contractions over resident digits
+    reshares: int = 0               # re-sharing rounds (degree reduction)
+    ripple_segments: int = 0        # SS-SUB ripple segments dispatched
     span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def record(self, n_dispatches: int, transfer_bytes: int = 0,

@@ -26,8 +26,11 @@ round engine, batch-first like everything in :mod:`.rounds`:
     steps.
 
 Numeric-domain contracts (documented, not enforceable on shares):
-  * SUM/AVG open an exact field sum — the phase refuses relations where
-    ``n · 2^(t−1)`` could wrap the Mersenne-31 half-range.
+  * SUM/AVG open exact field sums. Where ``n · 2^(t−1)`` could pass the
+    Mersenne-31 half-range, the value's bit planes are summed in limbs
+    narrow enough that no limb's sum can (:func:`sum_limbs`), each limb
+    opened apart and the limbs added on the host as integers; a relation
+    past 2^29 tuples, where not even a one-bit limb is safe, is refused.
   * MIN/MAX comparisons subtract t-bit values; like the paper's SS-SUB,
     differences must fit in t bits. Conditional jobs additionally compare
     against the ±(2^(t−2) − 1) sentinel, so values should stay within
@@ -60,9 +63,9 @@ from .. import dataplane, encoding, field, shamir
 from ..costs import CostLedger
 from ..dataplane import RelationLike, span
 from ..shamir import Shares
-from .rounds import (MatchJob, _batched_matcher, _open_on_host,
-                     _ripple_segmenter, _segment_edges, _share_patterns,
-                     _stack_columns, _stack_numeric)
+from .rounds import (MatchJob, _batched_matcher, _open_on_host, _reshare,
+                     _ripple_segmenter, _share_patterns, ripple_schedule,
+                     _stack_columns, _stack_numeric, _within_clouds)
 
 AGG_OPS = ("sum", "avg", "min", "max")
 
@@ -121,13 +124,38 @@ class MinMaxJob(AggJob):
 # shared helpers
 # ---------------------------------------------------------------------------
 
-def _value_weights(t_bits: int) -> jax.Array:
-    """Public bit weights lifting an LSB-first two's-complement bit sharing
-    to a sharing of the (centered) field value: Σ 2^i·b_i with the sign bit
-    weighted −2^(t−1) mod p. A public linear combination — each cloud
-    applies it locally, degree unchanged."""
-    w = [1 << i for i in range(t_bits - 1)]
-    w.append(int(field.P) - (1 << (t_bits - 1)))
+def sum_limbs(n: int, t_bits: int) -> List[int]:
+    """Widths, LSB first, of the bit-plane limbs that a SUM over ``n``
+    tuples of a t-bit column opens apart. Each limb's field sum has to lift
+    back to the integer it stands for: the whole width where n·2^(t−1)
+    stays inside the Mersenne-31 half-range (one opening), else limbs of
+    the widest L with n·2^L ≤ 2^30, the top one carrying the sign bit.
+    Raises ``ValueError`` past 2^29 tuples, where no limb fits."""
+    if n << (t_bits - 1) < 1 << 30:
+        return [t_bits]
+    width = 30 - (n - 1).bit_length()
+    if width < 1:
+        raise ValueError(
+            f"SUM over n={n} tuples may exceed the Mersenne-31 half-range "
+            f"even one bit plane at a time — the field sum would no longer "
+            f"be exact")
+    full, rest = divmod(t_bits, width)
+    return [width] * full + ([rest] if rest else [])
+
+
+def _limb_weights(t_bits: int, limbs: Sequence[int]) -> jax.Array:
+    """Public (t, limbs) bit weights lifting an LSB-first two's-complement
+    bit sharing to a sharing of each limb's (centered) value: 2^(i−lo) for
+    bit i of the limb starting at bit lo, the sign bit weighted
+    −2^(t−1−lo) mod p. A public linear combination — each cloud applies it
+    locally, degree unchanged."""
+    w = np.zeros((t_bits, len(limbs)), np.int64)
+    lo = 0
+    for j, width in enumerate(limbs):
+        for i in range(lo, lo + width):
+            w[i, j] = 1 << (i - lo)
+        lo += width
+    w[t_bits - 1, -1] = int(field.P) - w[t_bits - 1, -1]
     return jnp.asarray(w, field.DTYPE)
 
 
@@ -198,11 +226,7 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
     c = db.n_shares
     n = db.n_tuples
     t_bits = _validate_numeric(db, jobs, "agg_sum_phase")
-    if n << (t_bits - 1) >= 1 << 30:
-        raise ValueError(
-            f"SUM over n={n} tuples of a {t_bits}-bit column may exceed "
-            f"the Mersenne-31 half-range — the field sum would no longer "
-            f"be exact")
+    limbs = sum_limbs(n, t_bits)
     cond = [i for i, j in enumerate(jobs) if j.conditional]
     free = [i for i, j in enumerate(jobs) if not j.conditional]
     with span(plane.stats, "user.share"):
@@ -210,7 +234,11 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
                  else None)
     w = db.relation.values.shape[-2]
     match_deg = ((db.relation.degree + p_all.degree) * w if cond else 0)
-    weights = _value_weights(t_bits)
+    weights = _limb_weights(t_bits, limbs)[None, None]     # (1, 1, t, m)
+
+    def limb_planes(v, vc):                                # (c, n_s, m)
+        return field.sum_(field.mul(v.numeric[vc].values[..., None],
+                                    weights), axis=2)
 
     # one ss_matmul per distinct value column of the conditional jobs
     by_vcol: dict = {}
@@ -226,24 +254,19 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
                 p_all.values)                              # (c, Bc, n_s)
             out: List[Optional[jax.Array]] = [None] * len(cond)
             for vc, ks in by_vcol.items():
-                col = field.sum_(field.mul(v.numeric[vc].values,
-                                           weights[None, None, :]),
-                                 axis=2)                   # (c, n_s)
                 prod = be.ss_matmul(bits[:, jnp.asarray(ks)],
-                                    col[:, :, None])       # (c, |ks|, 1)
+                                    limb_planes(v, vc))    # (c, |ks|, m)
                 for r, k in enumerate(ks):
-                    out[k] = prod[:, r, 0]
-            parts.append(jnp.stack(out, axis=1))           # (c, Bc)
+                    out[k] = prod[:, r]
+            parts.append(jnp.stack(out, axis=1))           # (c, Bc, m)
         if free:
-            cols = jnp.stack(
-                [field.sum_(field.mul(v.numeric[jobs[i].value_column].values,
-                                      weights[None, None, :]), axis=2)
-                 for i in free], axis=1)                   # (c, Bf, n_s)
-            parts.append(field.sum_(cols, axis=2))         # (c, Bf)
+            cols = jnp.stack([limb_planes(v, jobs[i].value_column)
+                              for i in free], axis=1)      # (c, Bf, n_s, m)
+            parts.append(field.sum_(cols, axis=2))         # (c, Bf, m)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts,
                                                                 axis=1)
 
-    sums_flat = plane.run_sum(one, phase="match")          # (c, Bc+Bf)
+    sums_flat = plane.run_sum(one, phase="match")          # (c, Bc+Bf, m)
     per_job: List[Optional[Shares]] = [None] * len(jobs)
     for k, i in enumerate(cond):
         per_job[i] = Shares(sums_flat[:, k],
@@ -255,6 +278,7 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
     opened = _open_on_host(plane.stats, per_job)
 
     per_q = codec.word_length * codec.alphabet_size
+    m = len(limbs)
     for i, j in enumerate(jobs):
         j.ledger.round()
         if j.conditional:
@@ -262,12 +286,15 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
             j.ledger.cloud(n * (per_q + t_bits))
         else:
             j.ledger.cloud(n * t_bits)
-        j.ledger.recv(c)
-        j.ledger.user(per_job[i].degree + 1)
+        j.ledger.recv(c * m)
+        j.ledger.user((per_job[i].degree + 1) * m)
     for i, j in enumerate(jobs):
         if j.verify:
             _verify_openings(j, [per_job[i]], "SUM")
-    return [_centered(int(opened[i])) for i in range(len(jobs))]
+    offsets = np.cumsum([0] + limbs[:-1])
+    return [sum(_centered(int(v)) << int(lo)
+                for v, lo in zip(np.ravel(opened[i]), offsets))
+            for i in range(len(jobs))]
 
 
 # ---------------------------------------------------------------------------
@@ -365,9 +392,8 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
         masked = field.add(field.mul(bits.values[..., None], delta),
                            jnp.broadcast_to(sent_b, x.values.shape))
         red_key, sub = jax.random.split(red_key)
-        with span(plane.stats, "cloud.reshare"):
-            masked = shamir.reduce_degree(
-                sub, Shares(masked, match_deg + x.degree), target_degree=d)
+        masked = _reshare(plane, sub, Shares(masked, match_deg + x.degree),
+                          d, "the MIN/MAX sentinel mask")
         for i, j in enumerate(cond_jobs):
             j.ledger.round()                 # the mask re-share round
             j.ledger.send(c * c)
@@ -398,14 +424,12 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
         carry = None
         carry_deg = 0
         s_bits = None
-        for seg_i, (s0, s1) in enumerate(_segment_edges(t_bits,
-                                                        reduce_every)):
-            if seg_i > 0 and carry_deg > 1:
+        for s0, s1, seg_deg in ripple_schedule(t_bits, reduce_every,
+                                               cand_deg):
+            if carry_deg:
                 red_key, sub = jax.random.split(red_key)
-                with span(plane.stats, "cloud.reshare"):
-                    carry = shamir.reduce_degree(
-                        sub, Shares(carry, carry_deg),
-                        target_degree=1).values
+                carry = _reshare(plane, sub, Shares(carry, carry_deg), 1,
+                                 "the comparator's carry").values
                 carry_deg = 1
                 for j in jobs:
                     j.ledger.round()
@@ -413,7 +437,8 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
             with span(plane.stats, "cloud.ripple"):
                 s_bits, carry = segment(lhs[..., s0:s1], rhs[..., s0:s1],
                                         carry)
-            carry_deg = carry_deg + 2 * cand_deg * (s1 - s0)
+            plane.stats.ripple_segments += 1
+            carry_deg = seg_deg
         win = _select_winner(x1, x2, s_bits)
         win_deg = carry_deg + cand_deg
         for j in jobs:
@@ -426,9 +451,8 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
             # the FINAL level opens at its native degree instead, so a
             # post-reduction tamper is visible to verification.
             red_key, sub = jax.random.split(red_key)
-            with span(plane.stats, "cloud.reshare"):
-                cand = shamir.reduce_degree(sub, Shares(win, win_deg),
-                                            target_degree=d).values
+            cand = _reshare(plane, sub, Shares(win, win_deg), d,
+                            "the tournament's winners").values
             cand_deg = d
             for j in jobs:
                 j.ledger.round()
@@ -437,6 +461,7 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
             cand = win
             cand_deg = win_deg
 
+    _within_clouds(cand_deg, c, "the MIN/MAX value")
     val_parts = [Shares(cand[:, i, 0], cand_deg) for i in range(b)]
     cnt_parts = {i: Shares(counts.values[:, kk], counts.degree)
                  for kk, i in enumerate(cond)}

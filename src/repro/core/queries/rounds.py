@@ -252,6 +252,27 @@ def _open_on_host(sink, parts: Sequence[Shares]) -> List[np.ndarray]:
         return [shamir.interpolate_host(s) for s in parts]
 
 
+def _within_clouds(degree: int, c: int, what: str) -> None:
+    """Refuse to re-share or open a sharing that c clouds cannot hold: a
+    degree-D polynomial needs D + 1 points."""
+    if degree > c - 1:
+        raise ValueError(f"{what} reaches degree {degree}; {c} clouds "
+                         f"re-share or open at most degree {c - 1}")
+
+
+def _reshare(plane, key: jax.Array, shares: Shares, target_degree: int,
+             what: str) -> Shares:
+    """The protocol's re-sharing round (``shamir.reduce_degree``), under
+    the span ``cloud.reshare`` and counted in the plane's
+    ``DispatchStats.reshares``; a sharing above degree c − 1 is refused
+    first."""
+    _within_clouds(shares.degree, shares.n_shares, what)
+    with span(plane.stats, "cloud.reshare"):
+        out = shamir.reduce_degree(key, shares, target_degree=target_degree)
+    plane.stats.reshares += 1
+    return out
+
+
 def _share_patterns(db: SecretSharedDB, jobs: Sequence[MatchJob]) -> Shares:
     """User step: encode + share every job's predicate -> (c, B, W|k, A).
 
@@ -460,10 +481,8 @@ class _MatcherPlan:
             if con_idx:
                 m = self.w - k + 1
                 red_key = jax.random.fold_in(self.jobs[con_idx[0]].key, 1)
-                with span(plane.stats, "cloud.reshare"):
-                    p_red = shamir.reduce_degree(
-                        red_key, Shares(cat(gi, 3), t2 * k),
-                        target_degree=1)
+                p_red = _reshare(plane, red_key, Shares(cat(gi, 3), t2 * k),
+                                 1, "the window match")
                 z = automata.zero_indicator(p_red.values, m)
                 result.append((con_idx, Shares(
                     field.sub(jnp.ones_like(z), z), m)))
@@ -969,12 +988,22 @@ def _tree_block_round(be, plane, p_all, columns, exact_slot, cached,
 # §3.4 — batched range predicates (Algorithms 5 & 6)
 # ---------------------------------------------------------------------------
 
-def _segment_edges(t_bits: int, reduce_every: int) -> List[Tuple[int, int]]:
-    """[start, end) bit segments between degree-reduction boundaries."""
-    if not reduce_every:
-        return [(0, t_bits)]
-    edges = list(range(0, t_bits, reduce_every)) + [t_bits]
-    return list(zip(edges[:-1], edges[1:]))
+def ripple_schedule(t_bits: int, reduce_every: int, degree: int
+                    ) -> List[Tuple[int, int, int]]:
+    """The SS-SUB ripple as :func:`range_phase` and the MIN/MAX tournament
+    run it: one ``(s0, s1, carry_degree)`` per [s0, s1) bit segment
+    between degree-reduction boundaries, ``carry_degree`` being the
+    carry's (and the result bit's) degree at the segment's end. A bit step
+    adds 2·degree; the re-share between segments brings the carry back to
+    degree 1. The planner prices and bounds the same schedule
+    (``planner.choose_reduce_every``)."""
+    edges = (list(range(0, t_bits, reduce_every)) if reduce_every
+             else [0]) + [t_bits]
+    out, carry = [], 0
+    for s0, s1 in zip(edges[:-1], edges[1:]):
+        carry = (1 if out else 0) + 2 * degree * (s1 - s0)
+        out.append((s0, s1, carry))
+    return out
 
 
 def range_phase(be, db: RelationLike, jobs: Sequence[RangeJob]) -> Shares:
@@ -1046,17 +1075,15 @@ def range_phase(be, db: RelationLike, jobs: Sequence[RangeJob]) -> Shares:
     carries: List[Optional[jax.Array]] = [None] * len(shards)
     rb_parts: List[jax.Array] = []
     carry_deg = 0
-    for seg_i, (s0, s1) in enumerate(_segment_edges(t_bits, reduce_every)):
-        if seg_i > 0 and carry_deg > 1:
+    for s0, s1, seg_deg in ripple_schedule(t_bits, reduce_every, d):
+        if carry_deg:
             # degree reduction = the explicit re-sharing round: reassemble
             # the carry across shards, reduce ONCE, re-slice per shard.
             carry_full = (carries[0] if len(shards) == 1
                           else jnp.concatenate(carries, axis=2))
             red_key, sub = jax.random.split(red_key)
-            with span(plane.stats, "cloud.reshare"):
-                carry_full = shamir.reduce_degree(
-                    sub, Shares(carry_full, carry_deg),
-                    target_degree=1).values
+            carry_full = _reshare(plane, sub, Shares(carry_full, carry_deg),
+                                  1, "the range ripple's carry").values
             carry_deg = 1
             carries = [carry_full[:, :, sh.lo:sh.hi] for sh in shards]
             for j in jobs:
@@ -1069,9 +1096,10 @@ def range_phase(be, db: RelationLike, jobs: Sequence[RangeJob]) -> Shares:
                 lhs_parts[sh.index][..., s0:s1],
                 rhs_parts[sh.index][..., s0:s1], carries[sh.index]),
             phase="ripple")
+        plane.stats.ripple_segments += 1
         rb_parts = [o[0] for o in outs]
         carries = [o[1] for o in outs]
-        carry_deg = carry_deg + 2 * d * (s1 - s0)
+        carry_deg = seg_deg
     for j in jobs:
         j.ledger.cloud(2 * n * t_bits)
 
@@ -1097,6 +1125,7 @@ def range_rounds(be, db: RelationLike, jobs: Sequence[RangeJob]
     plane = dataplane.as_dataplane(db)
     ind = range_phase(be, plane, jobs)
     c, n = db.n_shares, db.n_tuples
+    _within_clouds(ind.degree, c, "the range indicator")
     out: List[Union[int, List[int], None]] = [None] * len(jobs)
     cnt_idx = [i for i, j in enumerate(jobs) if not j.want_addresses]
     sel_idx = [i for i, j in enumerate(jobs) if j.want_addresses]

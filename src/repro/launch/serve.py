@@ -236,13 +236,15 @@ class RelationStats:
     """One relation's slice of the serving telemetry.
 
     ``dispatches`` / ``transfer_bytes`` / ``table_splits`` /
-    ``presplit_contractions`` / ``span_s`` mirror the relation dataplane's
+    ``presplit_contractions`` / ``reshares`` / ``ripple_segments`` /
+    ``span_s`` mirror the relation dataplane's
     :class:`~repro.core.dataplane.DispatchStats` deltas, accumulated per
     served batch — so the staged bytes (zero after placement for a
     device-resident dispatcher), the resident table digits made and read,
-    and the host seconds of each program span (``cloud.<phase>`` steps,
-    ``client.plan``, ``user.share``, ``user.open``) are visible to
-    monitoring code, not only dispatch counts.
+    the re-sharing rounds and ripple segments, and the host seconds of
+    each program span (``cloud.<phase>`` steps, ``client.plan``,
+    ``user.share``, ``user.open``) are visible to monitoring code, not
+    only dispatch counts.
 
     ``queue_depth`` and ``steered_wait_ms`` are *gauges* (last observed
     value, refreshed each served batch, not accumulated):
@@ -259,6 +261,8 @@ class RelationStats:
     transfer_bytes: int = 0
     table_splits: int = 0
     presplit_contractions: int = 0
+    reshares: int = 0
+    ripple_segments: int = 0
     span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     queue_depth: int = 0
     steered_wait_ms: float = 0.0
@@ -279,6 +283,8 @@ class RelationStats:
                     transfer_bytes=self.transfer_bytes,
                     table_splits=self.table_splits,
                     presplit_contractions=self.presplit_contractions,
+                    reshares=self.reshares,
+                    ripple_segments=self.ripple_segments,
                     span_s=dict(self.span_s),
                     queue_depth=self.queue_depth,
                     steered_wait_ms=self.steered_wait_ms,
@@ -321,6 +327,8 @@ class ServeStats:
     transfer_bytes: int = 0          # staged bytes (dataplane)
     table_splits: int = 0            # resident digit sets made (dataplane)
     presplit_contractions: int = 0   # contractions over them (dataplane)
+    reshares: int = 0                # re-sharing rounds (dataplane)
+    ripple_segments: int = 0         # SS-SUB ripple segments (dataplane)
     span_s: Dict[str, float] = dataclasses.field(
         default_factory=dict)       # program span name -> host seconds
     latencies_s: "Deque[float]" = dataclasses.field(default_factory=_window)
@@ -395,6 +403,7 @@ class ServeStats:
                      busy_s: float = 0.0, dispatches: int = 0,
                      transfer_bytes: int = 0, table_splits: int = 0,
                      presplit_contractions: int = 0,
+                     reshares: int = 0, ripple_segments: int = 0,
                      span_s: Optional[Dict[str, float]] = None,
                      queue_depth: Optional[int] = None,
                      steered_wait_ms: Optional[float] = None) -> None:
@@ -412,6 +421,8 @@ class ServeStats:
                 st.transfer_bytes += transfer_bytes
                 st.table_splits += table_splits
                 st.presplit_contractions += presplit_contractions
+                st.reshares += reshares
+                st.ripple_segments += ripple_segments
                 for name, seconds in (span_s or {}).items():
                     st.span_s[name] = st.span_s.get(name, 0.0) + seconds
             if relation is not None:
@@ -456,6 +467,8 @@ class ServeStats:
                         transfer_bytes=self.transfer_bytes,
                         table_splits=self.table_splits,
                         presplit_contractions=self.presplit_contractions,
+                        reshares=self.reshares,
+                        ripple_segments=self.ripple_segments,
                         span_s=dict(self.span_s),
                         p50_latency_s=_quantile(list(self.latencies_s),
                                                 0.50),
@@ -874,6 +887,9 @@ class QueryServer:
                     if d else 0,
                     presplit_contractions=(d.presplit_contractions
                                            - d0.presplit_contractions)
+                    if d else 0,
+                    reshares=(d.reshares - d0.reshares) if d else 0,
+                    ripple_segments=(d.ripple_segments - d0.ripple_segments)
                     if d else 0,
                     span_s=({k: v - d0.span_s.get(k, 0.0)
                              for k, v in d.span_s.items()} if d else None),

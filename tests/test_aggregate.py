@@ -297,13 +297,25 @@ def test_verify_needs_redundant_clouds():
 # ---------------------------------------------------------------------------
 
 def test_sum_phase_rejects_overflowable_relations():
-    """n·2^(t-1) beyond the Mersenne-31 half-range must refuse, not wrap."""
+    """A sum that could wrap the Mersenne-31 half-range must not wrap: it
+    opens in bit-plane limbs that cannot, and comes out exact (here the
+    single field sum would lift to a negative number); past 2^29 tuples, where not even a
+    one-bit limb is safe, the phase refuses."""
+    big = (1 << 28) - 1
+    values = [big] * 6 + [-big, 12345]
     db = outsource(jax.random.PRNGKey(4),
-                   [[f"i{k}", "1"] for k in range(8)],
-                   column_names=["Id", "V"], codec=CODEC, n_shares=20,
-                   degree=1, numeric_columns={1: 28})
+                   [[f"i{k}", str(v)] for k, v in enumerate(values)],
+                   column_names=["Id", "V"], codec=Codec(word_length=10),
+                   n_shares=4, degree=1, numeric_columns={1: 30})
+    assert sum(values) > (1 << 30)      # one field sum would read < 0
+    assert agg_mod.sum_limbs(8, 30) == [27, 3]
+    cl = QueryClient(db, key=1)
+    assert cl.run(Aggregate("sum", "V")).value == sum(values)
+    assert cl.run(Aggregate("avg", "V")).value == sum(values) / 8
+    assert agg_mod.sum_limbs(8192, 26) == [17, 9]
+    assert agg_mod.sum_limbs(8192, 8) == [8]
     with pytest.raises(ValueError, match="half-range"):
-        QueryClient(db, key=1).run(Aggregate("sum", "V"))
+        agg_mod.sum_limbs((1 << 29) + 1, 8)
 
 
 def test_mixed_bit_width_jobs_must_group():

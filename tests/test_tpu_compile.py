@@ -22,7 +22,9 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 import repro  # noqa: F401  (process-wide x64, as the program runs)
-from repro.core import field
+from repro.api import planner
+from repro.api.backends import jnp_ripple_segment
+from repro.core import field, shamir
 from repro.core.mesh_dispatch import MeshDispatcher
 from repro.kernels.aa_match import aa_match_batch_pallas, aa_slide_batch_pallas
 from repro.kernels.ripple import ripple_segment_pallas
@@ -33,6 +35,10 @@ from repro.launch.mesh import make_mesh
 N_TUPLES, W, A, M_COLS = 16_384, 12, 69, 4
 CLOUDS = (25, 27)
 TOKENS, VOCAB, D_MODEL, C_EMBED = 256, 262_144, 1_152, 4
+# the lineitem.range cell: a 14-bit range group of 8 requests (16 chains)
+# over 8,192 tuples at 27 clouds, in the planner's segments (13, then 1)
+RANGE_C, RANGE_S, RANGE_N, RANGE_T = 27, 16, 8_192, 14
+RANGE_EVERY = planner.choose_reduce_every(RANGE_C, 1, RANGE_T)
 FETCH_ROWS = 256
 U32 = jnp.uint32
 
@@ -119,10 +125,35 @@ def test_ripple_segment_compiles(one_chip, init):
                                                   interpret=False), a, a, c)
 
 
+@pytest.mark.parametrize("k, carry", [(RANGE_EVERY, False),
+                                      (RANGE_T - RANGE_EVERY, True)])
+def test_jnp_ripple_segment_compiles(one_chip, k, carry):
+    assert RANGE_EVERY == 13
+    bits = jax.ShapeDtypeStruct((RANGE_C, RANGE_S, RANGE_N, k), U32,
+                                sharding=one_chip)
+    if carry:
+        c_in = jax.ShapeDtypeStruct((RANGE_C, RANGE_S, RANGE_N), U32,
+                                    sharding=one_chip)
+        _compile(jnp_ripple_segment, bits, bits, c_in)
+    else:
+        _compile(lambda a, b: jnp_ripple_segment(a, b), bits, bits)
+
+
+def test_carry_reshare_compiles(one_chip):
+    # the carry after 13 bits is degree 26: all 27 clouds' shares re-share
+    key = jax.ShapeDtypeStruct((2,), U32, sharding=one_chip)
+    carry = jax.ShapeDtypeStruct((RANGE_C, RANGE_S, RANGE_N), U32,
+                                 sharding=one_chip)
+    _compile(lambda k, v: shamir._reshare(k, v, need=2 * RANGE_EVERY + 1,
+                                          target_degree=1), key, carry)
+
+
 @pytest.mark.parametrize("shape", [
     *(((c, FETCH_ROWS, N_TUPLES), (c, N_TUPLES, M_COLS * W * A))  # fetch
       for c in CLOUDS),
     ((C_EMBED, TOKENS, VOCAB), (C_EMBED, VOCAB, D_MODEL)),        # embed
+    # lineitem.range: 4 Q1 match bits against a 26-bit column's two limbs
+    ((RANGE_C, 4, RANGE_N), (RANGE_C, RANGE_N, 2)),
 ])
 def test_field_matmul_compiles(one_chip, shape):
     a = jax.ShapeDtypeStruct(shape[0], U32, sharding=one_chip)
