@@ -63,9 +63,10 @@ from .. import dataplane, encoding, field, shamir
 from ..costs import CostLedger
 from ..dataplane import RelationLike, span
 from ..shamir import Shares
-from .rounds import (MatchJob, _batched_matcher, _open_on_host, _reshare,
-                     _ripple_segmenter, _share_patterns, ripple_schedule,
-                     _stack_columns, _stack_numeric, _within_clouds)
+from .rounds import (MatchJob, _batched_matcher, _open_on_host,
+                     _reshare_next, _ripple_segmenter, _share_patterns,
+                     ripple_schedule, _stack_columns, _stack_numeric,
+                     _within_clouds)
 
 AGG_OPS = ("sum", "avg", "min", "max")
 
@@ -301,21 +302,31 @@ def agg_sum_phase(be, db: RelationLike, jobs: Sequence[SumJob]
 # MIN / MAX — sentinel mask + knockout tournament on the SS-SUB comparator
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("pairs",))
-def _pair_level(cand, is_min, pairs: int):
+@functools.partial(jax.jit, static_argnames=("bounds",))
+def _pair_level(cand, is_min, bounds):
     """One tournament level's fixed pairing (2i, 2i+1) of (c, B, k, t)
-    candidates. SS-SUB(lhs, rhs) opens [rhs < lhs]: min wants s = [x2 < x1]
+    candidates: ``(x1, x2, segments)``, ``segments`` one ``(lhs, rhs)``
+    pair of (c, B, pairs, s1 − s0) bit slices per segment ``bounds``.
+    SS-SUB(lhs, rhs) opens [rhs < lhs]: min wants s = [x2 < x1]
     (lhs=x1), max wants s = [x1 < x2] (lhs=x2); either way the winner is
     x1 + s·(x2 − x1)."""
+    pairs = cand.shape[2] // 2
     x1 = cand[:, :, 0:2 * pairs:2]                          # (c,B,pairs,t)
     x2 = cand[:, :, 1:2 * pairs:2]
-    return (x1, x2, jnp.where(is_min, x1, x2), jnp.where(is_min, x2, x1))
+    lhs, rhs = jnp.where(is_min, x1, x2), jnp.where(is_min, x2, x1)
+    return x1, x2, tuple((lhs[..., s0:s1], rhs[..., s0:s1])
+                         for s0, s1 in bounds)
 
 
 @jax.jit
-def _select_winner(x1, x2, s_bits):
-    """The oblivious select x₁ + s·(x₂ − x₁), share-wise."""
-    return field.add(x1, field.mul(s_bits[..., None], field.sub(x2, x1)))
+def _select_winners(x1, x2, s_bits, cand):
+    """The oblivious select x₁ + s·(x₂ − x₁), share-wise, with the odd
+    candidate of ``cand`` carried over unpaired."""
+    win = field.add(x1, field.mul(s_bits[..., None], field.sub(x2, x1)))
+    pairs = x1.shape[2]
+    if 2 * pairs < cand.shape[2]:
+        win = jnp.concatenate([win, cand[:, :, 2 * pairs:]], axis=2)
+    return win
 
 
 def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
@@ -391,9 +402,9 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
                                                      x.values.shape))
         masked = field.add(field.mul(bits.values[..., None], delta),
                            jnp.broadcast_to(sent_b, x.values.shape))
-        red_key, sub = jax.random.split(red_key)
-        masked = _reshare(plane, sub, Shares(masked, match_deg + x.degree),
-                          d, "the MIN/MAX sentinel mask")
+        masked, red_key = _reshare_next(
+            plane, red_key, Shares(masked, match_deg + x.degree), d,
+            "the MIN/MAX sentinel mask")
         for i, j in enumerate(cond_jobs):
             j.ledger.round()                 # the mask re-share round
             j.ledger.send(c * c)
@@ -407,59 +418,57 @@ def agg_minmax_rounds(be, db: RelationLike, jobs: Sequence[MinMaxJob]
         [masked_by_pos[i] if i in masked_by_pos
          else db.numeric[jobs[i].value_column].values
          for i in range(b)], axis=1)                        # (c, B, n, t)
-    cand_deg = d
 
     # knockout tournament: global fixed pairing (2i, 2i+1) per level, odd
     # leftover carried unpaired; each level is one batched SS-SUB ripple
     # (sign s = [loser-side < winner-side]) plus the oblivious select
     # x₁ + s·(x₂ − x₁). Levels run on the gathered relation, like tree
-    # Q&A rounds — identical transcript for every shard count.
+    # Q&A rounds — identical transcript for every shard count. A level is
+    # its pairing, each segment, each re-share with its key split, and the
+    # select: one program each. Every level enters at degree d, so all
+    # share one schedule.
     segment = _ripple_segmenter(be)
     is_min = jnp.asarray([j.op == "min" for j in jobs],
                          bool)[None, :, None, None]
+    schedule = ripple_schedule(t_bits, reduce_every, d)
+    bounds = tuple((s0, s1) for s0, s1, _ in schedule)
+    win_deg = schedule[-1][2] + d
+    cand_deg = d
     k = n
     while k > 1:
         pairs = k // 2
-        x1, x2, lhs, rhs = _pair_level(cand, is_min, pairs)
-        carry = None
-        carry_deg = 0
-        s_bits = None
-        for s0, s1, seg_deg in ripple_schedule(t_bits, reduce_every,
-                                               cand_deg):
+        x1, x2, segments = _pair_level(cand, is_min, bounds)
+        carry, carry_deg = None, 0
+        for (lhs, rhs), (_, _, seg_deg) in zip(segments, schedule):
             if carry_deg:
-                red_key, sub = jax.random.split(red_key)
-                carry = _reshare(plane, sub, Shares(carry, carry_deg), 1,
-                                 "the comparator's carry").values
-                carry_deg = 1
+                shares, red_key = _reshare_next(
+                    plane, red_key, Shares(carry, carry_deg), 1,
+                    "the comparator's carry")
+                carry = shares.values
                 for j in jobs:
                     j.ledger.round()
                     j.ledger.send(c * c)
             with span(plane.stats, "cloud.ripple"):
-                s_bits, carry = segment(lhs[..., s0:s1], rhs[..., s0:s1],
-                                        carry)
+                s_bits, carry = segment(lhs, rhs, carry)
             plane.stats.ripple_segments += 1
             carry_deg = seg_deg
-        win = _select_winner(x1, x2, s_bits)
-        win_deg = carry_deg + cand_deg
+        cand = _select_winners(x1, x2, s_bits, cand)
         for j in jobs:
             j.ledger.cloud(2 * pairs * t_bits)
-        if 2 * pairs < k:
-            win = jnp.concatenate([win, cand[:, :, 2 * pairs:]], axis=2)
-        k = win.shape[2]
-        if k > 1:
-            # inter-level re-share back to the base degree (one round);
-            # the FINAL level opens at its native degree instead, so a
+        k = cand.shape[2]
+        if k == 1:
+            # the FINAL level opens at its native degree, so a
             # post-reduction tamper is visible to verification.
-            red_key, sub = jax.random.split(red_key)
-            cand = _reshare(plane, sub, Shares(win, win_deg), d,
-                            "the tournament's winners").values
-            cand_deg = d
-            for j in jobs:
-                j.ledger.round()
-                j.ledger.send(c * c)
-        else:
-            cand = win
             cand_deg = win_deg
+            break
+        # inter-level re-share back to the base degree (one round)
+        shares, red_key = _reshare_next(
+            plane, red_key, Shares(cand, win_deg), d,
+            "the tournament's winners")
+        cand = shares.values
+        for j in jobs:
+            j.ledger.round()
+            j.ledger.send(c * c)
 
     _within_clouds(cand_deg, c, "the MIN/MAX value")
     val_parts = [Shares(cand[:, i, 0], cand_deg) for i in range(b)]

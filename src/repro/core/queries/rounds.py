@@ -62,6 +62,7 @@ bit-identical for every shard count; S is purely an execution knob.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -260,17 +261,46 @@ def _within_clouds(degree: int, c: int, what: str) -> None:
                          f"re-share or open at most degree {c - 1}")
 
 
+@contextlib.contextmanager
+def _resharing(plane, degree: int, c: int, what: str):
+    """The host's side of one re-sharing round: a sharing above degree
+    c − 1 is refused first, the round's dispatch runs under the span
+    ``cloud.reshare``, and it counts in the plane's
+    ``DispatchStats.reshares``."""
+    _within_clouds(degree, c, what)
+    with span(plane.stats, "cloud.reshare"):
+        yield
+    plane.stats.reshares += 1
+
+
 def _reshare(plane, key: jax.Array, shares: Shares, target_degree: int,
              what: str) -> Shares:
-    """The protocol's re-sharing round (``shamir.reduce_degree``), under
-    the span ``cloud.reshare`` and counted in the plane's
-    ``DispatchStats.reshares``; a sharing above degree c − 1 is refused
-    first."""
-    _within_clouds(shares.degree, shares.n_shares, what)
-    with span(plane.stats, "cloud.reshare"):
-        out = shamir.reduce_degree(key, shares, target_degree=target_degree)
-    plane.stats.reshares += 1
-    return out
+    """The protocol's re-sharing round (``shamir.reduce_degree``)."""
+    with _resharing(plane, shares.degree, shares.n_shares, what):
+        return shamir.reduce_degree(key, shares, target_degree=target_degree)
+
+
+@functools.partial(jax.jit, static_argnames=("degree", "target_degree"))
+def _split_reshare(key: jax.Array, values: jax.Array, *, degree: int,
+                   target_degree: int) -> Tuple[jax.Array, jax.Array]:
+    """``key, sub = jax.random.split(key)`` and the re-share of a
+    degree-``degree`` sharing with ``sub``, in one program; returns
+    ``(values, key)``."""
+    key, sub = jax.random.split(key)
+    return shamir.reduce_degree(sub, Shares(values, degree),
+                                target_degree=target_degree).values, key
+
+
+def _reshare_next(plane, key: jax.Array, shares: Shares, target_degree: int,
+                  what: str) -> Tuple[Shares, jax.Array]:
+    """:func:`_reshare` with the key of a reduction chain: the chain's key
+    splits, the round takes the second half, and the first comes back as
+    the chain's next key, all in the round's one dispatch."""
+    with _resharing(plane, shares.degree, shares.n_shares, what):
+        values, key = _split_reshare(key, shares.values,
+                                     degree=shares.degree,
+                                     target_degree=target_degree)
+    return Shares(values, target_degree), key
 
 
 def _share_patterns(db: SecretSharedDB, jobs: Sequence[MatchJob]) -> Shares:

@@ -12,16 +12,31 @@ Anchor properties:
   * unknown plan classes fail with a clear ``PlanNotSupported``.
 """
 import dataclasses
+import functools
+from typing import List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import (Aggregate, Count, Eq, Plan, PlanNotSupported,
-                       QueryClient, Select, VerificationError, get_backend,
-                       ripple_segmenter)
-from repro.core import Codec, outsource
+from repro.api import (Aggregate, Count, Eq, MapReduceExecutor, Plan,
+                       PlanNotSupported, QueryClient, Select,
+                       VerificationError, get_backend, ripple_segmenter)
+from repro.core import Codec, dataplane, encoding, field, outsource
+from repro.core.costs import CostLedger
+from repro.core.dataplane import ShardedRelation, span
 from repro.core.queries import aggregate as agg_mod
+from repro.core.queries import rounds
+from repro.core.queries.aggregate import _validate_numeric, _verify_openings
+from repro.core.queries.rounds import (MatchJob, _batched_matcher,
+                                       _open_on_host, _reshare,
+                                       _ripple_segmenter, _share_patterns,
+                                       _stack_columns, _stack_numeric,
+                                       _within_clouds, ripple_schedule)
+from repro.core.shamir import Shares
+from repro.launch.serve import QueryServer
+from repro.runtime import MapReduceRunner, WorkerPool
 
 CODEC = Codec(word_length=6)
 
@@ -362,3 +377,334 @@ def test_distinct_value_columns_fuse_in_one_batch():
     seq = [QueryClient(db, key=3).run(p) for p in plans]
     for a, b in zip(seq, res):
         assert a.value == b.value and a.ledger == b.ledger
+
+
+# ---------------------------------------------------------------------------
+# fused tournament programs: bit-identical to the host loop they replaced
+# ---------------------------------------------------------------------------
+# The reference below is agg_minmax_rounds as it ran when every slice, key
+# split and re-share of a level was its own host dispatch, kept verbatim
+# apart from its name. Its helpers come from the modules the program
+# imports.
+
+@functools.partial(jax.jit, static_argnames=("pairs",))
+def _pair_level(cand, is_min, pairs: int):
+    """One tournament level's fixed pairing (2i, 2i+1) of (c, B, k, t)
+    candidates. SS-SUB(lhs, rhs) opens [rhs < lhs]: min wants s = [x2 < x1]
+    (lhs=x1), max wants s = [x1 < x2] (lhs=x2); either way the winner is
+    x1 + s·(x2 − x1)."""
+    x1 = cand[:, :, 0:2 * pairs:2]                          # (c,B,pairs,t)
+    x2 = cand[:, :, 1:2 * pairs:2]
+    return (x1, x2, jnp.where(is_min, x1, x2), jnp.where(is_min, x2, x1))
+
+
+@jax.jit
+def _select_winner(x1, x2, s_bits):
+    """The oblivious select x₁ + s·(x₂ − x₁), share-wise."""
+    return field.add(x1, field.mul(s_bits[..., None], field.sub(x2, x1)))
+
+
+def _loop_minmax_rounds(be, db, jobs):
+    """MIN/MAX for B jobs, every tournament level fused across the batch.
+
+    Returns ``(value, count)`` per job: ``count`` is the opened predicate
+    cardinality for conditional jobs (None otherwise); ``value`` is None
+    when a conditional job matched nothing (the tournament would open the
+    sentinel). The final level's winner opens at its native comparator
+    degree — no trailing re-share — so a share tampered anywhere after the
+    last reduction fails verification.
+    """
+    if not jobs:
+        return []
+    plane = dataplane.as_dataplane(db)
+    db = plane.db
+    codec = db.codec
+    c = db.n_shares
+    n = db.n_tuples
+    d = db.base_degree
+    t_bits = _validate_numeric(db, jobs, "agg_minmax_rounds")
+    if t_bits < 2:
+        raise ValueError("MIN/MAX needs a >= 2-bit value column")
+    if len({j.reduce_every for j in jobs}) != 1:
+        raise ValueError("a fused agg_minmax_rounds needs uniform "
+                         "reduce_every across its jobs (group them)")
+    reduce_every = jobs[0].reduce_every
+    b = len(jobs)
+    w = codec.word_length
+    per_q = w * codec.alphabet_size
+    cond = [i for i, j in enumerate(jobs) if j.conditional]
+
+    # round 1: predicates travel up; the final bits come back in the same
+    # logical round once the tournament's re-share rounds are done.
+    for j in jobs:
+        j.ledger.round()
+        if j.conditional:
+            j.ledger.send(c * per_q)
+
+    # every job's key splits (pattern, reduction-chain); the fused
+    # reduction chain seeds from the first job, as in range_phase —
+    # re-share randomness never changes opened values.
+    split_keys = [jax.random.split(j.key) for j in jobs]
+    red_key = split_keys[0][1]
+
+    counts: Optional[Shares] = None
+    masked_by_pos: dict = {}
+    if cond:
+        cond_jobs = [jobs[i] for i in cond]
+        with span(plane.stats, "user.share"):
+            p_all = _share_patterns(db, [
+                MatchJob(j.pred_column, j.pattern, split_keys[i][0],
+                         j.ledger) for i, j in zip(cond, cond_jobs)])
+        match_deg = (db.relation.degree + p_all.degree) * w
+        bits = Shares(plane.run_concat(
+            lambda v, sh: _batched_matcher(be)(
+                _stack_columns(v, [j.pred_column
+                                   for j in cond_jobs]).values,
+                p_all.values), axis=2, phase="match"),
+            match_deg)                                      # (c, Bc, n)
+        counts = Shares(field.sum_(bits.values, axis=2), match_deg)
+        # sentinel mask: non-matching rows become the op's losing extreme
+        # (a public constant, so masking is cloud-local share arithmetic):
+        # masked = m·(x − s) + s.
+        bound = (1 << (t_bits - 2)) - 1
+        sent = np.stack([encoding.encode_number_bits(
+            bound if j.op == "min" else -bound, t_bits)
+            for j in cond_jobs])                            # (Bc, t)
+        sent_b = jnp.asarray(sent, field.DTYPE)[None, :, None, :]
+        x = _stack_numeric(db, [j.value_column for j in cond_jobs])
+        delta = field.sub(x.values, jnp.broadcast_to(sent_b,
+                                                     x.values.shape))
+        masked = field.add(field.mul(bits.values[..., None], delta),
+                           jnp.broadcast_to(sent_b, x.values.shape))
+        red_key, sub = jax.random.split(red_key)
+        masked = _reshare(plane, sub, Shares(masked, match_deg + x.degree),
+                          d, "the MIN/MAX sentinel mask")
+        for i, j in enumerate(cond_jobs):
+            j.ledger.round()                 # the mask re-share round
+            j.ledger.send(c * c)
+            j.ledger.cloud(n * (per_q + t_bits))
+            masked_by_pos[cond[i]] = masked.values[:, i]
+    for i, j in enumerate(jobs):
+        if not j.conditional:
+            j.ledger.cloud(n * t_bits)
+
+    cand = jnp.stack(
+        [masked_by_pos[i] if i in masked_by_pos
+         else db.numeric[jobs[i].value_column].values
+         for i in range(b)], axis=1)                        # (c, B, n, t)
+    cand_deg = d
+
+    # knockout tournament: global fixed pairing (2i, 2i+1) per level, odd
+    # leftover carried unpaired; each level is one batched SS-SUB ripple
+    # (sign s = [loser-side < winner-side]) plus the oblivious select
+    # x₁ + s·(x₂ − x₁). Levels run on the gathered relation, like tree
+    # Q&A rounds — identical transcript for every shard count.
+    segment = _ripple_segmenter(be)
+    is_min = jnp.asarray([j.op == "min" for j in jobs],
+                         bool)[None, :, None, None]
+    k = n
+    while k > 1:
+        pairs = k // 2
+        x1, x2, lhs, rhs = _pair_level(cand, is_min, pairs)
+        carry = None
+        carry_deg = 0
+        s_bits = None
+        for s0, s1, seg_deg in ripple_schedule(t_bits, reduce_every,
+                                               cand_deg):
+            if carry_deg:
+                red_key, sub = jax.random.split(red_key)
+                carry = _reshare(plane, sub, Shares(carry, carry_deg), 1,
+                                 "the comparator's carry").values
+                carry_deg = 1
+                for j in jobs:
+                    j.ledger.round()
+                    j.ledger.send(c * c)
+            with span(plane.stats, "cloud.ripple"):
+                s_bits, carry = segment(lhs[..., s0:s1], rhs[..., s0:s1],
+                                        carry)
+            plane.stats.ripple_segments += 1
+            carry_deg = seg_deg
+        win = _select_winner(x1, x2, s_bits)
+        win_deg = carry_deg + cand_deg
+        for j in jobs:
+            j.ledger.cloud(2 * pairs * t_bits)
+        if 2 * pairs < k:
+            win = jnp.concatenate([win, cand[:, :, 2 * pairs:]], axis=2)
+        k = win.shape[2]
+        if k > 1:
+            # inter-level re-share back to the base degree (one round);
+            # the FINAL level opens at its native degree instead, so a
+            # post-reduction tamper is visible to verification.
+            red_key, sub = jax.random.split(red_key)
+            cand = _reshare(plane, sub, Shares(win, win_deg), d,
+                            "the tournament's winners").values
+            cand_deg = d
+            for j in jobs:
+                j.ledger.round()
+                j.ledger.send(c * c)
+        else:
+            cand = win
+            cand_deg = win_deg
+
+    _within_clouds(cand_deg, c, "the MIN/MAX value")
+    val_parts = [Shares(cand[:, i, 0], cand_deg) for i in range(b)]
+    cnt_parts = {i: Shares(counts.values[:, kk], counts.degree)
+                 for kk, i in enumerate(cond)}
+    opened = _open_on_host(plane.stats,
+                           val_parts + [cnt_parts[i] for i in cond])
+
+    for i, j in enumerate(jobs):
+        j.ledger.recv(c * t_bits)
+        j.ledger.user((cand_deg + 1) * t_bits)
+        if j.conditional:
+            j.ledger.recv(c)
+            j.ledger.user(counts.degree + 1)
+    for i, j in enumerate(jobs):
+        if j.verify:
+            tensors = [val_parts[i]]
+            if j.conditional:
+                tensors.append(cnt_parts[i])
+            _verify_openings(j, tensors, j.op.upper())
+
+    out: List[Tuple[Optional[int], Optional[int]]] = []
+    cnt_at = {i: b + kk for kk, i in enumerate(cond)}
+    for i, j in enumerate(jobs):
+        val = encoding.decode_number_bits(np.asarray(opened[i]))
+        if j.conditional:
+            cnt = int(opened[cnt_at[i]])
+            out.append((val if cnt > 0 else None, cnt))
+        else:
+            out.append((val, None))
+    return out
+
+
+def _minmax_db(n):
+    """n rows of signed 8-bit values, three names for the predicates."""
+    rows = [[f"id{i}", f"nm{i % 3}", str((i * 37) % 121 - 60)]
+            for i in range(n)]
+    return rows, outsource(jax.random.PRNGKey(n), rows,
+                           column_names=["Id", "Name", "Val"], codec=CODEC,
+                           n_shares=20, degree=1, numeric_columns={2: 8})
+
+
+@pytest.fixture(scope="module")
+def minmax_dbs():
+    """48 rows leave an odd candidate over (48, 24, 12, 6, 3, 2, 1); 64
+    pair evenly; both take 6 levels."""
+    return {n: _minmax_db(n) for n in (48, 64)}
+
+
+def _minmax_jobs(op, conditional, b, reduce_every):
+    other = "max" if op == "min" else "min"
+    return [agg_mod.MinMaxJob(
+        value_column=2, key=jax.random.PRNGKey(100 + i), ledger=CostLedger(),
+        pred_column=1 if conditional else None,
+        pattern=f"nm{i + 1}" if conditional else None,
+        op=op if i == 0 else other, reduce_every=reduce_every)
+        for i in range(b)]
+
+
+@pytest.mark.parametrize("n", [48, 64])
+@pytest.mark.parametrize("reduce_every", [0, 2])
+@pytest.mark.parametrize("b", [1, 2])
+@pytest.mark.parametrize("conditional", [False, True])
+@pytest.mark.parametrize("op", ["min", "max"])
+def test_fused_tournament_matches_host_loop(minmax_dbs, monkeypatch, op,
+                                            conditional, b, reduce_every, n):
+    """The fused pairing, key splits and select open the same shares, at
+    the same degree, with the same ledgers and re-share and segment counts
+    as the host loop: one ripple segment a level and segment."""
+    rows, db = minmax_dbs[n]
+    opened = {}
+
+    def recording(name):
+        def record(sink, parts):
+            opened[name] = [(p.degree, np.asarray(p.values)) for p in parts]
+            return rounds._open_on_host(sink, parts)
+        return record
+
+    monkeypatch.setattr(agg_mod, "_open_on_host", recording("program"))
+    monkeypatch.setitem(globals(), "_open_on_host", recording("loop"))
+    be = get_backend("jnp")
+    runs = {}
+    for name, phase in (("program", agg_mod.agg_minmax_rounds),
+                        ("loop", _loop_minmax_rounds)):
+        plane = ShardedRelation(db, shards=1)
+        jobs = _minmax_jobs(op, conditional, b, reduce_every)
+        runs[name] = (phase(be, plane, jobs), [j.ledger for j in jobs],
+                      plane.stats)
+    (got, got_led, got_st), (want, want_led, want_st) = (runs["program"],
+                                                         runs["loop"])
+    assert got == want and got_led == want_led
+    assert len(opened["program"]) == len(opened["loop"])
+    for (dg, vg), (dw, vw) in zip(opened["program"], opened["loop"]):
+        assert dg == dw
+        np.testing.assert_array_equal(vg, vw)
+    assert (got_st.reshares, got_st.ripple_segments) == (want_st.reshares,
+                                                         want_st.ripple_segments)
+    assert got_st.ripple_segments == 6 * len(ripple_schedule(8,
+                                                             reduce_every, 1))
+    vals, names = _oracle(rows)
+    for i, (value, count) in enumerate(got):
+        mask = names == f"nm{i + 1}" if conditional else np.ones(n, bool)
+        pick = min if (op if i == 0 else {"min": "max",
+                                          "max": "min"}[op]) == "min" else max
+        assert value == pick(vals[mask])
+        assert count == (int(mask.sum()) if conditional else None)
+
+
+def test_second_identical_max_compiles_nothing(minmax_dbs):
+    """Each tournament program is traced once per shape: the same MAX
+    again compiles no program."""
+    _, db = minmax_dbs[48]
+    client = QueryClient(db, key=5)
+    plane = client.attach(shards=1)
+    plan = Aggregate("max", "Val", reduce_every=2)
+    first = client.run(plan)
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        second = client.run(plan)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+    assert second.value == first.value
+    assert plane.stats.ripple_segments == 2 * 6 * 4    # 8 bits, 2 a segment
+
+
+def test_server_snapshot_counts_tournament_segments(minmax_dbs):
+    """The levels run on the gathered relation, the same segments whatever
+    the shard count, and the server folds their count into its
+    snapshot."""
+    rows, db = minmax_dbs[64]
+    vals, _ = _oracle(rows)
+    server = QueryServer(db, key=10, shards=2)
+    reqs = [server.submit(Aggregate("max", "Val")) for _ in range(2)]
+    while server.pending():
+        server.pump()
+    assert [r.result.value for r in reqs] == [int(vals.max())] * 2
+    snap = server.stats.snapshot()
+    assert snap["batches"] >= 1
+    assert snap["ripple_segments"] == 6 * snap["batches"]    # one segment
+    assert server.dataplane.stats.ripple_segments == 6 * snap["batches"]
+
+
+def test_mapreduce_executor_runs_the_tournament(minmax_dbs):
+    """A backend whose segment fans out to host workers runs each segment
+    between the fused programs, with equal results."""
+    _, db = minmax_dbs[48]
+    runner = MapReduceRunner(WorkerPool(2), lease_s=5.0, max_attempts=30)
+    plans = [Aggregate("max", "Val", reduce_every=2),
+             Aggregate("min", "Val", where=Eq("Name", "nm1"),
+                       reduce_every=2)]
+    ref = QueryClient(db, key=21).run_batch(plans)
+    got = QueryClient(db, key=21,
+                      executor=MapReduceExecutor(runner, n_splits=3)
+                      ).run_batch(plans)
+    for a, b in zip(ref, got):
+        assert (a.value, a.count, a.ledger) == (b.value, b.count, b.ledger)

@@ -26,6 +26,8 @@ from repro.api import planner
 from repro.api.backends import jnp_ripple_segment
 from repro.core import field, shamir
 from repro.core.mesh_dispatch import MeshDispatcher
+from repro.core.queries.aggregate import _pair_level, _select_winners
+from repro.core.queries.rounds import _split_reshare, ripple_schedule
 from repro.kernels.aa_match import aa_match_batch_pallas, aa_slide_batch_pallas
 from repro.kernels.ripple import ripple_segment_pallas
 from repro.kernels.ss_matmul import (share_onehot_pallas, ss_matmul_pallas,
@@ -39,6 +41,10 @@ TOKENS, VOCAB, D_MODEL, C_EMBED = 256, 262_144, 1_152, 4
 # over 8,192 tuples at 27 clouds, in the planner's segments (13, then 1)
 RANGE_C, RANGE_S, RANGE_N, RANGE_T = 27, 16, 8_192, 14
 RANGE_EVERY = planner.choose_reduce_every(RANGE_C, 1, RANGE_T)
+# its MAX(l_extendedprice) tournament: two 26-bit candidates a batch over
+# 8,192 tuples, in the planner's segments (12, 12, 2)
+MAX_B, MAX_T = 2, 26
+MAX_EVERY = planner.choose_reduce_every(RANGE_C, 1, MAX_T)
 FETCH_ROWS = 256
 U32 = jnp.uint32
 
@@ -146,6 +152,35 @@ def test_carry_reshare_compiles(one_chip):
                                  sharding=one_chip)
     _compile(lambda k, v: shamir._reshare(k, v, need=2 * RANGE_EVERY + 1,
                                           target_degree=1), key, carry)
+
+
+@pytest.mark.parametrize("k", [RANGE_N, 2])
+def test_tournament_level_compiles(one_chip, k):
+    # the widest level (4,096 pairs; the carry re-shares from degrees 24
+    # and 25 with its key split, the winners from 6) and the final one (one
+    # pair, no winners' re-share); no program loops
+    schedule = ripple_schedule(MAX_T, MAX_EVERY, 1)
+    assert [s1 - s0 for s0, s1, _ in schedule] == [12, 12, 2]
+    assert [deg for _, _, deg in schedule] == [24, 25, 5]
+    cand = jax.ShapeDtypeStruct((RANGE_C, MAX_B, k, MAX_T), U32,
+                                sharding=one_chip)
+    is_min = jax.ShapeDtypeStruct((1, MAX_B, 1, 1), jnp.bool_,
+                                  sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), U32, sharding=one_chip)
+    carry = jax.ShapeDtypeStruct((RANGE_C, MAX_B, k // 2), U32,
+                                 sharding=one_chip)
+    bounds = tuple((s0, s1) for s0, s1, _ in schedule)
+    texts = [_compile(lambda x, m: _pair_level(x, m, bounds), cand, is_min)]
+    texts += [_compile(lambda r, v, deg=deg: _split_reshare(
+        r, v, degree=deg, target_degree=1), key, carry)
+        for _, _, deg in schedule[:-1]]
+    pair = jax.ShapeDtypeStruct((RANGE_C, MAX_B, k // 2, MAX_T), U32,
+                                sharding=one_chip)
+    texts.append(_compile(_select_winners, pair, pair, carry, cand))
+    if k > 2:
+        texts.append(_compile(lambda r, v: _split_reshare(
+            r, v, degree=schedule[-1][2] + 1, target_degree=1), key, pair))
+    assert not any(" while(" in t for t in texts)
 
 
 @pytest.mark.parametrize("shape", [
