@@ -279,10 +279,9 @@ def bench_batched_vs_sequential(*, batch_sizes: Sequence[int] = (8, 32),
             _sweep_plans(f"batched_select_{strategy}", db, plans,
                          n=n, b=b, out=out)
     for b in batch_sizes:
-        plans = [RangeCount(Between("Salary", 500 + 100 * i, 5000),
-                            reduce_every=2) if i % 2 == 0
-                 else RangeSelect(Between("Salary", 600, 900 + 50 * i),
-                                  reduce_every=2)
+        plans = [RangeCount(Between("Salary", 500 + 100 * i, 5000))
+                 if i % 2 == 0
+                 else RangeSelect(Between("Salary", 600, 900 + 50 * i))
                  for i in range(b)]
         _sweep_plans("batched_range", db, plans, n=n, b=b, out=out)
     child = [[rows[i % n][0], f"t{i}"] for i in range(min(n, 16))]
@@ -317,8 +316,8 @@ def bench_sharded_dataplane(*, n: int = 128, batch: int = 8,
                          n_shares=20, degree=1)
     plans = ([Select(Eq("FirstName", patterns[i % len(patterns)]),
                      strategy="one_round") for i in range(batch - 3)]
-             + [RangeCount(Between("Salary", 500, 4000), reduce_every=2),
-                RangeSelect(Between("Salary", 600, 1500), reduce_every=2),
+             + [RangeCount(Between("Salary", 500, 4000)),
+                RangeSelect(Between("Salary", 600, 1500)),
                 Join(right=db_child, on=("EmployeeId", "EmployeeId"),
                      kind="pkfk")])
 
@@ -374,8 +373,7 @@ def bench_multi_tenant_serving(*, n: int = 64, queries: int = 6
     pats_b = sorted({r[4] for r in rows_b})
     plans_a = [Select(Eq("FirstName", pats_a[i % len(pats_a)]),
                       strategy="one_round") for i in range(queries - 1)]
-    plans_a.append(RangeCount(Between("Salary", 500, 4000),
-                              reduce_every=2))
+    plans_a.append(RangeCount(Between("Salary", 500, 4000)))
     plans_b = [Count(Eq("Department", pats_b[i % len(pats_b)]))
                for i in range(queries)]
 
@@ -583,9 +581,9 @@ def bench_aggregation(*, n: int = 64) -> List[dict]:
                                    where=Eq("FirstName", "John")),
          statistics.mean(johns)),
         ("agg_min_cond", Aggregate("min", "Salary",
-                                   where=Eq("FirstName", "John"),
-                                   reduce_every=2), min(johns)),
-        ("agg_max", Aggregate("max", "Salary", reduce_every=2), max(sal)),
+                                   where=Eq("FirstName", "John")),
+         min(johns)),
+        ("agg_max", Aggregate("max", "Salary"), max(sal)),
     ]
     out: List[dict] = []
     plans = [p for _, p, _ in specs]
@@ -599,8 +597,7 @@ def bench_aggregation(*, n: int = 64) -> List[dict]:
         got = b.value
         assert (abs(got - want) < 1e-9), (name, got, want)
         ver = QueryClient(db, key=41).run(
-            Aggregate(plan.op, plan.column, where=plan.where, verify=True,
-                      reduce_every=plan.reduce_every))
+            Aggregate(plan.op, plan.column, where=plan.where, verify=True))
         assert ver.value == a.value    # verification never moves the value
         out.append(dict(
             name=name, n=n, batch=len(plans),
@@ -637,8 +634,7 @@ def bench_mesh_dispatcher(*, n: int = 64, shards: int = 4) -> List[dict]:
         ("mesh_count", Count(Eq("FirstName", patterns[0]))),
         ("mesh_select", Select(Eq("FirstName", patterns[1 % len(patterns)]),
                                strategy="one_round")),
-        ("mesh_range", RangeCount(Between("Salary", 500, 4000),
-                                  reduce_every=2)),
+        ("mesh_range", RangeCount(Between("Salary", 500, 4000))),
         ("mesh_join", Join(right=db_child, on=("EmployeeId", "EmployeeId"),
                            kind="pkfk")),
         ("mesh_aggregate", Aggregate("sum", "Salary",

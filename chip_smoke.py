@@ -121,9 +121,9 @@ def relation_plans(rows, right_pk, right_eq):
          lambda r: r.count == sum(w.startswith("ka") for w in words)),
         ("like_substring", Count(Like("Word", "%omth%")),
          lambda r: r.count == sum("omth" in w for w in words)),
-        ("range_count", RangeCount(Between("Val", lo, hi), reduce_every=2),
+        ("range_count", RangeCount(Between("Val", lo, hi)),
          lambda r: r.count == int(((vals >= lo) & (vals <= hi)).sum())),
-        ("range_select", RangeSelect(Between("Val", lo, hi), reduce_every=2),
+        ("range_select", RangeSelect(Between("Val", lo, hi)),
          select_check((vals >= lo) & (vals <= hi))),
         ("join_pkfk", Join(right=right_pk[1], on=("Id", "Id"), kind="pkfk"),
          lambda r: r.rows == pk_want),
@@ -135,7 +135,7 @@ def relation_plans(rows, right_pk, right_eq):
          lambda r: r.value == int(vals[in_b].sum())),
         ("avg", Aggregate("avg", "Val", where=Eq("Name", name_b)),
          lambda r: abs(r.value - float(vals[in_b].mean())) < 1e-9),
-        ("min", Aggregate("min", "Val", reduce_every=2),
+        ("min", Aggregate("min", "Val"),
          lambda r: r.value == int(vals.min())),
     ]
 

@@ -85,10 +85,10 @@ def main():
           f"[rounds={res.ledger.rounds}]\n")
 
     print("== RANGE (§3.4): Salary in [1000, 2000] ==")
-    # 14-bit SS-SUB grows the polynomial degree past our 20 clouds ->
-    # apply the paper's degree-reduction (re-sharing) every 2 bits
-    cnt = client.range_count("Salary", 1000, 2000, reduce_every=2)
-    sel = client.range_select("Salary", 1000, 2000, reduce_every=2)
+    # 14-bit SS-SUB grows the polynomial degree past our 20 clouds -> the
+    # planner inserts the paper's degree-reduction (re-sharing) round
+    cnt = client.range_count("Salary", 1000, 2000)
+    sel = client.range_select("Salary", 1000, 2000)
     print(f"  -> count {cnt.count}; rows {[r[0] for r in sel.rows]}\n")
 
     print("== AGGREGATE: verified AVG(Salary) WHERE FirstName='John' ==")
@@ -106,7 +106,7 @@ def main():
     res = client.run(plan)
     print(f"  -> AVG = {res.value} over {res.count} matching rows, "
           f"verified  [rounds={res.ledger.rounds}]")
-    lo = client.run(Aggregate("min", "Salary", reduce_every=2))
+    lo = client.run(Aggregate("min", "Salary"))
     print(f"  -> MIN(Salary) = {lo.value} via the ripple-comparator "
           f"tournament\n")
 

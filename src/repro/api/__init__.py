@@ -16,8 +16,8 @@ paper-optimal selection strategy; backends are looked up in a registry
 The legacy free functions in ``repro.core.queries`` remain as thin
 deprecated wrappers; new code should go through this package.
 """
-from ..core.dataplane import (Dispatcher, PoolHandle, ShardedRelation,
-                              ThreadedDispatcher)
+from ..core.dataplane import (Dispatcher, MapReduceDispatcher, PoolHandle,
+                              ShardedRelation, ThreadedDispatcher)
 from ..core.encoding import PatternSpec, parse_like
 from ..core.mesh_dispatch import MeshDispatcher
 from ..core.queries import VerificationError
@@ -25,7 +25,6 @@ from .backends import (Backend, available_backends, batched_match_matrix,
                        batched_matcher, get_backend, register_backend,
                        ripple_segmenter, ripple_stepper, slide_matcher)
 from .client import DEFAULT_RELATION, AttachedRelation, QueryClient
-from .executor import MapReduceDispatcher, MapReduceExecutor
 from .planner import (DEFAULT_ELL, BatchExplanation, CostEstimate, DBStats,
                       GroupEstimate, PlanNotSupported, candidate_estimates,
                       candidate_pattern_estimates, choose_match_method,
@@ -46,7 +45,7 @@ __all__ = [
     "batched_match_matrix", "get_backend", "register_backend",
     "ripple_segmenter", "ripple_stepper", "slide_matcher", "QueryClient",
     "DEFAULT_RELATION", "AttachedRelation",
-    "MapReduceDispatcher", "MapReduceExecutor", "MeshDispatcher",
+    "MapReduceDispatcher", "MeshDispatcher",
     "Dispatcher", "PoolHandle", "ShardedRelation", "ThreadedDispatcher",
     "DEFAULT_ELL", "BatchExplanation", "CostEstimate", "DBStats",
     "GroupEstimate", "PlanNotSupported", "candidate_estimates",

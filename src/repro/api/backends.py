@@ -1,8 +1,7 @@
 """Backend registry: named implementations of the cloud-side hot ops.
 
-Replaces the ad-hoc ``impl="jnp"|"pallas"`` strings that used to be threaded
-through every query function. A :class:`Backend` bundles the share-space
-hotspots every query is built from:
+A :class:`Backend` bundles the share-space hotspots every query is built
+from:
 
   * ``aa_match``       — accumulating-automata word match (§3.1, Table 3),
   * ``ss_matmul``      — share-space mod-p matmul (the oblivious-fetch and
@@ -47,11 +46,9 @@ All operate on *raw* uint32 share arrays (cloud axis first where batched);
 polynomial-degree bookkeeping stays at the query layer. Queries resolve a
 backend by name via :func:`get_backend`; ``repro.api.QueryClient`` exposes
 the choice as a constructor argument. Third parties can plug in alternatives
-(a GPU kernel set, a distributed runner) with :func:`register_backend` — see
-``repro.api.executor.MapReduceExecutor`` for a wrapping backend that fans
-the map phase (including the fused batch) out over MapReduce splits. A
-backend that omits ``aa_match_batch`` still works: :func:`batched_matcher`
-falls back to ``vmap`` over its ``aa_match`` when that is traceable.
+(a GPU kernel set) with :func:`register_backend`. A backend that omits
+``aa_match_batch`` still works: :func:`batched_matcher` falls back to
+``vmap`` over its ``aa_match``.
 """
 from __future__ import annotations
 
@@ -98,8 +95,7 @@ def batched_matcher(backend: Backend) -> _Op:
     """The backend's batched AA match, or a vmap fallback over ``aa_match``.
 
     The fallback covers third-party backends whose ``aa_match`` is a
-    traceable jax function; backends built from host-side callables (e.g.
-    the MapReduce executor wrapper) must provide ``aa_match_batch``.
+    traceable jax function.
     """
     if backend.aa_match_batch is not None:
         return backend.aa_match_batch
@@ -143,9 +139,8 @@ def ripple_segmenter(backend: Backend) -> _RippleOp:
 def batched_match_matrix(backend: Backend) -> _Op:
     """The backend's stacked all-pairs matcher, or a vmap fallback.
 
-    As with :func:`batched_matcher`, backends built from host-side
-    callables (the MapReduce executor wrapper) must provide the batched op
-    themselves; any traceable ``match_matrix`` gets the vmap for free.
+    As with :func:`batched_matcher`, any traceable ``match_matrix`` gets
+    the vmap for free.
     """
     if backend.match_matrix_batch is not None:
         return backend.match_matrix_batch
@@ -169,8 +164,8 @@ def aggregate_match_matrix(backend: Backend) -> _Op:
     flattened (W·A) ``ss_matmul`` gives P = #matching positions per pair;
     the Lagrange equality indicator ``1[P==W]`` is a share-local
     elementwise chain. Same secrets and same final degree as the chain
-    matcher — 1 dot-set instead of W — so the planner may pick either
-    per join group (``Join.match_method``).
+    matcher — 1 dot-set instead of W — so the planner picks either per
+    join group by launch count (``planner.choose_match_method``).
     """
     def run(bx: Array, by: Array) -> Array:
         from ..core import automata

@@ -2,8 +2,8 @@
 
 One object replaces the nine free query functions: it owns the root PRNG key
 (per-query keys derive via ``jax.random.fold_in``, no manual threading), the
-backend choice (``repro.api.backends`` registry), the optional MapReduce
-executor, and the cost-based selection planner (``repro.api.planner``).
+backend choice (``repro.api.backends`` registry) and the cost-based
+selection planner (``repro.api.planner``).
 Every query family returns the same :class:`~.plans.QueryResult`.
 
 The client fronts a *registry* of attached relations, matching the paper's
@@ -20,10 +20,10 @@ relation "orders" opens bit-identical rows and ledgers whether or not
 Every plan family executes through the round-structured batch engine
 (``repro.core.queries.rounds``): :meth:`QueryClient.run_batch` cost-plans
 each query, groups compatible strategies — Count/Select by selection
-algorithm, ranges by (bit-width, ``reduce_every``), joins by kind — stacks
-their shared predicates and executes each protocol round *once for the
-whole group*: one fused device dispatch + one interpolation per match or
-Q&A round, one ``ripple_carry`` dispatch per SS-SUB bit-round, and ONE
+algorithm, ranges by bit-width, joins by kind — stacks their shared
+predicates and executes each protocol round *once for the whole group*:
+one fused device dispatch + one interpolation per match or Q&A round, one
+``ripple_segment`` dispatch per SS-SUB segment, and ONE
 cross-group ``ss_matmul`` for every oblivious fetch (one_round, tree and
 range one-hot matrices *and* PK/FK match matrices stack row-wise).
 :meth:`QueryClient.run` is the B = 1 case of the same machinery, so
@@ -48,7 +48,6 @@ from ..core.queries import CardinalityError, aggregate, rounds
 from ..core.queries import embed as embed_q
 from . import planner as _planner
 from .backends import BackendLike, get_backend
-from .executor import MapReduceExecutor
 from .plans import (AUTO, Aggregate, Between, ColumnRef, Contains, Count,
                     EmbedLookup, Eq, Join, Like, Padding, Plan, Prefix,
                     QueryResult, RangeCount, RangeSelect, Select, Suffix,
@@ -133,19 +132,19 @@ def _lower_match(db: SecretSharedDB, where, context: str
 
 def _ripple_every(db: SecretSharedDB, plan: Plan, t_bits: int) -> int:
     """The ``reduce_every`` a range or MIN/MAX plan runs with: the
-    planner's choice from (c, base degree, t) where the plan leaves it at
-    0, else the plan's own value once the planner has checked it
-    (:func:`~.planner.choose_reduce_every`). A column not outsourced in
-    binary form keeps the plan's value; the round engine refuses it."""
+    planner's choice from (c, base degree, t)
+    (:func:`~.planner.choose_reduce_every`), which refuses a plan no
+    segment fits. A column not outsourced in binary form gets 0; the round
+    engine refuses it."""
     if t_bits <= 0:
-        return plan.reduce_every
+        return 0
     minmax = isinstance(plan, Aggregate)
     match_degree = ((db.relation.degree + db.base_degree)
                     * db.codec.word_length
                     if minmax and plan.where is not None else None)
     return _planner.choose_reduce_every(
-        db.n_shares, db.base_degree, t_bits, plan.reduce_every,
-        tournament=minmax, match_degree=match_degree)
+        db.n_shares, db.base_degree, t_bits, tournament=minmax,
+        match_degree=match_degree)
 
 
 def _plan_signature(plan: Plan) -> tuple:
@@ -214,8 +213,6 @@ class QueryClient:
                      stream (seeded from this root unless ``attach`` is
                      given an explicit ``key=``).
     backend:         registered backend name or Backend instance.
-    executor:        optional :class:`MapReduceExecutor` — fans every
-                     cloud-side map phase out over fault-tolerant splits.
     round_cost_bits: planner latency weight — how many communication bits
                      one extra protocol round is worth to this user.
     """
@@ -223,7 +220,6 @@ class QueryClient:
     def __init__(self, db: Union[SecretSharedDB, ShardedRelation,
                                  None] = None, key=0, *,
                  backend: BackendLike = "jnp",
-                 executor: Optional[MapReduceExecutor] = None,
                  round_cost_bits: int = 0):
         if isinstance(key, int):
             key = jax.random.PRNGKey(key)
@@ -237,9 +233,6 @@ class QueryClient:
                 DEFAULT_RELATION, plane.db if plane is not None else db,
                 plane, key, itertools.count())
         self.backend = get_backend(backend)
-        if executor is not None:
-            self.backend = executor.wrap(self.backend)
-        self.executor = executor
         self.round_cost_bits = round_cost_bits
 
     # -- registry -----------------------------------------------------------
@@ -310,8 +303,8 @@ class QueryClient:
         Every cloud step of every subsequent query against this relation
         fans out as one dispatch per tuple-axis shard, executed by
         ``dispatcher`` (serial by default; pass a ``ThreadedDispatcher`` —
-        or a shared pool's ``handle()`` — for concurrent shards, or
-        ``MapReduceExecutor.dispatcher()`` for fault-tolerant placement).
+        or a shared pool's ``handle()`` — for concurrent shards, or a
+        ``MapReduceDispatcher`` for fault-tolerant placement).
         Sharding is pure execution policy: rows, opened values and ledgers
         stay bit-identical to the unsharded relation, and the planner
         prices the per-shard dispatch counts through ``stats().shards``.
@@ -476,8 +469,8 @@ class QueryClient:
         group_sizes: Dict[str, int] = {s: 0 for s in sel_ells}
         group_rounds: Dict[str, int] = {}
         count_ests: List[_planner.CostEstimate] = []
-        range_grps: Dict[Tuple[int, int], List[Tuple[bool, Optional[int],
-                                                     Optional[int]]]] = {}
+        range_grps: Dict[int, Tuple[int, List[Tuple[
+            bool, Optional[int], Optional[int]]]]] = {}
         joins: Dict[str, List[Plan]] = {"pkfk": [], "equi": []}
         agg_grps: Dict[tuple, List[_planner.CostEstimate]] = {}
         embed_ests: List[_planner.CostEstimate] = []
@@ -524,9 +517,9 @@ class QueryClient:
                     raise ValueError(f"column {col} was not outsourced in "
                                      f"binary form")
                 t_bits = db.numeric_bits[col]
-                gk = (t_bits, _ripple_every(db, plan, t_bits))
+                every = _ripple_every(db, plan, t_bits)
                 want = isinstance(plan, RangeSelect)
-                range_grps.setdefault(gk, []).append(
+                range_grps.setdefault(t_bits, (every, []))[1].append(
                     (want, None, plan.padding.rows if want else None))
             elif isinstance(plan, Aggregate):
                 col = resolve_column(db, plan.column)
@@ -540,10 +533,10 @@ class QueryClient:
                     stats, plan.op, t_bits=t_bits,
                     conditional=plan.where is not None,
                     verify=plan.verify, reduce_every=every)
-                # mirror run_batch grouping: SUM/AVG fuse per bit-width,
-                # MIN/MAX per (bit-width, reduce_every)
-                gk = (("agg_sum", t_bits) if plan.op in ("sum", "avg")
-                      else ("agg_minmax", t_bits, every))
+                # mirror run_batch grouping: SUM/AVG and MIN/MAX each fuse
+                # per bit-width
+                gk = (("agg_sum" if plan.op in ("sum", "avg")
+                       else "agg_minmax"), t_bits)
                 agg_grps.setdefault(gk, []).append(est)
             elif isinstance(plan, EmbedLookup):
                 embed_ests.append(_planner.estimate_embed_cost(
@@ -579,9 +572,9 @@ class QueryClient:
                         stats, strategy, ells=ells,
                         padded_rows=sel_pad[strategy],
                         specs=sel_specs[strategy])))
-        for (t_bits, reduce_every), members in range_grps.items():
+        for t_bits, (every, members) in range_grps.items():
             ests = [_planner.estimate_range_cost(
-                stats, t_bits=t_bits, reduce_every=reduce_every,
+                stats, t_bits=t_bits, reduce_every=every,
                 want_addresses=want,
                 ell=_planner.DEFAULT_ELL if ell is None else max(ell, 1),
                 padded_rows=pad) for (want, ell, pad) in members]
@@ -646,10 +639,10 @@ class QueryClient:
 
         * Count/Select groups stack their shared predicates — each match,
           Q&A and address round is one fused dispatch + one interpolation.
-        * Range plans group by (bit-width, ``reduce_every`` as the planner
-          resolves it: :func:`~.planner.choose_reduce_every`); the whole
+        * Range plans group by bit-width, which fixes where the planner
+          re-shares (:func:`~.planner.choose_reduce_every`); the whole
           group's SS-SUB bit-vectors ripple in ONE ``(c, 2B, n, t)`` carry
-          chain — one ``ripple_carry`` dispatch per bit-round, one
+          chain — one ``ripple_segment`` dispatch per segment, one
           degree-reduction re-share per boundary for the batch.
         * Every oblivious fetch in the batch — one_round, tree and range
           one-hot matrices plus PK/FK join match matrices (a zero-match
@@ -662,7 +655,7 @@ class QueryClient:
         * Aggregates fuse per family: SUM/AVG numerators share ONE masked
           contraction per bit-width (conditional AVG denominators ride the
           batch's count phase), MIN/MAX tournaments march in lockstep per
-          (bit-width, ``reduce_every``) group.
+          bit-width.
 
         Results come back in plan order; each query's rows and
         ``CostLedger`` are bit-identical to running it sequentially (ledgers
@@ -712,9 +705,10 @@ class QueryClient:
             count_grp: List[_Slot] = []
             sel_grp: Dict[str, List[_Slot]] = {
                 "one_tuple": [], "one_round": [], "tree": []}
-            range_grps: Dict[Tuple[int, int], List[_Slot]] = {}
+            # ranges and MIN/MAX: bit-width -> (planner's k, slots)
+            range_grps: Dict[int, Tuple[int, List[_Slot]]] = {}
             agg_sum_grps: Dict[int, List[_Slot]] = {}
-            agg_mm_grps: Dict[Tuple[int, int], List[_Slot]] = {}
+            agg_mm_grps: Dict[int, Tuple[int, List[_Slot]]] = {}
             embed_grp: List[_Slot] = []
             pkfk_grp: List[_Slot] = []
             equi_grp: List[_Slot] = []
@@ -771,8 +765,8 @@ class QueryClient:
                 elif isinstance(plan, (RangeCount, RangeSelect)):
                     slot.column = resolve_column(db, plan.where.column)
                     t_bits = db.numeric_bits.get(slot.column, -1)
-                    gk = (t_bits, _ripple_every(db, plan, t_bits))
-                    range_grps.setdefault(gk, []).append(slot)
+                    every = _ripple_every(db, plan, t_bits)
+                    range_grps.setdefault(t_bits, (every, []))[1].append(slot)
                 elif isinstance(plan, Aggregate):
                     slot.column = resolve_column(db, plan.column)
                     if plan.where is not None:
@@ -782,9 +776,9 @@ class QueryClient:
                     if plan.op in ("sum", "avg"):
                         agg_sum_grps.setdefault(t_bits, []).append(slot)
                     else:
-                        agg_mm_grps.setdefault(
-                            (t_bits, _ripple_every(db, plan, t_bits)),
-                            []).append(slot)
+                        every = _ripple_every(db, plan, t_bits)
+                        agg_mm_grps.setdefault(t_bits,
+                                               (every, []))[1].append(slot)
                 elif isinstance(plan, EmbedLookup):
                     embed_grp.append(slot)
                 elif isinstance(plan, Join):
@@ -855,8 +849,8 @@ class QueryClient:
                                              strategy="embed",
                                              embeddings=emb)
 
-        # -- aggregation: SUM/AVG numerators fuse per bit-width, MIN/MAX
-        # tournaments per (bit-width, reduce_every) ------------------------
+        # -- aggregation: SUM/AVG numerators and MIN/MAX tournaments fuse
+        # per bit-width ---------------------------------------------------
         for group in agg_sum_grps.values():
             sums = aggregate.agg_sum_phase(be, rel, [
                 aggregate.SumJob(
@@ -881,7 +875,7 @@ class QueryClient:
                         plan=s.plan, ledger=s.ledger, strategy="agg_avg",
                         value=(total / db.n_tuples if db.n_tuples
                                else None))
-        for (_, reduce_every), group in agg_mm_grps.items():
+        for every, group in agg_mm_grps.values():
             outs = aggregate.agg_minmax_rounds(be, rel, [
                 aggregate.MinMaxJob(
                     value_column=s.column, key=s.key, ledger=s.ledger,
@@ -889,7 +883,7 @@ class QueryClient:
                     pattern=(s.plan.where.pattern if s.plan.where is not None
                              else None),
                     verify=s.plan.verify, op=s.plan.op,
-                    reduce_every=reduce_every) for s in group])
+                    reduce_every=every) for s in group])
             for s, (val, cnt) in zip(group, outs):
                 results[s.idx] = QueryResult(
                     plan=s.plan, ledger=s.ledger,
@@ -971,8 +965,8 @@ class QueryClient:
                                                       s.plan.padding.rows))
                     fetch_meta.append((s, "tree", a))
 
-        # -- ranges: one fused ripple per (bit-width, reduce_every) group ---
-        for (_, reduce_every), group in range_grps.items():
+        # -- ranges: one fused ripple per bit-width -------------------------
+        for every, group in range_grps.values():
             jobs = []
             for s in group:
                 if isinstance(s.plan, RangeSelect):
@@ -981,7 +975,7 @@ class QueryClient:
                     k_ind = s.key
                 jobs.append(rounds.RangeJob(
                     s.column, s.plan.where.lo, s.plan.where.hi, k_ind,
-                    s.ledger, reduce_every=reduce_every,
+                    s.ledger, reduce_every=every,
                     want_addresses=isinstance(s.plan, RangeSelect)))
             for s, out in zip(group, rounds.range_rounds(be, rel, jobs)):
                 if isinstance(s.plan, RangeCount):
@@ -1000,8 +994,7 @@ class QueryClient:
             join_jobs = [rounds.JoinJob(
                 s.plan.right, resolve_column(db, s.plan.on[0]),
                 resolve_column(s.plan.right, s.plan.on[1]), s.key, s.ledger,
-                match_method=_planner.choose_match_method(
-                    stats, s.plan.match_method))
+                match_method=_planner.choose_match_method(stats))
                 for s in pkfk_grp]
             join_entries = rounds.join_match_round(be, rel, join_jobs)
 
@@ -1085,26 +1078,20 @@ class QueryClient:
                                padding=padding), relation=relation)
 
     def range_count(self, column: ColumnRef, lo: int, hi: int, *,
-                    reduce_every: int = 0,
                     relation: Optional[str] = None) -> QueryResult:
-        return self.run(RangeCount(Between(column, lo, hi),
-                                   reduce_every=reduce_every),
+        return self.run(RangeCount(Between(column, lo, hi)),
                         relation=relation)
 
     def range_select(self, column: ColumnRef, lo: int, hi: int, *,
-                     reduce_every: int = 0,
                      padding: Padding = Padding.NONE,
                      relation: Optional[str] = None) -> QueryResult:
         return self.run(RangeSelect(Between(column, lo, hi),
-                                    reduce_every=reduce_every,
                                     padding=padding), relation=relation)
 
     def aggregate(self, op: str, column: ColumnRef, *,
                   where: Optional[Eq] = None, verify: bool = False,
-                  reduce_every: int = 0,
                   relation: Optional[str] = None) -> QueryResult:
-        return self.run(Aggregate(op, column, where=where, verify=verify,
-                                  reduce_every=reduce_every),
+        return self.run(Aggregate(op, column, where=where, verify=verify),
                         relation=relation)
 
     def join(self, right: SecretSharedDB,

@@ -260,22 +260,19 @@ def estimate_match_method_launches(stats: DBStats, method: str) -> int:
                          f"('chain', 'aggregate')") from None
 
 
-def choose_match_method(stats: DBStats, method: str = "auto") -> str:
-    """Resolve a ``Join.match_method`` — the §3.1.2 chain-vs-aggregate
-    execution knob. Both methods open the same match matrix at the same
-    degree (2tW) with identical ledgers, so bits and rounds never
-    discriminate; the planner prices the remaining axis — backend launch
-    count — and AUTO takes the cheaper evaluation (``aggregate`` whenever
-    the word is longer than its two fixed launches, i.e. any real W)."""
-    if method != "auto":
-        estimate_match_method_launches(stats, method)   # validate
-        return method
+def choose_match_method(stats: DBStats) -> str:
+    """How a PK/FK join evaluates its match matrix: the §3.1.2 chain or
+    its aggregate form. Both open the same match matrix at the same degree
+    (2tW) with identical ledgers, so bits and rounds never discriminate;
+    the planner prices the remaining axis — backend launch count — and
+    takes the cheaper evaluation (``aggregate`` whenever the word is longer
+    than its two fixed launches, i.e. W > 2)."""
     return min(("chain", "aggregate"),
                key=lambda m: estimate_match_method_launches(stats, m))
 
 
-def choose_reduce_every(c: int, degree: int, t_bits: int,
-                        requested: int = 0, *, tournament: bool = False,
+def choose_reduce_every(c: int, degree: int, t_bits: int, *,
+                        tournament: bool = False,
                         match_degree: Optional[int] = None) -> int:
     """``reduce_every`` for a range (or, with ``tournament``, a MIN/MAX)
     ripple over a t-bit column shared at ``degree`` to ``c`` clouds.
@@ -284,14 +281,12 @@ def choose_reduce_every(c: int, degree: int, t_bits: int,
     fewer than degree + 1 points determine it): each segment's carry of
     the round engine's :func:`~repro.core.queries.rounds.ripple_schedule`,
     and the result bit, which the tournament's select x₁ + s·(x₂ − x₁)
-    lifts by ``degree`` before it is re-shared or opened.
-    ``requested = 0`` gets the widest segment that keeps them there: 0, no
-    reduction, wherever the whole width fits, so such a plan runs, and
-    costs, exactly as without the choice. An explicit ``requested`` is
-    kept if it fits and refused otherwise. ``match_degree`` is a
-    conditional tournament's match: its count opens at that degree and
-    the masked candidates re-share at that plus ``degree``, whatever the
-    segment. Raises ``ValueError`` naming the degree and c.
+    lifts by ``degree`` before it is re-shared or opened. The choice is
+    the widest segment that keeps them there: 0, no reduction, wherever
+    the whole width fits. ``match_degree`` is a conditional tournament's
+    match: its count opens at that degree and the masked candidates
+    re-share at that plus ``degree``, whatever the segment. Raises
+    ``ValueError`` naming the degree and c where no segment fits.
     """
     top = c - 1
     mux = degree if tournament else 0
@@ -303,13 +298,6 @@ def choose_reduce_every(c: int, degree: int, t_bits: int,
     def worst(k: int) -> int:
         degrees = [g for _, _, g in ripple_schedule(t_bits, k, degree)]
         return max(degrees[:-1] + [degrees[-1] + mux])
-    if requested:
-        if worst(requested) > top:
-            raise ValueError(
-                f"reduce_every={requested} lets a {t_bits}-bit ripple reach "
-                f"degree {worst(requested)}; {c} clouds re-share or open at "
-                f"most degree {top}")
-        return requested
     for k in range(t_bits, 0, -1):
         if worst(k) <= top:
             return 0 if k == t_bits else k

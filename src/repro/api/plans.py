@@ -201,23 +201,20 @@ class Select(Plan):
 class RangeCount(Plan):
     """COUNT(*) WHERE lo <= col <= hi (§3.4, Algorithm 5).
 
-    reduce_every > 0 inserts the paper's degree-reduction (re-sharing) round
-    every that many SS-SUB bit positions, trading rounds for cloud count.
-    Left at 0, the planner picks it from (c, base degree, t): no reduction
-    where the whole width opens within the c clouds, else the widest
-    segment that does (``planner.choose_reduce_every``). An explicit value
-    that would re-share or open above degree c − 1 is refused at plan time.
+    The planner decides where the ripple re-shares its carry (the paper's
+    degree-reduction round): nowhere where the whole width opens within
+    the c clouds, else every k SS-SUB bit positions, k the widest segment
+    that does (``planner.choose_reduce_every``). A column no segment fits
+    is refused at plan time.
     """
     where: Between
-    reduce_every: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
 class RangeSelect(Plan):
-    """Fetch all tuples with col in [lo, hi] (§3.4 + §3.2 fetch);
-    ``reduce_every`` as for :class:`RangeCount`."""
+    """Fetch all tuples with col in [lo, hi] (§3.4 + §3.2 fetch); the
+    ripple re-shares as for :class:`RangeCount`."""
     where: Between
-    reduce_every: int = 0
     padding: Padding = Padding.NONE
 
     def __post_init__(self):
@@ -234,20 +231,16 @@ class Join(Plan):
     on:   (left column, right column) — names or indices.
     kind: ``"pkfk"`` (§3.3.1, left column is a primary key) or ``"equi"``
           (§3.3.2, join values may repeat on both sides).
-    match_method: how the PK/FK match matrix is evaluated — ``"chain"``
-          (W sequential dot-sets, §3.1.2), ``"aggregate"`` (ONE flattened
-          W·A dot + the Lagrange equality indicator, §3.1.2 aggregate
-          form) or ``"auto"`` (planner-priced). Both produce the same
-          secrets at the same degree; the choice is a backend-execution
-          knob the planner prices by launch count. Defaults to ``"chain"``
-          (the paper's dispatch shape — one ``match_matrix`` per group);
-          pass ``"auto"`` to let the planner pick the cheaper launch plan.
+
+    The planner evaluates a PK/FK match matrix by the §3.1.2 chain or its
+    aggregate form, whichever takes fewer backend launches
+    (``planner.choose_match_method``); both open the same secrets at the
+    same degree.
     """
     right: SecretSharedDB
     on: Tuple[ColumnRef, ColumnRef]
     kind: str = "pkfk"
     padding: Padding = Padding.NONE
-    match_method: str = "chain"
 
     def __post_init__(self):
         if self.kind not in JOIN_KINDS:
@@ -255,10 +248,6 @@ class Join(Plan):
                              f"{JOIN_KINDS}")
         if len(self.on) != 2:
             raise ValueError("Join.on must be a (left, right) column pair")
-        if self.match_method not in (AUTO, "chain", "aggregate"):
-            raise ValueError(
-                f"unknown match_method {self.match_method!r}; choose from "
-                f"('auto', 'chain', 'aggregate')")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -273,25 +262,19 @@ class Aggregate(Plan):
             aggregate tensor and raise ``VerificationError`` if a cloud's
             response share is inconsistent. Needs c >= degree + 2 clouds;
             the extra round/bits are priced in ``explain()``.
-    reduce_every: MIN/MAX only — insert a degree-reduction round every
-            this many comparator bit positions (same knob as range plans,
-            chosen by the planner when left at 0).
+
+    A MIN/MAX comparator re-shares its carry where the planner says, as a
+    range plan's ripple does.
     """
     op: str
     column: ColumnRef
     where: Optional[Eq] = None
     verify: bool = False
-    reduce_every: int = 0
 
     def __post_init__(self):
         if self.op not in AGG_OPS:
             raise ValueError(f"unknown aggregate op {self.op!r}; choose "
                              f"from {AGG_OPS}")
-        if self.reduce_every < 0:
-            raise ValueError("reduce_every must be >= 0")
-        if self.reduce_every and self.op in ("sum", "avg"):
-            raise ValueError("reduce_every is a MIN/MAX comparator knob; "
-                             "SUM/AVG run in one contraction round")
 
 
 @dataclasses.dataclass(frozen=True)

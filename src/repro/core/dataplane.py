@@ -25,11 +25,10 @@ together with the reduction that reassembles them:
     state themselves (the ripple carry chain).
 
 Execution is a *placement policy*, not part of the protocol:
-:class:`SerialDispatcher` runs shards inline (the S = 1 path is exactly the
+:class:`Dispatcher` runs shards inline (the S = 1 path is exactly the
 pre-shard engine), :class:`ThreadedDispatcher` fans them out over a thread
-pool (the async serving runtime), and
-``repro.api.executor.MapReduceDispatcher`` places each shard dispatch as a
-fault-tolerant MapReduce task.
+pool (the async serving runtime), and :class:`MapReduceDispatcher` places
+each shard dispatch as a fault-tolerant MapReduce task.
 
 Two multi-tenant refinements ride on the thread pool:
 
@@ -65,6 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from . import field
+from ..runtime.mapreduce import MapReduceRunner
 from .engine import SecretSharedDB
 from .partition import split_bounds
 from .shamir import Shares
@@ -295,6 +295,20 @@ class PoolHandle(Dispatcher):
 
     def close(self) -> None:
         self._detached = True
+
+
+class MapReduceDispatcher(Dispatcher):
+    """Run each shard dispatch as one MapReduce task (re-execution and
+    speculative straggler backups included — shard dispatches are pure
+    share-space programs, so duplicate execution is safe)."""
+
+    def __init__(self, runner: MapReduceRunner):
+        self.runner = runner
+
+    def run_all(self, thunks: Sequence[Callable[[], Any]]) -> List[Any]:
+        if len(thunks) <= 1:
+            return [t() for t in thunks]
+        return self.runner.run(lambda t: t(), list(thunks))
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,10 @@
 """Contiguous range partitioning — the one split helper everyone shares.
 
-Three call sites used to carry their own ``np.linspace``-based variant of
-this logic: the MapReduce input-split bounds (``repro.api.executor``), the
-tree-selection block partitioning (``repro.core.queries``), and the executor
-backend wrappers. They now all call :func:`split_bounds`, so a split computed
-for a MapReduce map task and a block computed for a §3.2.2 Q&A round follow
-the same rounding rules (``linspace`` edges truncated toward zero, empty
-sub-ranges dropped).
+The dataplane's tuple-axis shards (``repro.core.dataplane``) and the
+tree-selection block partitioning (``repro.core.queries``) both call
+:func:`split_bounds`, so a shard and a block computed for a §3.2.2 Q&A round
+follow the same rounding rules (``linspace`` edges truncated toward zero,
+empty sub-ranges dropped).
 """
 from __future__ import annotations
 

@@ -8,7 +8,7 @@ DEPRECATED as a public surface: these free functions are kept as the
 protocol implementations (and for backward compatibility), but new code
 should use ``repro.api.QueryClient`` — one facade with logical plans,
 name-based columns, automatic key derivation, a cost-based selection
-planner, and the backend registry replacing the old ``impl=`` strings.
+planner, and the backend registry.
 """
 from . import aggregate, embed, rounds
 from .aggregate import VerificationError

@@ -1,27 +1,18 @@
 """Shared backend resolution for the query suite.
 
 Queries accept ``backend=`` (a registered name or ``repro.api.backends
-.Backend`` instance). The historical ``impl="jnp"|"pallas"`` strings are
-still accepted as a deprecated alias so pre-registry callers keep working.
-The import of the registry is deferred: ``repro.api`` sits *above* the core
-layer, and resolving at call time keeps the layering acyclic.
+.Backend`` instance). The import of the registry is deferred: ``repro.api``
+sits *above* the core layer, and resolving at call time keeps the layering
+acyclic.
 """
 from __future__ import annotations
-
-import warnings
-from typing import Optional
 
 from ..shamir import Shares
 
 
-def resolve_backend(backend, impl: Optional[str] = None):
-    """-> Backend; ``impl`` (deprecated) overrides ``backend`` when given."""
+def get_backend(backend):
+    """-> Backend: ``repro.api.backends.get_backend``, imported late."""
     from ...api import backends as _registry
-    if impl is not None:
-        warnings.warn(
-            "the impl= argument is deprecated; use backend= (see "
-            "repro.api.backends)", DeprecationWarning, stacklevel=3)
-        backend = impl
     return _registry.get_backend(backend)
 
 

@@ -16,12 +16,12 @@ import jax
 from ..costs import CostLedger
 from ..engine import SecretSharedDB
 from . import rounds
-from ._common import resolve_backend
+from ._common import get_backend
 
 
 def count_query(key: jax.Array, db: SecretSharedDB, column: int, pattern: str,
                 *, ledger: Optional[CostLedger] = None,
-                backend="jnp", impl: Optional[str] = None
+                backend="jnp"
                 ) -> Tuple[int, CostLedger]:
     """COUNT(*) WHERE col = pattern — oblivious, one round.
 
@@ -30,7 +30,7 @@ def count_query(key: jax.Array, db: SecretSharedDB, column: int, pattern: str,
     one count share per contacted cloud.
     """
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     cnt = rounds.count_phase(
         be, db, [rounds.MatchJob(column, pattern, key, ledger)])[0]
     return cnt, ledger

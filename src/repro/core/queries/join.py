@@ -39,7 +39,7 @@ import jax
 from ..costs import CostLedger
 from ..engine import SecretSharedDB
 from . import rounds
-from ._common import resolve_backend
+from ._common import get_backend
 
 
 # ---------------------------------------------------------------------------
@@ -62,10 +62,10 @@ def pkfk_join(*args, **kwargs) -> Tuple[List[List[str]], CostLedger]:
 def _pkfk_join(key: Optional[jax.Array], dbX: SecretSharedDB,
                dbY: SecretSharedDB, col_x: int, col_y: int, *,
                ledger: Optional[CostLedger] = None,
-               backend="jnp", impl: Optional[str] = None
+               backend="jnp"
                ) -> Tuple[List[List[str]], CostLedger]:
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     job = rounds.JoinJob(dbY, col_x, col_y, key, ledger)
     entries = rounds.join_match_round(be, dbX, [job])
     _, fetched = rounds.fetch_fusion(be, dbX, [], entries)
@@ -81,7 +81,7 @@ def equijoin(key: jax.Array, dbX: SecretSharedDB, dbY: SecretSharedDB,
              col_x: int, col_y: int, *,
              ledger: Optional[CostLedger] = None,
              padded_values: int = 0,
-             backend="jnp", impl: Optional[str] = None
+             backend="jnp"
              ) -> Tuple[List[List[str]], CostLedger]:
     """General equijoin; join values may repeat in BOTH relations.
 
@@ -89,7 +89,7 @@ def equijoin(key: jax.Array, dbX: SecretSharedDB, dbY: SecretSharedDB,
     discussion of §3.3.2).
     """
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     rows = rounds.equijoin_rounds(be, dbX, [
         rounds.EquiJob(dbY, col_x, col_y, key, ledger,
                        padded_values=padded_values)])[0]

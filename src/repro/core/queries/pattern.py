@@ -38,7 +38,7 @@ from .. import encoding
 from ..costs import CostLedger
 from ..engine import SecretSharedDB
 from . import rounds
-from ._common import resolve_backend
+from ._common import get_backend
 from .rounds import match_phase_cost  # noqa: F401  (re-export)
 
 #: spec kinds that leave the full-width chain (``masked`` does not)
@@ -66,7 +66,7 @@ def pattern_count(key: jax.Array, db: SecretSharedDB, column: int,
                   backend="jnp") -> Tuple[int, CostLedger]:
     """COUNT(*) WHERE col LIKE pattern — one round (two for CONTAINS)."""
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, None)
+    be = get_backend(backend)
     cnt = rounds.count_phase(
         be, db, [rounds.MatchJob(column, spec.body, key, ledger, spec)])[0]
     return cnt, ledger
@@ -86,7 +86,7 @@ def pattern_select(key: jax.Array, db: SecretSharedDB, column: int,
     ``(rows, addresses, ledger)``.
     """
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, None)
+    be = get_backend(backend)
     k_pat, k_fetch = jax.random.split(key)
     if strategy == "one_round":
         addresses = rounds.match_all_round(

@@ -33,7 +33,7 @@ from ..costs import CostLedger
 from ..engine import SecretSharedDB
 from ..shamir import Shares
 from . import rounds
-from ._common import resolve_backend
+from ._common import get_backend
 
 
 def _xor(a: Shares, b: Shares) -> Shares:
@@ -82,7 +82,7 @@ def ss_sub(key: jax.Array, A: Shares, B: Shares, *,
 def range_count(key: jax.Array, db: SecretSharedDB, column: int,
                 lo: int, hi: int, *, ledger: Optional[CostLedger] = None,
                 reduce_every: int = 0,
-                backend="jnp", impl: Optional[str] = None
+                backend="jnp"
                 ) -> Tuple[int, CostLedger]:
     """COUNT(*) WHERE lo <= col <= hi (Algorithm 5, counting phase).
 
@@ -90,7 +90,7 @@ def range_count(key: jax.Array, db: SecretSharedDB, column: int,
     ``ripple_carry`` runs the whole carry chain, one dispatch per bit.
     """
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     cnt = rounds.range_rounds(be, db, [
         rounds.RangeJob(column, lo, hi, key, ledger,
                         reduce_every=reduce_every)])[0]
@@ -100,7 +100,7 @@ def range_count(key: jax.Array, db: SecretSharedDB, column: int,
 def range_select(key: jax.Array, db: SecretSharedDB, column: int,
                  lo: int, hi: int, *, ledger: Optional[CostLedger] = None,
                  reduce_every: int = 0, padded_rows: Optional[int] = None,
-                 backend="jnp", impl: Optional[str] = None
+                 backend="jnp"
                  ) -> Tuple[List[List[str]], List[int], CostLedger]:
     """Fetch all tuples with col ∈ [lo, hi] (Alg 5 "simple solution" path:
     per-tuple indicator bits -> addresses -> oblivious matrix fetch).
@@ -109,7 +109,7 @@ def range_select(key: jax.Array, db: SecretSharedDB, column: int,
     batch the fetch rides the cross-group fused ``ss_matmul``.
     """
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     k_ind, k_fetch = jax.random.split(key)
     addresses = rounds.range_rounds(be, db, [
         rounds.RangeJob(column, lo, hi, k_ind, ledger,

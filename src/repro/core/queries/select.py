@@ -29,7 +29,7 @@ import jax
 from ..costs import CostLedger
 from ..engine import SecretSharedDB
 from . import rounds
-from ._common import resolve_backend
+from ._common import get_backend
 from .count import count_query
 
 
@@ -51,11 +51,11 @@ class CardinalityError(ValueError):
 def select_one_tuple(key: jax.Array, db: SecretSharedDB, column: int,
                      pattern: str, *, ledger: Optional[CostLedger] = None,
                      skip_count_phase: bool = False,
-                     backend="jnp", impl: Optional[str] = None
+                     backend="jnp"
                      ) -> Tuple[List[List[str]], CostLedger]:
     """SELECT * WHERE col = pattern, when the predicate hits exactly 1 tuple."""
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     k_count, k_sel = jax.random.split(key)
 
     if not skip_count_phase:  # Phase 0 (Alg 3 line 1)
@@ -79,14 +79,14 @@ def select_one_tuple(key: jax.Array, db: SecretSharedDB, column: int,
 def fetch_by_addresses(key: jax.Array, db: SecretSharedDB,
                        addresses: Sequence[int], *, ledger: CostLedger,
                        padded_rows: Optional[int] = None,
-                       backend="jnp", impl: Optional[str] = None
+                       backend="jnp"
                        ) -> List[List[str]]:
     """Fetch tuples at known addresses with an ℓ'×n shared one-hot matrix.
 
     ``padded_rows`` ≥ ℓ hides the true result size (fake-row padding, §3.2.2
     leakage discussion): extra rows are all-zero one-hots and fetch nothing.
     """
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     return rounds.fetch_round(
         be, db, [rounds.FetchJob(key, list(addresses), ledger,
                                  padded_rows)])[0]
@@ -99,12 +99,12 @@ def fetch_by_addresses(key: jax.Array, db: SecretSharedDB,
 def select_one_round(key: jax.Array, db: SecretSharedDB, column: int,
                      pattern: str, *, ledger: Optional[CostLedger] = None,
                      padded_rows: Optional[int] = None,
-                     backend="jnp", impl: Optional[str] = None
+                     backend="jnp"
                      ) -> Tuple[List[List[str]], List[int], CostLedger]:
     """Phase 1: per-tuple match bits in ONE round (user interpolates n·c′).
     Phase 2: oblivious matrix fetch."""
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     k_pat, k_fetch = jax.random.split(key)
 
     addresses = rounds.match_all_round(
@@ -124,7 +124,7 @@ def select_tree(key: jax.Array, db: SecretSharedDB, column: int, pattern: str,
                 branching: Optional[int] = None,
                 padded_rows: Optional[int] = None,
                 known_count: Optional[int] = None,
-                backend="jnp", impl: Optional[str] = None
+                backend="jnp"
                 ) -> Tuple[List[List[str]], List[int], CostLedger]:
     """Tree-based multi-round address discovery + oblivious fetch (Alg 4).
 
@@ -139,7 +139,7 @@ def select_tree(key: jax.Array, db: SecretSharedDB, column: int, pattern: str,
     and measured ledger never moves with the shard count.
     """
     ledger = ledger if ledger is not None else CostLedger()
-    be = resolve_backend(backend, impl)
+    be = get_backend(backend)
     k_count, k_pat, k_fetch = jax.random.split(key, 3)
 
     # Phase 0: count occurrences (unless the caller already did)

@@ -173,7 +173,7 @@ def match_matrix_batch(col_x: jax.Array, col_y: jax.Array) -> jax.Array:
 def as_backend():
     """Bundle these kernels as the ``"pallas"`` entry of the backend
     registry (``repro.api.backends``) — the query suite selects them with
-    ``backend="pallas"`` instead of the old ``impl=`` strings."""
+    ``backend="pallas"``."""
     from ..api.backends import Backend  # local import to avoid cycle
     return Backend(name="pallas", aa_match=aa_match, ss_matmul=ss_matmul,
                    match_matrix=match_matrix, aa_match_batch=aa_match_batch,

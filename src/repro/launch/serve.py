@@ -20,7 +20,7 @@ oldest request's *steered* wait expires — and runs the group through
 ``QueryClient.run_batch(plans, relation=...)``, which groups compatible
 strategies and executes every protocol round once for the whole group —
 including range traffic (one fused SS-SUB ripple segment per
-degree-reduction interval per (bit-width, reduce_every) group) and join
+degree-reduction interval per bit-width group) and join
 traffic (equal-size PK/FK match matrices stack into one batched dispatch
 and ride the batch's single cross-group fetch ``ss_matmul``; equijoins
 fuse per phase), so a mixed live queue pays one dispatch set per round,
@@ -62,9 +62,7 @@ it all consistently under the stats lock. The scheduler's clock is
 ``time.perf_counter``, so waits and latencies never jump with the wall
 clock. Per-request keys derive from the target relation's root key in pop
 order (streams are per relation, so tenants never perturb each other's
-transcripts); an optional ``MapReduceExecutor`` fans each cloud-side map
-phase (including the fused batch dispatch) out over fault-tolerant worker
-splits. The synchronous ``pump``/``serve`` surface is unchanged — the
+transcripts). The synchronous ``pump``/``serve`` surface is unchanged — the
 scheduler thread is the same ``pump`` driven by deadlines instead of by
 the caller.
 """
@@ -80,8 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..api import (DEFAULT_RELATION, MapReduceExecutor, Plan, QueryClient,
-                   QueryResult)
+from ..api import DEFAULT_RELATION, Plan, QueryClient, QueryResult
 from ..api.plans import PATTERN_PREDICATES
 from ..core.dataplane import (Dispatcher, ShardedRelation,
                               ThreadedDispatcher, span)
@@ -567,7 +564,7 @@ class QueryServer:
     server-owned thread pool (``pool_workers`` bounds the global fan-out),
     each through its own detachable :class:`~repro.core.dataplane.
     PoolHandle` — pass ``dispatcher=`` to override placement per relation
-    (e.g. ``MapReduceExecutor.dispatcher()``). Sharding and batching are
+    (e.g. a ``MapReduceDispatcher``). Sharding and batching are
     both pure execution policy, and per-relation key streams are
     independent, so every relation's rows and ledgers are bit-identical
     to a solo single-relation server (the multi-tenant acceptance test).
@@ -576,7 +573,6 @@ class QueryServer:
     def __init__(self, db: Union[SecretSharedDB, ShardedRelation,
                                  None] = None, key=None, *,
                  backend="jnp",
-                 executor: Optional[MapReduceExecutor] = None,
                  max_batch: int = 32,
                  max_wait_ms: float = 20.0,
                  shards: int = 1,
@@ -585,7 +581,7 @@ class QueryServer:
         self.max_batch = max(1, max_batch)
         self.max_wait_ms = max(0.0, max_wait_ms)
         self.client = QueryClient(db, 0 if key is None else key,
-                                  backend=backend, executor=executor)
+                                  backend=backend)
         self._owned_dispatcher: Optional[ThreadedDispatcher] = None
         self._pool_workers = pool_workers
         self._tenants: Dict[str, _Tenant] = {}

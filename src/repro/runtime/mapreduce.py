@@ -20,15 +20,12 @@ At cluster scale each "worker" is a TPU host driving a jitted shard program;
 here workers are threads driving the same jitted functions on CPU — the
 scheduling logic is identical and is what the tests exercise.
 
-Two callers sit on top of this runner: the backend wrapper
-(``repro.api.executor.MapReduceExecutor.wrap`` — each hot op splits its own
-data axis into map tasks) and the sharded-dataplane placement policy
-(``repro.api.executor.MapReduceDispatcher`` — the round engine already
-emitted one dispatch per tuple-axis shard of a
-``repro.core.dataplane.ShardedRelation``; each shard dispatch becomes one
-map task here, inheriting re-execution and speculative backups). ``splits``
-is any sequence of task payloads — input-split bounds for the wrapper,
-zero-argument thunks for the dispatcher.
+Its caller is the sharded-dataplane placement policy
+(``repro.core.dataplane.MapReduceDispatcher``): the round engine emits one
+dispatch per tuple-axis shard of a ``ShardedRelation``, and each shard
+dispatch becomes one map task here, inheriting re-execution and speculative
+backups. ``splits`` is any sequence of task payloads; the dispatcher's are
+zero-argument thunks.
 """
 from __future__ import annotations
 

@@ -20,9 +20,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import (Aggregate, Count, Eq, MapReduceExecutor, Plan,
+from repro.api import (Aggregate, Count, Eq, MapReduceDispatcher, Plan,
                        PlanNotSupported, QueryClient, Select,
-                       VerificationError, get_backend, ripple_segmenter)
+                       VerificationError, get_backend, planner,
+                       ripple_segmenter)
 from repro.core import Codec, dataplane, encoding, field, outsource
 from repro.core.costs import CostLedger
 from repro.core.dataplane import ShardedRelation, span
@@ -58,15 +59,17 @@ def _oracle(rows):
     return vals, names
 
 
+# at signed_db's 20 clouds the planner re-shares each 14-bit MIN/MAX
+# comparator after 9 bits (tests/test_reduce_every.py::test_planner_choice)
 ALL_OPS_PLANS = [
     Aggregate("sum", "Val"),
     Aggregate("sum", "Val", where=Eq("Name", "nm1")),
     Aggregate("avg", "Val"),
     Aggregate("avg", "Val", where=Eq("Name", "nm2")),
-    Aggregate("min", "Val", reduce_every=2),
-    Aggregate("max", "Val", reduce_every=2),
-    Aggregate("min", "Val", where=Eq("Name", "nm3"), reduce_every=2),
-    Aggregate("max", "Val", where=Eq("Name", "nm4"), reduce_every=2),
+    Aggregate("min", "Val"),
+    Aggregate("max", "Val"),
+    Aggregate("min", "Val", where=Eq("Name", "nm3")),
+    Aggregate("max", "Val", where=Eq("Name", "nm4")),
 ]
 
 
@@ -136,8 +139,7 @@ def test_aggregates_fuse_with_other_families(signed_db):
 def test_empty_predicate_yields_none_value(signed_db):
     _, db = signed_db
     cl = QueryClient(db, key=5)
-    r = cl.run(Aggregate("min", "Val", where=Eq("Name", "zzz"),
-                         reduce_every=2))
+    r = cl.run(Aggregate("min", "Val", where=Eq("Name", "zzz")))
     assert r.value is None and r.count == 0
     r = cl.run(Aggregate("avg", "Val", where=Eq("Name", "zzz")))
     assert r.value is None and r.count == 0
@@ -149,7 +151,7 @@ def test_empty_predicate_yields_none_value(signed_db):
 def test_convenience_method(signed_db):
     rows, db = signed_db
     vals, _ = _oracle(rows)
-    r = QueryClient(db, key=11).aggregate("max", "Val", reduce_every=2)
+    r = QueryClient(db, key=11).aggregate("max", "Val")
     assert r.value == int(vals.max())
 
 
@@ -159,7 +161,7 @@ def test_single_tuple_relation_minmax():
                    column_names=["Id", "V"], codec=CODEC, n_shares=20,
                    degree=1, numeric_columns={1: 8})
     cl = QueryClient(db, key=1)
-    assert cl.run(Aggregate("min", "V", reduce_every=2)).value == 42
+    assert cl.run(Aggregate("min", "V")).value == 42
     assert cl.run(Aggregate("sum", "V", verify=True)).value == 42
 
 
@@ -170,10 +172,8 @@ def test_single_tuple_relation_minmax():
 def test_plan_validation():
     with pytest.raises(ValueError, match="unknown aggregate op"):
         Aggregate("median", "Val")
-    with pytest.raises(ValueError, match="reduce_every"):
-        Aggregate("sum", "Val", reduce_every=2)
-    with pytest.raises(ValueError, match="reduce_every"):
-        Aggregate("min", "Val", reduce_every=-1)
+    with pytest.raises(TypeError, match="reduce_every"):
+        Aggregate("min", "Val", reduce_every=2)   # the planner's choice
 
 
 def test_non_numeric_column_rejected(signed_db):
@@ -212,7 +212,7 @@ def test_explain_single_non_select_plan(signed_db):
     cl = QueryClient(db, key=1)
     exp = cl.explain(Count(Eq("Name", "nm1")))
     assert exp.groups[0].family == "count" and exp.bits > 0
-    exp = cl.explain(Aggregate("min", "Val", reduce_every=2))
+    exp = cl.explain(Aggregate("min", "Val"))
     assert exp.groups[0].family == "aggregate"
 
 
@@ -237,8 +237,7 @@ def test_verify_overhead_is_priced_and_bounded(signed_db):
     per opened tensor — and never changes the opened value."""
     _, db = signed_db
     for plan in (Aggregate("sum", "Val", where=Eq("Name", "nm1")),
-                 Aggregate("min", "Val", where=Eq("Name", "nm3"),
-                           reduce_every=2)):
+                 Aggregate("min", "Val", where=Eq("Name", "nm3"))):
         off = QueryClient(db, key=7).run(plan)
         on = QueryClient(db, key=7).run(
             dataclasses.replace(plan, verify=True))
@@ -290,7 +289,7 @@ def test_verify_detects_tampered_minmax_share(signed_db):
                              ripple_segment=bad_segment)
     with pytest.raises(VerificationError, match="MIN verification failed"):
         QueryClient(db, key=7, backend=be).run(
-            Aggregate("min", "Val", reduce_every=2, verify=True))
+            Aggregate("min", "Val", verify=True))
 
 
 def test_verify_needs_redundant_clouds():
@@ -578,13 +577,13 @@ def _loop_minmax_rounds(be, db, jobs):
     return out
 
 
-def _minmax_db(n):
+def _minmax_db(n, c=20):
     """n rows of signed 8-bit values, three names for the predicates."""
     rows = [[f"id{i}", f"nm{i % 3}", str((i * 37) % 121 - 60)]
             for i in range(n)]
     return rows, outsource(jax.random.PRNGKey(n), rows,
                            column_names=["Id", "Name", "Val"], codec=CODEC,
-                           n_shares=20, degree=1, numeric_columns={2: 8})
+                           n_shares=c, degree=1, numeric_columns={2: 8})
 
 
 @pytest.fixture(scope="module")
@@ -655,11 +654,13 @@ def test_fused_tournament_matches_host_loop(minmax_dbs, monkeypatch, op,
 
 def test_second_identical_max_compiles_nothing(minmax_dbs):
     """Each tournament program is traced once per shape: the same MAX
-    again compiles no program."""
-    _, db = minmax_dbs[48]
+    again compiles no program. At 7 clouds the planner re-shares the
+    8-bit comparator every 2 bits."""
+    _, db = _minmax_db(48, c=7)
+    assert planner.choose_reduce_every(7, 1, 8, tournament=True) == 2
     client = QueryClient(db, key=5)
     plane = client.attach(shards=1)
-    plan = Aggregate("max", "Val", reduce_every=2)
+    plan = Aggregate("max", "Val")
     first = client.run(plan)
     compiles = []
 
@@ -694,17 +695,19 @@ def test_server_snapshot_counts_tournament_segments(minmax_dbs):
     assert server.dataplane.stats.ripple_segments == 6 * snap["batches"]
 
 
-def test_mapreduce_executor_runs_the_tournament(minmax_dbs):
-    """A backend whose segment fans out to host workers runs each segment
-    between the fused programs, with equal results."""
-    _, db = minmax_dbs[48]
+def test_mapreduce_executor_runs_the_tournament():
+    """Shard dispatches placed as MapReduce tasks run the tournament and
+    its conditional match with equal results and ledgers. At 14 clouds
+    the planner re-shares the 8-bit comparator after 6 bits."""
+    _, db = _minmax_db(48, c=14)
+    assert planner.choose_reduce_every(14, 1, 8, tournament=True) == 6
     runner = MapReduceRunner(WorkerPool(2), lease_s=5.0, max_attempts=30)
-    plans = [Aggregate("max", "Val", reduce_every=2),
-             Aggregate("min", "Val", where=Eq("Name", "nm1"),
-                       reduce_every=2)]
+    plans = [Aggregate("max", "Val"),
+             Aggregate("min", "Val", where=Eq("Name", "nm1"))]
     ref = QueryClient(db, key=21).run_batch(plans)
-    got = QueryClient(db, key=21,
-                      executor=MapReduceExecutor(runner, n_splits=3)
-                      ).run_batch(plans)
+    cl = QueryClient(db, key=21)
+    plane = cl.attach(shards=3, dispatcher=MapReduceDispatcher(runner))
+    got = cl.run_batch(plans)
+    assert plane.stats.reshares > 0
     for a, b in zip(ref, got):
         assert (a.value, a.count, a.ledger) == (b.value, b.count, b.ledger)

@@ -1,9 +1,9 @@
-"""Unified QueryClient API: plans, planner, backends, executor.
+"""Unified QueryClient API: plans, planner, backends, MapReduce placement.
 
 Covers plan construction + validation, name-based column resolution, the
 cost-based planner's strategy choice across (n, ℓ) regimes, exact
 ``CostLedger``/row equivalence between the client and the legacy free
-functions, and the MapReduce executor path.
+functions, and shard dispatches placed as MapReduce tasks.
 """
 import dataclasses
 
@@ -12,10 +12,11 @@ import numpy as np
 import pytest
 
 from repro.api import (AUTO, Between, Count, DBStats, Eq, Join,
-                       MapReduceExecutor, Padding, QueryClient, QueryResult,
+                       MapReduceDispatcher, Padding, QueryClient, QueryResult,
                        RangeCount, RangeSelect, Select, available_backends,
                        candidate_estimates, choose_select_strategy,
-                       estimate_select_cost, get_backend, resolve_column)
+                       estimate_select_cost, get_backend, planner,
+                       resolve_column)
 from repro.core import outsource, Codec
 from repro.core.queries import (count_query, pkfk_join, range_count,
                                 range_select, select_one_round,
@@ -281,9 +282,14 @@ def test_pkfk_join_keyword_call_forms():
                   key=jax.random.PRNGKey(6))       # duplicate key
 
 
-def test_range_queries_match_legacy(client, employee_db):
-    res = client.range_count("Salary", 1000, 2000, reduce_every=2)
-    cnt, led = range_count(jax.random.PRNGKey(0), employee_db, 3, 1000,
+def test_range_queries_match_legacy():
+    # at 7 clouds the planner re-shares the 14-bit ripple every 2 bits
+    db7 = outsource(jax.random.PRNGKey(7), EMPLOYEE, column_names=COLUMNS,
+                    codec=CODEC, n_shares=7, degree=1,
+                    numeric_columns={3: 14})
+    assert planner.choose_reduce_every(7, 1, 14) == 2
+    res = QueryClient(db7, key=42).range_count("Salary", 1000, 2000)
+    cnt, led = range_count(jax.random.PRNGKey(0), db7, 3, 1000,
                            2000, reduce_every=2)
     assert res.count == cnt == 2
     assert res.ledger == led
@@ -369,27 +375,22 @@ def test_client_pallas_backend_matches_jnp(employee_db):
     assert rj.ledger == rp.ledger
 
 
-def test_impl_alias_is_deprecated(employee_db):
-    with pytest.warns(DeprecationWarning):
-        cnt, _ = count_query(jax.random.PRNGKey(0), employee_db, 1, "Eve",
-                             impl="jnp")
-    assert cnt == 1
-
-
 # ---------------------------------------------------------------------------
-# MapReduce executor path
+# MapReduce placement of shard dispatches
 # ---------------------------------------------------------------------------
 
 def _mr_client(db, **pool_kw):
     pool = WorkerPool(3, **pool_kw)
     runner = MapReduceRunner(pool, lease_s=5.0, max_attempts=30)
-    return QueryClient(db, key=42,
-                       executor=MapReduceExecutor(runner, n_splits=3))
+    cl = QueryClient(db, key=42)
+    cl.attach(shards=3, dispatcher=MapReduceDispatcher(runner))
+    return cl
 
 
 def test_mapreduce_executor_count_and_select(employee_db):
     cl = _mr_client(employee_db)
-    assert cl.backend.name == "jnp+mapreduce"
+    assert isinstance(cl.dataplane.dispatcher, MapReduceDispatcher)
+    assert cl.dataplane.n_shards == 3
     plain = QueryClient(employee_db, key=42)
     res_mr, res = cl.count("FirstName", "John"), plain.count("FirstName",
                                                              "John")

@@ -15,8 +15,8 @@ import pytest
 
 from repro.api import (Between, Count, DBStats, Eq, Join, Padding,
                        QueryClient, RangeCount, RangeSelect, Select,
-                       MapReduceExecutor, choose_select_strategy,
-                       get_backend)
+                       MapReduceDispatcher, choose_select_strategy,
+                       get_backend, planner)
 from repro.api.backends import Backend, batched_matcher, ripple_stepper
 from repro.core import outsource, Codec
 from repro.core.queries import CardinalityError, select_tree
@@ -204,7 +204,7 @@ def test_run_batch_mixed_strategies_equals_sequential(employee_db):
         Select(Eq("FirstName", "John"), strategy="one_round",
                padding=Padding.to_rows(4)),
         Select(Eq("FirstName", "Zoe"), strategy="tree"),   # ℓ = 0
-        RangeCount(Between("Salary", 900, 2100), reduce_every=2),
+        RangeCount(Between("Salary", 900, 2100)),
         Select(Eq("LastName", "Smith")),                   # auto strategy
     ]
     seq = [QueryClient(employee_db, key=42).run(p) for p in plans]
@@ -261,12 +261,17 @@ def test_run_batch_pallas_matches_jnp():
         _assert_results_equal(a, b)
 
 
+def _mr_client(db, key):
+    """A client whose 3 shard dispatches run as MapReduce tasks."""
+    runner = MapReduceRunner(WorkerPool(3), lease_s=5.0, max_attempts=30)
+    cl = QueryClient(db, key=key)
+    cl.attach(shards=3, dispatcher=MapReduceDispatcher(runner))
+    return cl
+
+
 def test_run_batch_mapreduce_executor_splits_fused_batch():
     _, db = _wide_db()
-    pool = WorkerPool(3)
-    runner = MapReduceRunner(pool, lease_s=5.0, max_attempts=30)
-    cl_mr = QueryClient(db, key=21,
-                        executor=MapReduceExecutor(runner, n_splits=3))
+    cl_mr = _mr_client(db, 21)
     cl = QueryClient(db, key=21)
     plans = [Select(Eq("Name", p), strategy="one_round")
              for p in ("ann", "bob", "cat")]
@@ -278,12 +283,15 @@ def test_run_batch_mapreduce_executor_splits_fused_batch():
 # batched ranges: one fused ripple dispatch per bit-round
 # ---------------------------------------------------------------------------
 
-def _range_db(n=32, word_length=6, t_bits=14):
+def _range_db(n=32, word_length=6, t_bits=14, n_shares=20):
+    """At the default 20 clouds the planner re-shares a 14-bit ripple
+    after 9 bits; at 7 clouds, every 2."""
     rows = [[f"id{i}", f"nm{i % 5}", str(500 + 137 * i)] for i in range(n)]
     return rows, outsource(jax.random.PRNGKey(19), rows,
                            column_names=["Id", "Name", "Val"],
-                           codec=Codec(word_length=word_length), n_shares=20,
-                           degree=1, numeric_columns={2: t_bits})
+                           codec=Codec(word_length=word_length),
+                           n_shares=n_shares, degree=1,
+                           numeric_columns={2: t_bits})
 
 
 def _child_db(rows, k=6, word_length=6, n_shares=20, dup=False):
@@ -297,10 +305,10 @@ def _child_db(rows, k=6, word_length=6, n_shares=20, dup=False):
 
 
 def test_batch16_ranges_one_ripple_dispatch_per_bit_round(monkeypatch):
-    _, db = _range_db()
-    plans = [RangeCount(Between("Val", 600, 600 + 200 * i), reduce_every=2)
+    _, db = _range_db(n_shares=7)
+    plans = [RangeCount(Between("Val", 600, 600 + 200 * i))
              if i % 2 == 0 else
-             RangeSelect(Between("Val", 500, 700 + 150 * i), reduce_every=2)
+             RangeSelect(Between("Val", 500, 700 + 150 * i))
              for i in range(16)]
     seq = [QueryClient(db, key=33).run(p) for p in plans]
 
@@ -319,17 +327,58 @@ def test_batch16_ranges_one_ripple_dispatch_per_bit_round(monkeypatch):
         _assert_results_equal(a, b)
 
 
-def test_range_groups_split_by_reduce_every(monkeypatch):
-    """Different reduce_every values cannot share a carry chain: they form
-    separate groups (each fused), and results still match sequential."""
-    _, db = _range_db()
-    plans = [RangeCount(Between("Val", 500, 3000), reduce_every=2),
-             RangeCount(Between("Val", 500, 3000), reduce_every=4),
-             RangeCount(Between("Val", 600, 2000), reduce_every=2)]
-    seq = [QueryClient(db, key=3).run(p) for p in plans]
+def _two_width_db():
+    """A 14-bit and an 8-bit numeric column at 7 clouds: the planner
+    re-shares both ripples every 2 bits (7 and 4 segments)."""
+    rows = [[f"id{i}", str(500 + 137 * i), str(i * 7 % 60)]
+            for i in range(12)]
+    return outsource(jax.random.PRNGKey(29), rows,
+                     column_names=["Id", "Val", "Qty"], codec=CODEC,
+                     n_shares=7, degree=1, numeric_columns={1: 14, 2: 8})
+
+
+def _range_batch(plans, key):
+    """(sequential results, batched results, counting calls, plane stats)
+    of ``plans`` over :func:`_two_width_db`."""
+    db = _two_width_db()
+    seq = [QueryClient(db, key=key).run(p) for p in plans]
     be, calls = _counting_backend()
-    bat = QueryClient(db, key=3, backend=be).run_batch(plans)
-    assert calls["ripple_carry"] == 28          # two groups, 14 bits each
+    cl = QueryClient(db, key=key, backend=be)
+    plane = cl.attach(shards=1)
+    return seq, cl.run_batch(plans), calls, plane.stats
+
+
+def test_range_groups_split_by_reduce_every():
+    """Ranges on two column widths cannot share a carry chain: they form
+    one group per width (each fused, each re-sharing where the planner
+    says for its width), and results still match sequential."""
+    plans = [RangeCount(Between("Val", 500, 3000)),
+             RangeCount(Between("Qty", 10, 60)),
+             RangeSelect(Between("Val", 600, 2000)),
+             RangeCount(Between("Qty", 0, 30))]
+    seq, bat, calls, stats = _range_batch(plans, 3)
+    every = {t: planner.choose_reduce_every(7, 1, t) for t in (14, 8)}
+    assert every == {14: 2, 8: 2}
+    assert calls["ripple_carry"] == 14 + 8      # two groups, one per width
+    assert stats.ripple_segments == sum(
+        len(rounds.ripple_schedule(t, k, 1)) for t, k in every.items())
+    assert stats.reshares == 6 + 3
+    for a, b in zip(seq, bat):
+        _assert_results_equal(a, b)
+
+
+def test_range_plans_on_one_width_form_one_group():
+    """RangeCount and RangeSelect plans on one column width, whatever their
+    bounds, run as ONE ripple group: one group's segments and re-shares."""
+    plans = [RangeCount(Between("Val", 500, 3000)),
+             RangeSelect(Between("Val", 700, 1500)),
+             RangeCount(Between("Val", 0, 4000)),
+             RangeCount(Between("Val", 600, 2000))]
+    seq, bat, calls, stats = _range_batch(plans, 4)
+    schedule = rounds.ripple_schedule(14, 2, 1)
+    assert calls["ripple_carry"] == 14
+    assert stats.ripple_segments == len(schedule) == 7
+    assert stats.reshares == len(schedule) - 1
     for a, b in zip(seq, bat):
         _assert_results_equal(a, b)
 
@@ -343,7 +392,7 @@ def test_cross_group_fetch_is_one_matmul(monkeypatch):
     child = _child_db(rows)
     plans = [Select(Eq("Name", "nm1"), strategy="one_round"),
              Select(Eq("Name", "nm2"), strategy="tree"),
-             RangeSelect(Between("Val", 550, 2500), reduce_every=2),
+             RangeSelect(Between("Val", 550, 2500)),
              Join(right=child, on=("Id", "Id"), kind="pkfk")]
     seq = [QueryClient(db, key=77).run(p) for p in plans]
 
@@ -351,9 +400,11 @@ def test_cross_group_fetch_is_one_matmul(monkeypatch):
     bat = QueryClient(db, key=77, backend=be).run_batch(plans)
 
     # one_round + tree + range one-hot matrices AND the join's transposed
-    # match matrix stack into a single fused fetch dispatch.
-    assert calls["ss_matmul"] == 1
-    assert calls["match_matrix"] == 1           # the join's n² string match
+    # match matrix stack into a single fused fetch dispatch; the planner
+    # evaluates the join's n² string match in the aggregate form (W = 6
+    # positions > its 2 launches), one flattened ss_matmul of its own.
+    assert calls["ss_matmul"] == 1 + 1
+    assert calls["match_matrix"] == 0
     for a, b in zip(seq, bat):
         _assert_results_equal(a, b)
 
@@ -379,16 +430,16 @@ def test_run_batch_all_families_b16_equals_sequential():
         Select(Eq("Name", "nm3"), strategy="tree"),
         Select(Eq("Id", "id7"), strategy="one_tuple"),
         Select(Eq("Name", "nm4")),                       # auto
-        RangeCount(Between("Val", 500, 2000), reduce_every=2),
-        RangeSelect(Between("Val", 900, 1800), reduce_every=2),
+        RangeCount(Between("Val", 500, 2000)),
+        RangeSelect(Between("Val", 900, 1800)),
         Join(right=child_pk, on=("Id", "Id"), kind="pkfk"),
         Join(right=child_dup, on=("Id", "Id"), kind="equi",
              padding=Padding.fake_values(1)),
         Select(Eq("Name", "nm0"), strategy="one_round",
                padding=Padding.to_rows(8)),
-        RangeCount(Between("Val", 0, 8000), reduce_every=2),
+        RangeCount(Between("Val", 0, 8000)),
         Select(Eq("Name", "zzz"), strategy="tree"),      # ℓ = 0
-        RangeSelect(Between("Val", 4000, 5000), reduce_every=2),
+        RangeSelect(Between("Val", 4000, 5000)),
         Count(Eq("Name", "nm0")),
         Join(right=child_pk, on=("Id", "Id"), kind="pkfk"),
         Select(Eq("Name", "nm1"), strategy="one_round"),
@@ -420,8 +471,8 @@ def test_equijoin_no_common_values_returns_empty():
 def test_run_batch_range_join_pallas_matches_jnp():
     rows, db = _range_db(n=8)
     child = _child_db(rows, k=4)
-    plans = [RangeCount(Between("Val", 500, 1200), reduce_every=2),
-             RangeSelect(Between("Val", 500, 900), reduce_every=2),
+    plans = [RangeCount(Between("Val", 500, 1200)),
+             RangeSelect(Between("Val", 500, 900)),
              Join(right=child, on=("Id", "Id"), kind="pkfk")]
     rj = QueryClient(db, key=5, backend="jnp").run_batch(plans)
     rp = QueryClient(db, key=5, backend="pallas").run_batch(plans)
@@ -435,7 +486,7 @@ def test_zero_match_select_empty_fetch_stack_all_backends():
     empty matmul."""
     _, db = _range_db(n=8)
     plans = [Select(Eq("Name", "zzz"), strategy="one_round"),
-             RangeSelect(Between("Val", 8000, 8100), reduce_every=2)]
+             RangeSelect(Between("Val", 8000, 8100))]
     for backend in ("jnp", "pallas"):
         res = QueryClient(db, key=6, backend=backend).run_batch(plans)
         assert res[0].rows == [] and res[0].addresses == []
@@ -445,13 +496,10 @@ def test_zero_match_select_empty_fetch_stack_all_backends():
 def test_run_batch_range_join_mapreduce_matches_plain():
     rows, db = _range_db()
     child = _child_db(rows)
-    pool = WorkerPool(3)
-    runner = MapReduceRunner(pool, lease_s=5.0, max_attempts=30)
-    cl_mr = QueryClient(db, key=21,
-                        executor=MapReduceExecutor(runner, n_splits=3))
+    cl_mr = _mr_client(db, 21)
     cl = QueryClient(db, key=21)
-    plans = [RangeCount(Between("Val", 500, 2500), reduce_every=2),
-             RangeSelect(Between("Val", 600, 1500), reduce_every=2),
+    plans = [RangeCount(Between("Val", 500, 2500)),
+             RangeSelect(Between("Val", 600, 1500)),
              Join(right=child, on=("Id", "Id"), kind="pkfk"),
              Select(Eq("Name", "nm1"), strategy="one_round")]
     for a, b in zip(cl.run_batch(plans), cl_mr.run_batch(plans)):
@@ -589,10 +637,8 @@ def test_query_server_batches_range_join_traffic(employee_db):
                       n_shares=20, degree=1)
     server = QueryServer(employee_db, key=19, max_batch=8)
     reqs = [QueryRequest(Count(Eq("FirstName", "John"))),
-            QueryRequest(RangeCount(Between("Salary", 900, 2100),
-                                    reduce_every=2)),
-            QueryRequest(RangeSelect(Between("Salary", 400, 1500),
-                                     reduce_every=2)),
+            QueryRequest(RangeCount(Between("Salary", 900, 2100))),
+            QueryRequest(RangeSelect(Between("Salary", 400, 1500))),
             QueryRequest(Join(right=child, on=("EmployeeId", "EmployeeId"),
                               kind="pkfk")),
             QueryRequest(Select(Eq("Department", "Sale"), strategy="tree"))]

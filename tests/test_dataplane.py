@@ -16,8 +16,8 @@ from repro.api import (Aggregate, Backend, Between, Count, Eq, Join,
                        Padding, QueryClient, RangeCount, RangeSelect, Select,
                        ShardedRelation, ThreadedDispatcher,
                        MapReduceDispatcher, batched_match_matrix,
-                       batched_matcher, get_backend, ripple_segmenter,
-                       ripple_stepper)
+                       batched_matcher, get_backend, planner,
+                       ripple_segmenter, ripple_stepper)
 from repro.core import Codec, outsource
 from repro.core.dataplane import as_dataplane
 from repro.runtime import MapReduceRunner, WorkerPool
@@ -50,8 +50,8 @@ def _all_family_plans(child):
         Select(Eq("Name", "nm3"), strategy="tree"),
         Select(Eq("Id", "id7"), strategy="one_tuple"),
         Select(Eq("Name", "nm4")),                          # auto
-        RangeCount(Between("Val", 500, 2000), reduce_every=2),
-        RangeSelect(Between("Val", 900, 1800), reduce_every=2),
+        RangeCount(Between("Val", 500, 2000)),
+        RangeSelect(Between("Val", 900, 1800)),
         Join(right=child, on=("Id", "Id"), kind="pkfk"),
         Join(right=child, on=("Id", "Id"), kind="equi",
              padding=Padding.fake_values(1)),
@@ -64,8 +64,8 @@ def _all_family_plans(child):
         Aggregate("sum", "Val"),
         Aggregate("sum", "Val", where=Eq("Name", "nm1"), verify=True),
         Aggregate("avg", "Val", where=Eq("Name", "nm2")),
-        Aggregate("min", "Val", where=Eq("Name", "nm1"), reduce_every=2),
-        Aggregate("max", "Val", reduce_every=2),
+        Aggregate("min", "Val", where=Eq("Name", "nm1")),
+        Aggregate("max", "Val"),
     ]
 
 
@@ -172,8 +172,8 @@ def test_sharded_aggregation_matches_plaintext_oracle(range_db, shards):
     res = client.run_batch([
         Aggregate("sum", "Val"),
         Aggregate("avg", "Val", where=Eq("Name", "nm2")),
-        Aggregate("min", "Val", where=Eq("Name", "nm1"), reduce_every=2),
-        Aggregate("max", "Val", reduce_every=2, verify=True),
+        Aggregate("min", "Val", where=Eq("Name", "nm1")),
+        Aggregate("max", "Val", verify=True),
     ])
     assert res[0].value == int(vals.sum())
     assert res[1].value == pytest.approx(vals[names == "nm2"].mean())
@@ -192,6 +192,8 @@ def test_shard_count_never_changes_step_count(range_db, child_db):
         plane = client.attach(shards=s)
         client.run_batch(plans)
         steps.add(plane.stats.steps)
+        # at 20 clouds the 14-bit ripples re-share after 9 bits
+        assert plane.stats.reshares > 0
     assert len(steps) == 1
 
 
@@ -285,10 +287,15 @@ def _counting_backend(name="jnp"):
 
 
 def test_range_phase_dispatches_one_segment_per_boundary(range_db):
-    """t=14 bits at reduce_every=2 -> 7 fused segment dispatches (never 14
-    per-bit steps) when the backend provides ripple_segment."""
-    _, db = range_db
-    plans = [RangeCount(Between("Val", 600, 600 + 200 * i), reduce_every=2)
+    """t=14 bits at 7 clouds re-share every 2 bits -> 7 fused segment
+    dispatches (never 14 per-bit steps) when the backend provides
+    ripple_segment."""
+    rows, _ = range_db
+    db = outsource(jax.random.PRNGKey(19), rows,
+                   column_names=["Id", "Name", "Val"], codec=CODEC,
+                   n_shares=7, degree=1, numeric_columns={2: 14})
+    assert planner.choose_reduce_every(7, 1, 14) == 2
+    plans = [RangeCount(Between("Val", 600, 600 + 200 * i))
              for i in range(4)]
     seq = [QueryClient(db, key=33).run(p) for p in plans]
     be, calls = _counting_backend()
@@ -297,7 +304,7 @@ def test_range_phase_dispatches_one_segment_per_boundary(range_db):
     assert calls["ripple_carry"] == 0
     for a, b in zip(seq, bat):
         _assert_results_equal(a, b)
-    # reduce_every=0: the whole chain is ONE dispatch (no reductions, the
+    # at 34 clouds no reduction: the whole chain is ONE dispatch (the
     # carry degree climbs to 2t — needs enough clouds to open)
     deep = outsource(jax.random.PRNGKey(2),
                      [[f"i{k}", str(600 + 10 * k)] for k in range(8)],
@@ -312,16 +319,18 @@ def test_range_phase_dispatches_one_segment_per_boundary(range_db):
 def test_join_group_stacks_match_matrices_into_one_dispatch(range_db,
                                                             child_db):
     """Equal-size right relations in a join group ride ONE (c,B,nx,ny)
-    batched dispatch — the per-pkfk-job match_matrix loop is retired."""
+    batched dispatch — the per-pkfk-job match_matrix loop is retired. At
+    W = 6 the planner evaluates it in the aggregate form: one flattened
+    ss_matmul for the group."""
     _, db = range_db
     plans = [Join(right=child_db, on=("Id", "Id"), kind="pkfk")
              for _ in range(3)]
     seq = [QueryClient(db, key=77).run(p) for p in plans]
     be, calls = _counting_backend()
     bat = QueryClient(db, key=77, backend=be).run_batch(plans)
-    assert calls["match_matrix_batch"] == 1    # 3 joins, one dispatch
+    assert calls["match_matrix_batch"] == 0
     assert calls["match_matrix"] == 0
-    assert calls["ss_matmul"] == 1             # the shared fetch
+    assert calls["ss_matmul"] == 1 + 1         # 3 joins' match, the fetch
     for a, b in zip(seq, bat):
         _assert_results_equal(a, b)
 
@@ -340,7 +349,7 @@ def test_join_groups_split_by_right_relation_size(range_db, child_db):
     seq = [QueryClient(db, key=13).run(p) for p in plans]
     be, calls = _counting_backend()
     bat = QueryClient(db, key=13, backend=be).run_batch(plans)
-    assert calls["match_matrix_batch"] == 2    # one per ny class
+    assert calls["ss_matmul"] == 2 + 1         # one per ny class, fetch
     for a, b in zip(seq, bat):
         _assert_results_equal(a, b)
 
@@ -449,7 +458,7 @@ def test_explain_batch_predicts_run_batch_ledger(range_db, child_db):
     exp = client.explain(plans)
     assert exp.shards == 1
     assert exp.bits > 0 and exp.rounds > 0 and exp.dispatches > 0
-    # RangeCount and RangeSelect share (t_bits, reduce_every) -> ONE fused
+    # RangeCount and RangeSelect share a column width -> ONE fused
     # range group, reported under range_select because a member fetches
     assert {g.family for g in exp.groups} == {
         "count", "one_round", "tree", "one_tuple", "range_select",

@@ -53,7 +53,7 @@ ALPHA_PLANS = [Select(Eq("Name", "nm2"), strategy="one_round",
                Count(Eq("Name", "nm1")),
                Select(Eq("Name", "nm3"), strategy="tree",
                       expected_matches=3),
-               RangeCount(Between("Val", 600, 1500), reduce_every=2)]
+               RangeCount(Between("Val", 600, 1500))]
 BETA_PLANS = [Select(Eq("Status", "open"), strategy="one_round",
                      expected_matches=6),
               Select(Eq("Customer", "c1"), strategy="tree",

@@ -189,8 +189,8 @@ def test_count_query_pallas_equals_jnp():
     db = outsource(jax.random.PRNGKey(0), rows, codec=Codec(word_length=6),
                    n_shares=16)
     got_p, _ = count_query(jax.random.PRNGKey(1), db, 1, "John",
-                           impl="pallas")
-    got_j, _ = count_query(jax.random.PRNGKey(1), db, 1, "John", impl="jnp")
+                           backend="pallas")
+    got_j, _ = count_query(jax.random.PRNGKey(1), db, 1, "John", backend="jnp")
     assert got_p == got_j == 2
 
 
@@ -201,9 +201,9 @@ def test_select_fetch_pallas_equals_jnp():
     db = outsource(jax.random.PRNGKey(2), rows, codec=Codec(word_length=6),
                    n_shares=16)
     rp, ap, _ = select_one_round(jax.random.PRNGKey(3), db, 1, "x1",
-                                 impl="pallas")
+                                 backend="pallas")
     rj, aj, _ = select_one_round(jax.random.PRNGKey(3), db, 1, "x1",
-                                 impl="jnp")
+                                 backend="jnp")
     assert rp == rj and ap == aj == [0, 2]
 
 
@@ -215,6 +215,6 @@ def test_pkfk_join_pallas_equals_jnp():
     Y = [["b1", "c1"], ["b2", "c2"], ["b2", "c3"]]
     dbX = outsource(jax.random.PRNGKey(4), X, codec=codec, n_shares=16)
     dbY = outsource(jax.random.PRNGKey(5), Y, codec=codec, n_shares=16)
-    rp, _ = pkfk_join(dbX, dbY, 1, 0, impl="pallas")
-    rj, _ = pkfk_join(dbX, dbY, 1, 0, impl="jnp")
+    rp, _ = pkfk_join(dbX, dbY, 1, 0, backend="pallas")
+    rj, _ = pkfk_join(dbX, dbY, 1, 0, backend="jnp")
     assert rp == rj

@@ -61,14 +61,14 @@ def _family_plans(child):
         Select(Eq("Name", "nm2"), strategy="one_round"),
         Select(Eq("Name", "nm3"), strategy="tree"),
         Select(Eq("Id", "id7"), strategy="one_tuple"),
-        RangeCount(Between("Val", 500, 2000), reduce_every=2),
-        RangeSelect(Between("Val", 900, 1800), reduce_every=2),
+        RangeCount(Between("Val", 500, 2000)),
+        RangeSelect(Between("Val", 900, 1800)),
         Join(right=child, on=("Id", "Id"), kind="pkfk"),
         Join(right=child, on=("Id", "Id"), kind="equi",
              padding=Padding.fake_values(1)),
         Aggregate("sum", "Val", where=Eq("Name", "nm1"), verify=True),
         Aggregate("avg", "Val", where=Eq("Name", "nm2")),
-        Aggregate("min", "Val", where=Eq("Name", "nm1"), reduce_every=2),
+        Aggregate("min", "Val", where=Eq("Name", "nm1")),
     ]
 
 
@@ -244,7 +244,7 @@ db = outsource(jax.random.PRNGKey(11), rows,
                column_names=["Id", "Name", "Val"], codec=CODEC,
                n_shares=20, degree=1, numeric_columns={2: 14})
 plans = [Count(Eq("Name", "nm1")),
-         RangeCount(Between("Val", 500, 900), reduce_every=2),
+         RangeCount(Between("Val", 500, 900)),
          Aggregate("sum", "Val", where=Eq("Name", "nm2"), verify=True)]
 serial = QueryClient(db, key=7); serial.attach(shards=4)
 ref = serial.run_batch(plans)

@@ -18,7 +18,7 @@ automata step. Pinned here:
   one-round pattern selects;
 * malformed/unknown predicates raise typed ``PlanNotSupported``;
 * the PK/FK join match matrix opens identically under the chain and
-  aggregate evaluations (the planner-priced ``match_method`` knob).
+  aggregate evaluations (the planner prices the choice by launches).
 """
 import jax
 import numpy as np
@@ -29,9 +29,13 @@ from repro.api import (AUTO, Between, Contains, Count, DBStats, Eq, Join,
                        QueryClient, RangeCount, Select, Suffix,
                        ThreadedDispatcher, choose_match_method,
                        estimate_count_cost, estimate_match_method_launches,
-                       estimate_pattern_cost, estimate_select_cost)
+                       estimate_pattern_cost, estimate_select_cost,
+                       get_backend)
 from repro.api.client import _lower_match
 from repro.core import Codec, encoding, outsource
+from repro.core.costs import CostLedger
+from repro.core.queries import rounds
+from repro.core.shamir import Shares
 from repro.launch.mesh import make_host_mesh
 
 CODEC = Codec(word_length=8)
@@ -281,30 +285,47 @@ def test_plan_not_supported_is_typed(db):
 
 
 # ---------------------------------------------------------------------------
-# join match_method: chain vs aggregate parity + planner pricing
+# join match matrix: chain vs aggregate parity + planner pricing
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_join_match_method_parity(db, right_db, backend):
+    """The round engine's chain and aggregate evaluations open the same
+    match matrix at the same degree, and join to the same rows and
+    ledger; the client's join (the planner's choice) equals both."""
+    be = get_backend(backend)
+    key = jax.random.fold_in(jax.random.PRNGKey(13), 0)  # client's 1st key
     outs = {}
-    for mm in ("chain", "aggregate", "auto"):
-        cl = QueryClient(db, key=13, backend=backend)
-        res = cl.run(Join(right=right_db, on=(0, 0), match_method=mm))
-        outs[mm] = (res.rows, res.ledger)
-    assert outs["chain"] == outs["aggregate"] == outs["auto"]
-    rows = outs["chain"][0]
-    assert [r[0] for r in rows] == ["banana", "cab"]
+    for mm in ("chain", "aggregate"):
+        job = rounds.JoinJob(right_db, 0, 0, key, CostLedger(),
+                             match_method=mm)
+        (entry,) = rounds.join_match_round(be, db, [job])
+        (opened,) = rounds._open_on_host(None, [Shares(entry.values,
+                                                       entry.degree)])
+        _, fetched = rounds.fetch_fusion(be, db, [], [entry])
+        (rows,) = rounds.join_emit_round(db, [job], fetched)
+        outs[mm] = (entry.degree, opened, rows, job.ledger)
+    (dc, mc, rc, lc), (da, ma, ra, la) = outs["chain"], outs["aggregate"]
+    assert dc == da
+    np.testing.assert_array_equal(mc, ma)
+    assert (rc, lc) == (ra, la)
+    assert [r[0] for r in rc] == ["banana", "cab"]
+    res = QueryClient(db, key=13, backend=backend).run(
+        Join(right=right_db, on=(0, 0)))
+    assert (res.rows, res.ledger) == (rc, lc)
 
 
 def test_choose_match_method_pricing(db):
     stats = DBStats.of(db)
-    # W=8 chain launches vs 2 aggregate launches: AUTO takes aggregate
+    # W=8 chain launches vs 2 aggregate launches: the planner takes
+    # aggregate; at W = 2 the chain ties and is kept
     assert estimate_match_method_launches(stats, "chain") == 8
     assert estimate_match_method_launches(stats, "aggregate") == 2
     assert choose_match_method(stats) == "aggregate"
-    assert choose_match_method(stats, "chain") == "chain"
+    assert choose_match_method(DBStats(n=8, m=3, c=20, w=2,
+                                       a=stats.a)) == "chain"
     with pytest.raises(ValueError):
-        choose_match_method(stats, "bogus")
+        estimate_match_method_launches(stats, "bogus")
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """The planner's degree reduction: ``reduce_every`` chosen from (c, base
 degree, t) so that no ripple carry, result bit or opened indicator passes
-degree c − 1; an explicit value that would is refused at plan time."""
+degree c − 1; a plan that no segment fits is refused at plan time."""
 import jax
 import numpy as np
 import pytest
@@ -21,6 +21,12 @@ CODEC = Codec(word_length=8)
     (7, 1, 8, False, 2),        # 4, then 5 after each re-share
     (27, 1, 8, False, 0),       # 16 fits: no reduction
     (27, 1, 6, True, 0),        # 12 + 1 fits
+    # the clouds the client-level tests pick to get a segmented ripple
+    (20, 1, 14, False, 9),      # 18, then 1 + 10
+    (20, 1, 14, True, 9),       # 18, then 1 + 10 + 1
+    (7, 1, 14, False, 2),       # 4, then 5 after each re-share
+    (7, 1, 8, True, 2),         # 4, 5, 5, then 5 + 1
+    (14, 1, 8, True, 6),        # 12, then 1 + 4 + 1
 ])
 def test_planner_choice(c, d, t, tournament, want):
     got = planner.choose_reduce_every(c, d, t, tournament=tournament)
@@ -97,34 +103,44 @@ def test_max_over_a_26_bit_column_at_27_clouds():
 
 
 @pytest.mark.parametrize("plan", [
-    RangeCount(Between("V", 1, 9), reduce_every=14),
-    RangeSelect(Between("V", 1, 9), reduce_every=20),
+    RangeCount(Between("V", 1, 9)),
+    RangeSelect(Between("V", 1, 9)),
 ])
 def test_overflowing_reduce_every_raises_at_plan_time(plan):
-    db = _db(DAYS, 14, 27)
+    """At 3 clouds even one bit a segment opens at degree 3 > c − 1."""
+    db = _db(DAYS, 14, 3)
     cl = QueryClient(db, key=5)
     cl.attach(shards=1)
-    with pytest.raises(ValueError, match="reach degree 28; 27 clouds"):
+    with pytest.raises(ValueError, match="no reduce_every keeps a 14-bit "
+                                         "ripple within 3 clouds"):
         cl.explain(plan)
-    with pytest.raises(ValueError, match="27 clouds"):
+    with pytest.raises(ValueError, match="reaches degree 3, above 2"):
         cl.run(plan)
     assert cl.dataplane.stats.steps == 0       # refused before any round
 
 
 def test_overflowing_max_reduce_every_raises_at_plan_time():
-    db = _db([1, 2, 3, 4], 26, 27)
-    with pytest.raises(ValueError, match="reduce_every=13 .* degree 28"):
-        QueryClient(db, key=6).run(Aggregate("max", "V", reduce_every=13))
+    """A tournament's one-bit segment adds the mux: degree 4 at 4 clouds."""
+    db = _db([1, 2, 3, 4], 26, 4)
+    cl = QueryClient(db, key=6)
+    cl.attach(shards=1)
+    with pytest.raises(ValueError, match="26-bit ripple within 4 clouds: "
+                                         "one bit a segment reaches degree 4"):
+        cl.run(Aggregate("max", "V"))
+    assert cl.dataplane.stats.steps == 0
 
 
-@pytest.mark.parametrize("plan", [
-    RangeCount(Between("V", 366, 1460)),
-    RangeCount(Between("V", 366, 1460), reduce_every=4),
-    Aggregate("max", "V"),
-    Aggregate("min", "V", where=Eq("F", "a")),
+@pytest.mark.parametrize("plan, c, every", [
+    (RangeCount(Between("V", 366, 1460)), 27, 13),
+    (RangeCount(Between("V", 366, 1460)), 10, 4),
+    (Aggregate("max", "V"), 27, 12),
+    (Aggregate("min", "V", where=Eq("F", "a")), 27, 12),
 ])
-def test_explain_equals_the_measured_ledger(plan):
-    db = _db(DAYS, 14 if not isinstance(plan, Aggregate) else 26, 27)
+def test_explain_equals_the_measured_ledger(plan, c, every):
+    t = 14 if not isinstance(plan, Aggregate) else 26
+    assert planner.choose_reduce_every(
+        c, 1, t, tournament=isinstance(plan, Aggregate)) == every
+    db = _db(DAYS, t, c)
     cl = QueryClient(db, key=7)
     exp = cl.explain(plan)
     led = cl.run(plan).ledger

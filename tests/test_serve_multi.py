@@ -56,7 +56,7 @@ def orders_db():
 
 EMP_PLANS = [Count(Eq("FirstName", "John")),
              Select(Eq("Department", "Sale"), strategy="tree"),
-             RangeCount(Between("Salary", 600, 4000), reduce_every=2),
+             RangeCount(Between("Salary", 600, 4000)),
              Count(Eq("LastName", "Smith"))]
 ORD_PLANS = [Count(Eq("Customer", "acme")),
              Select(Eq("Status", "open"), strategy="one_round"),
